@@ -180,4 +180,6 @@ def main(print_csv: bool = True, s: int = 1, img: int = 11) -> dict:
 
 if __name__ == "__main__":
     obs.setup_logging()
+    from repro.core.cachedir import enable_compile_cache
+    enable_compile_cache()
     main()

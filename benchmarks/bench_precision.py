@@ -90,4 +90,6 @@ def main(print_csv: bool = True, steps: int = 300) -> dict:
 
 if __name__ == "__main__":
     obs.setup_logging()
+    from repro.core.cachedir import enable_compile_cache
+    enable_compile_cache()
     main()

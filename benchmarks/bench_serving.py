@@ -193,6 +193,8 @@ if __name__ == "__main__":
                     help="exit 1 unless QPS>0 and zero dropped everywhere")
     a = ap.parse_args()
     obs.setup_logging()
+    from repro.core.cachedir import enable_compile_cache
+    enable_compile_cache()
     result = main(fast=a.fast,
                   backends=a.backends.split(",") if a.backends else None)
     for name, b in result["backends"].items():

@@ -100,4 +100,6 @@ def main(fast: bool = False, backends=None) -> dict:
 if __name__ == "__main__":
     import json
     obs.setup_logging()
+    from repro.core.cachedir import enable_compile_cache
+    enable_compile_cache()
     print(json.dumps(main(fast=True), indent=1))

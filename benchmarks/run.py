@@ -334,4 +334,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.core.cachedir import enable_compile_cache
+    enable_compile_cache()
     main()

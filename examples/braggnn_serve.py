@@ -201,4 +201,6 @@ def _run(args) -> None:
 
 
 if __name__ == "__main__":
+    from repro.core.cachedir import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -79,4 +79,6 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.core.cachedir import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -100,3 +100,30 @@ def cache_root(kind: str, *, base: Optional[Union[str, Path]] = None,
     root = base / f"v{version}" / kind
     root.mkdir(parents=True, exist_ok=True, mode=0o700)
     return root
+
+
+#: the checkout root (``src/repro/core`` sits three levels below it)
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> Path:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    ``$JAX_COMPILATION_CACHE_DIR``, when set, is the cache: JAX reads it
+    itself and no other directory is set here.  Otherwise the cache lives
+    at ``<checkout>/.jax_cache`` (git-ignored).  The path is fixed because
+    it is part of every entry's key: a directory that moves never hits.
+    Called by the entry points (``chip_smoke.py``, ``examples/``,
+    ``benchmarks/``), never on import of the library.  Returns the
+    directory in use.
+    """
+    import jax
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        path = Path(env)
+    else:
+        path = REPO_ROOT / ".jax_cache"
+        jax.config.update("jax_compilation_cache_dir", str(path))
+    # the kernels compile in well under JAX's default one-second floor
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path

@@ -244,7 +244,8 @@ def evaluate(g: Graph, feeds: dict[str, np.ndarray], *,
         elif oc == "relu":
             r = np.maximum(a0, 0.0)
         elif oc == "fmac":
-            # fmac(b, c, a) = b*c + a, rounded once (fused on FPGA)
+            # fmac(b, c, a) = b*c + a: the fp32 product and the sum are
+            # each rounded, then the result is quantised once
             r = a0 * M[args[rows, 1]] + M[args[rows, 2]]
         elif oc == "cmpugt":
             r = (a0 > M[args[rows, 1]]).astype(np.float32)

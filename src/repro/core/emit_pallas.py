@@ -30,9 +30,14 @@ re-quantised — the per-op FloPoCo functional model, bit-matching
 ``emit.evaluate``.
 
 ``use_pallas`` routes segment bodies / registry kernels through real
-``pl.pallas_call`` lowerings (interpret mode off-TPU — the CI
-``pallas-smoke`` path); the default off-accelerator is the kernels' own
-oracle discipline: same lowering, executed as plain XLA.
+``pl.pallas_call`` lowerings.  On the TPU the nest tier's kernels compile
+with Mosaic, the TPU kernel compiler; off the TPU they run in the Pallas
+interpreter.  That choice is made in one place (:func:`to_pallas_fn`) and
+recorded as ``PallasPlan.interpret``.  The default off the TPU is the
+kernels' own oracle discipline: same lowering, executed as plain XLA.  The
+DFG tier's fused segments gather from the value buffer with dynamic
+indices, which Mosaic cannot lower, so on the TPU they run as XLA programs
+by rule and ``use_pallas=True`` is refused there.
 """
 
 from __future__ import annotations
@@ -53,12 +58,9 @@ from repro.kernels import registry as kreg
 _TRIVIAL_NODES = ("ReLU", "OutputReLU", "Flatten")
 
 
-def _on_accelerator() -> bool:
+def _on_tpu() -> bool:
     import jax
-    try:
-        return jax.devices()[0].platform in ("tpu", "gpu")
-    except Exception:  # pragma: no cover - no backend at all
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 def _norm_fmt(fmt) -> tuple[Optional[FloatFormat], Optional[str]]:
@@ -80,8 +82,9 @@ class PallasPlan:
 
     mode: str                                  #: 'nests' | 'dfg'
     use_pallas: bool                           #: real pl.pallas_call bodies?
-    interpret: bool                            #: interpret=True off-TPU
+    interpret: bool                            #: Pallas interpreter (off TPU)?
     fmt: Optional[str] = None                  #: FloPoCo key, None = fp32
+    bodies: str = ""                           #: what executes the bodies
     n_groups: int = 0                          #: levelised groups (dfg tier)
     n_segments: int = 0                        #: fused kernels (dfg tier)
     fused_scatters: int = 0                    #: scatter->gather pairs elided
@@ -94,7 +97,8 @@ class PallasPlan:
 
     def summary(self) -> str:
         kern = ", ".join(f"{k}x{v}" for k, v in sorted(self.kernels.items()))
-        parts = [f"pallas[{self.mode}]"]
+        parts = [f"pallas[{self.mode}] {self.fmt or 'fp32'}",
+                 f"use_pallas={self.use_pallas} interpret={self.interpret}"]
         if self.mode == "dfg":
             parts.append(f"{self.n_segments} fused kernels over "
                          f"{self.n_groups} groups "
@@ -102,8 +106,7 @@ class PallasPlan:
         if kern:
             parts.append(kern)
         parts.append(f"{len(self.fallbacks)} fallbacks")
-        if not self.use_pallas:
-            parts.append("oracle bodies (no accelerator)")
+        parts.append(self.bodies)
         return "; ".join(parts)
 
 
@@ -559,6 +562,14 @@ def _node_label(node) -> str:
                or type(node).__name__)
 
 
+def _einsum(spec, a, b):
+    """An fp32 contraction outside the kernels, at full fp32 precision
+    (the TPU's default f32 matmul rounds operands to bf16)."""
+    import jax.numpy as jnp
+    from jax import lax
+    return jnp.einsum(spec, a, b, precision=lax.Precision.HIGHEST)
+
+
 def _nlb_step(node, conv_e, sm_e, fa_e, q, fmt_tuple, kw, nlb_flash: bool,
               plan: PallasPlan):
     """The NonLocalBlock composite: three 1x1 convs -> attention ->
@@ -599,9 +610,9 @@ def _nlb_step(node, conv_e, sm_e, fa_e, q, fmt_tuple, kw, nlb_flash: bool,
                         vv[:, :, None, :], causal=False, **kw)
             yc = q(y[:, :, 0, :].transpose(0, 2, 1))         # (B, c2, n)
         else:
-            scores = q(jnp.einsum("bci,bcj->bij", tf, pf))
+            scores = q(_einsum("bci,bcj->bij", tf, pf))
             attn = sm_e.fn(scores, taylor_order=node.taylor_order, **kw)
-            yc = q(jnp.einsum("bij,bcj->bci", attn, gf))
+            yc = q(_einsum("bij,bcj->bci", attn, gf))
         y4 = yc.reshape(b, c2, h, h)
         z = q(conv_e.fn(y4, w[f"{pre}.out_cnn.weight"], None,
                         fmt=fmt_tuple, **kw))
@@ -657,10 +668,10 @@ def _attention_step(node, mm_e, sm_e, fa_e, q, fmt_obj, fmt_tuple, kw,
             # flash divides logits by sqrt(dh) — exactly the DFG's scale
             y = fa_e.fn(qh, kh, vh, causal=False, **kw)
         else:
-            scores = q(jnp.einsum("bshk,bthk->bhst", qh, kh)
+            scores = q(_einsum("bshk,bthk->bhst", qh, kh)
                        * (1.0 / jnp.sqrt(jnp.float32(dh))))
             attn = sm_e.fn(scores, taylor_order=node.taylor_order, **kw)
-            y = q(jnp.einsum("bhst,bthk->bshk", attn, vh))
+            y = q(_einsum("bhst,bthk->bshk", attn, vh))
         wo = w[f"{pre}.o.kernel"].reshape(h * dh, d)
         z = q(mm_e.fn(y.reshape(b * l, h * dh), wo, None,
                       exp_bits=eb, man_bits=mb, **kw)).reshape(b, l, d)
@@ -702,9 +713,15 @@ def _mlp_step(node, mm_e, q, fmt_obj, plan: PallasPlan, kw):
 # Front door
 # ---------------------------------------------------------------------------
 
+#: why the DFG tier cannot run ``pl.pallas_call`` bodies on the TPU
+_NO_MOSAIC_GATHER = (
+    "the DFG tier's fused segments gather from the value buffer with "
+    "dynamic indices inside the kernel (buf[:, idx]), and Mosaic, the TPU "
+    "kernel compiler, has no lowering for that gather")
+
+
 def to_pallas_fn(g: Graph, *, module=None, fmt=None, mode: str = "auto",
-                 use_pallas: Optional[bool] = None,
-                 interpret: Optional[bool] = None, nlb_flash: bool = False,
+                 use_pallas: Optional[bool] = None, nlb_flash: bool = False,
                  opcode_table=None) -> Callable:
     """Compile a DFG (plus optional source ``ModuleGraph``) to a callable.
 
@@ -717,27 +734,41 @@ def to_pallas_fn(g: Graph, *, module=None, fmt=None, mode: str = "auto",
     ``mode='auto'`` picks the nest-pattern tier when ``module`` is given,
     else the generic DFG tier.  ``fmt`` (a FloPoCo key or ``FloatFormat``)
     quantises: per-op in the DFG tier (the functional model), per-kernel
-    operand/result in the nest tier.  ``use_pallas=None`` routes through
-    real ``pl.pallas_call`` bodies only on an accelerator; force ``True``
-    to exercise the Pallas lowering in interpret mode on CPU.
+    operand/result in the nest tier.  ``use_pallas=None`` routes the nest
+    tier through real ``pl.pallas_call`` bodies on the TPU (compiled by
+    Mosaic) and runs the oracle bodies elsewhere; ``True`` off the TPU
+    runs the Pallas lowering in the interpreter.  The DFG tier runs XLA
+    segment bodies on the TPU, and ``use_pallas=True`` there raises.
     ``opcode_table`` overrides the DFG tier's opcode registry (tests use
     this to force per-group fallbacks).
     """
     import jax
 
     fmt_obj, fmt_key = _norm_fmt(fmt)
-    accel = _on_accelerator()
-    if use_pallas is None:
-        use_pallas = accel
-    if interpret is None:
-        interpret = not accel
     if mode == "auto":
         mode = "nests" if module is not None else "dfg"
     if mode not in ("nests", "dfg"):
         raise ValueError(f"unknown pallas lowering mode {mode!r} "
                          f"(valid: auto, nests, dfg)")
+    on_tpu = _on_tpu()
+    if use_pallas is None:
+        use_pallas = on_tpu and mode == "nests"
+    if use_pallas and on_tpu and mode == "dfg":
+        raise NotImplementedError(
+            f"use_pallas=True on the TPU: {_NO_MOSAIC_GATHER}; the DFG tier "
+            f"runs XLA segment bodies there (leave use_pallas unset), and "
+            f"the nest tier (mode='nests') is the Pallas path on the chip")
+    # the one place interpret mode is chosen: only off the TPU
+    interpret = bool(use_pallas) and not on_tpu
+    if use_pallas:
+        bodies = ("Pallas interpreter (off TPU)" if interpret
+                  else "Mosaic kernels")
+    elif on_tpu:
+        bodies = f"XLA segment bodies ({_NO_MOSAIC_GATHER})"
+    else:
+        bodies = "oracle bodies (off TPU)"
     plan = PallasPlan(mode=mode, use_pallas=bool(use_pallas),
-                      interpret=bool(interpret), fmt=fmt_key)
+                      interpret=interpret, fmt=fmt_key, bodies=bodies)
 
     if mode == "nests":
         if module is None:

@@ -67,8 +67,26 @@ FP_5_3 = FloatFormat(5, 3, "(5,3)")
 FORMATS = {"5_11": FP_5_11, "5_4": FP_5_4, "5_3": FP_5_3}
 
 
+def _pow2(e, xp):
+    """Exact ``2**e`` for integer ``e``, built from fp32 exponent bits.
+
+    XLA's ``exp2`` is not exact on every backend (on the CPU it misses
+    integer powers by an ulp), which moves quantised values off the
+    lattice.  ``e`` is clamped to the fp32 normal range; every value whose
+    exponent lies outside it is flushed or saturated by the caller.
+    """
+    bits = (xp.clip(e, -126, 127) + 127).astype(xp.int32) << 23
+    if xp is np:
+        return bits.view(np.float32)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
 def _quantize_generic(x, fmt: FloatFormat, xp):
-    """Shared numpy/jnp quantiser.  RNE fraction rounding, FTZ, saturation."""
+    """Shared numpy/jnp quantiser.  RNE fraction rounding, FTZ, saturation.
+
+    Written with operations the Pallas TPU compiler lowers, so kernels
+    call it on VMEM blocks too.
+    """
     x = xp.asarray(x, dtype=xp.float32)
     sign = xp.sign(x)
     v = xp.abs(x)
@@ -82,7 +100,7 @@ def _quantize_generic(x, fmt: FloatFormat, xp):
     carry = q >= scale
     m_q = xp.where(carry, 1.0, 1.0 + q / scale)
     e_q = xp.where(carry, e + 1, e)
-    out = sign * m_q * xp.exp2(e_q.astype(xp.float32))
+    out = sign * m_q * _pow2(e_q, xp)
     # flush-to-zero below min normal (FloPoCo: no subnormals)
     out = xp.where(v < fmt.min_normal * 0.5, 0.0, out)
     out = xp.where((v >= fmt.min_normal * 0.5) & (v < fmt.min_normal),
@@ -92,7 +110,7 @@ def _quantize_generic(x, fmt: FloatFormat, xp):
     out = xp.where(v > fmt.max_value, sign * fmt.max_value, out)
     # exact zeros / non-finites pass through
     out = xp.where(v == 0.0, x, out)
-    out = xp.where(xp.isfinite(x), out, x)
+    out = xp.where(v <= np.finfo(np.float32).max, out, x)
     return out
 
 

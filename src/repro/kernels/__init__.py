@@ -1,9 +1,10 @@
 """Pallas TPU kernels (+ jnp oracles) for the perf-critical compute.
 
 Each kernel directory holds:
-  <name>.py — pl.pallas_call + explicit BlockSpec VMEM tiling (TPU target;
-              validated via interpret=True on CPU)
-  ops.py    — the jit'd public wrapper (oracle fallback off-TPU)
+  <name>.py — pl.pallas_call + explicit BlockSpec VMEM tiling, compiled
+              for the TPU (``interpret=True`` runs the interpreter, the
+              only mode on the CPU)
+  ops.py    — the public wrapper (``use_pallas=False`` runs the oracle)
   ref.py    — the pure-jnp oracle
 
 smallfloat_matmul — reduced-precision MAC array (paper §4.2)

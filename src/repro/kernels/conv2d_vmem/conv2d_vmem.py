@@ -1,15 +1,22 @@
-"""Pallas TPU kernel: weights-resident direct convolution (BraggNN path).
+"""Pallas TPU kernel: weights-resident convolution as one MXU contraction
+(BraggNN path).
 
 The paper's headline resource result is that at (5,4)/(5,3) precision the
 *entire* BraggNN weight set fits in registers/LUTs — no BRAM.  The TPU
-analogue: all conv weights live in VMEM for the kernel's lifetime (~59 KB
-at s=1), the batch streams through in blocks, and each (kh, kw) tap is one
-MXU contraction over input channels.  Valid padding, stride 1, NCHW —
-matching the loop-nest semantics of ``repro.core.frontend.conv2d``.
+analogue: the conv's weights live in VMEM for the kernel's lifetime (~59 KB
+for all of BraggNN at s=1) while the batch streams through in row blocks.
+Valid padding, stride 1, NCHW — matching the loop-nest semantics of
+``repro.core.frontend.conv2d``.
 
-Grid: (B / bb,).  Per step: x block (bb, Cin, H, W) + full weights ->
-out block (bb, Cout, Ho, Wo).  Optional fused ReLU and (wE,wF) weight
-quantisation (performed in VMEM, the FloPoCo discipline).
+The wrapper lowers the conv to im2col as XLA ops: the ``(B·Ho·Wo,
+Cin·kh·kw)`` patch matrix and the ``(Cin·kh·kw, Cout)`` weight matrix.  The
+kernel is then a single 2-D contraction per row block with bias, ReLU and
+(wE,wF) operand quantisation (in VMEM, the FloPoCo discipline) fused.  A
+per-tap contraction over NCHW blocks needs in-kernel reshapes across the
+lane axis (e.g. 81 lanes at img=11), which the TPU compiler refuses.
+
+Grid: (rows / bm,).  Per step: patch block (bm, K) + full weights (K, Cout)
++ bias (1, Cout) -> out block (bm, Cout).
 """
 
 from __future__ import annotations
@@ -21,70 +28,81 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.smallfloat_matmul.smallfloat_matmul import _quantize_block
+from repro.kernels.smallfloat_matmul.smallfloat_matmul import (
+    _dot, _quantize_block)
 
 
-def _conv_kernel(x_ref, w_ref, b_ref, o_ref, *, kh, kw, fmt, fuse_relu):
-    x = x_ref[...].astype(jnp.float32)            # (bb, Cin, H, W)
-    w = w_ref[...].astype(jnp.float32)            # (Cout, Cin, kh, kw)
+def _conv_kernel(p_ref, w_ref, b_ref, o_ref, *, fmt, fuse_relu):
+    p = p_ref[...].astype(jnp.float32)            # (bm, Cin·kh·kw)
+    w = w_ref[...].astype(jnp.float32)            # (Cin·kh·kw, Cout)
     if fmt is not None:
-        x = _quantize_block(x, *fmt)
+        p = _quantize_block(p, *fmt)
         w = _quantize_block(w, *fmt)
-    bb, cin, h, wdim = x.shape
-    cout = w.shape[0]
-    ho, wo = h - kh + 1, wdim - kw + 1
-    acc = jnp.zeros((bb, cout, ho, wo), jnp.float32)
-    for i in range(kh):
-        for j in range(kw):
-            patch = x[:, :, i:i + ho, j:j + wo]   # (bb, Cin, Ho, Wo)
-            tap = w[:, :, i, j]                   # (Cout, Cin)
-            acc = acc + jax.lax.dot_general(
-                tap, patch.reshape(bb, cin, ho * wo),
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ).transpose(1, 0, 2).reshape(bb, cout, ho, wo)
+    acc = _dot(p, w)
     if b_ref is not None:
-        acc = acc + b_ref[...].astype(jnp.float32)[None, :, None, None]
+        acc = acc + b_ref[...].astype(jnp.float32)
     if fuse_relu:
         acc = jnp.maximum(acc, 0.0)
     o_ref[...] = acc
 
 
-def _conv_kernel_nobias(x_ref, w_ref, o_ref, **kw):
-    _conv_kernel(x_ref, w_ref, None, o_ref, **kw)
+def _conv_kernel_nobias(p_ref, w_ref, o_ref, **kw):
+    _conv_kernel(p_ref, w_ref, None, o_ref, **kw)
+
+
+def im2col(x: jax.Array, kh: int, kw: int) -> jax.Array:
+    """(B, Cin, H, W) -> (B·Ho·Wo, Cin·kh·kw) valid-conv patch matrix.
+
+    Column order is ``(cin, i, j)``, matching ``w.reshape(Cout, -1)``.
+    """
+    b, cin, h, wdim = x.shape
+    ho, wo = h - kh + 1, wdim - kw + 1
+    taps = [x[:, :, i:i + ho, j:j + wo] for i in range(kh) for j in range(kw)]
+    p = jnp.stack(taps, axis=2)                   # (B, Cin, kh·kw, Ho, Wo)
+    return p.transpose(0, 3, 4, 1, 2).reshape(b * ho * wo, cin * kh * kw)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "fmt", "fuse_relu", "bb", "interpret"))
+    "fmt", "fuse_relu", "bm", "interpret"))
 def conv2d_vmem(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
                 *, fmt: Optional[tuple[int, int]] = None,
-                fuse_relu: bool = False, bb: int = 8,
-                interpret: bool = True) -> jax.Array:
-    """x: (B, Cin, H, W), w: (Cout, Cin, kh, kw), b: (Cout,) -> fp32."""
+                fuse_relu: bool = False, bm: int = 512,
+                interpret: bool = False) -> jax.Array:
+    """x: (B, Cin, H, W), w: (Cout, Cin, kh, kw), b: (Cout,) -> fp32.
+
+    ``bm`` is the patch rows per grid step; rows are zero-padded up to a
+    whole number of blocks when there is more than one block.
+    """
     bsz, cin, h, wdim = x.shape
     cout, cin2, kh, kw = w.shape
-    assert cin == cin2
-    bb = min(bb, bsz)
-    assert bsz % bb == 0, (bsz, bb)
+    if cin != cin2:
+        raise ValueError(f"input channels differ: x {x.shape}, w {w.shape}")
     ho, wo = h - kh + 1, wdim - kw + 1
-    grid = (bsz // bb,)
+    patches = im2col(x, kh, kw)
+    rows, kdim = patches.shape
+    bm = min(bm, rows)
+    rows_p = -(-rows // bm) * bm
+    if rows_p != rows:
+        patches = jnp.pad(patches, ((0, rows_p - rows), (0, 0)))
+    wmat = w.reshape(cout, kdim).T
 
     in_specs = [
-        pl.BlockSpec((bb, cin, h, wdim), lambda i: (i, 0, 0, 0)),
-        pl.BlockSpec((cout, cin, kh, kw), lambda i: (0, 0, 0, 0)),
+        pl.BlockSpec((bm, kdim), lambda i: (i, 0)),
+        pl.BlockSpec((kdim, cout), lambda i: (0, 0)),
     ]
-    args = [x, w]
+    args = [patches, wmat]
     kernel = _conv_kernel_nobias
     if b is not None:
-        in_specs.append(pl.BlockSpec((cout,), lambda i: (0,)))
-        args.append(b)
+        # bias kept 2-D: TPU VMEM tiles are (sublane, lane)-shaped
+        in_specs.append(pl.BlockSpec((1, cout), lambda i: (0, 0)))
+        args.append(b.reshape(1, cout))
         kernel = _conv_kernel
-    return pl.pallas_call(
-        functools.partial(kernel, kh=kh, kw=kw, fmt=fmt,
-                          fuse_relu=fuse_relu),
-        grid=grid,
+    out = pl.pallas_call(
+        functools.partial(kernel, fmt=fmt, fuse_relu=fuse_relu),
+        grid=(rows_p // bm,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bb, cout, ho, wo), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((bsz, cout, ho, wo), jnp.float32),
+        out_specs=pl.BlockSpec((bm, cout), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows_p, cout), jnp.float32),
         interpret=interpret,
     )(*args)
+    return out[:rows].reshape(bsz, ho, wo, cout).transpose(0, 3, 1, 2)

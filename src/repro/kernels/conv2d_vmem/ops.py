@@ -12,7 +12,7 @@ from repro.kernels.conv2d_vmem.ref import conv2d_ref
 
 def conv2d(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None, *,
            fmt: Optional[tuple[int, int]] = None, fuse_relu: bool = False,
-           use_pallas: bool = False, interpret: bool = True) -> jax.Array:
+           use_pallas: bool = False, interpret: bool = False) -> jax.Array:
     if use_pallas:
         return conv2d_vmem(x, w, b, fmt=fmt, fuse_relu=fuse_relu,
                            interpret=interpret)

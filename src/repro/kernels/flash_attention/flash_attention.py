@@ -76,7 +76,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: Optional[int] = None,
                     logit_cap: float = 0.0, bq: int = 256, bk: int = 256,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """q: (BH, Sq, D), k/v: (BH, Skv, D) — heads pre-flattened into BH.
 
     GQA is expressed by repeating kv head indices in the caller (ops.py).

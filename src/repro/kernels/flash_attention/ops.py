@@ -14,7 +14,7 @@ from repro.kernels.flash_attention.ref import flash_attention_ref
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True, window: Optional[int] = None,
               logit_cap: float = 0.0, use_pallas: bool = False,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool = False) -> jax.Array:
     """q: (B, S, H, D), k/v: (B, S, K, D) with H % K == 0."""
     b, s, h, d = q.shape
     n_kv = k.shape[2]

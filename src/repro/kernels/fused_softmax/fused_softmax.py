@@ -44,17 +44,25 @@ def _softmax_kernel(x_ref, o_ref, *, taylor_order, range_reduce):
     "taylor_order", "range_reduce", "block_rows", "interpret"))
 def fused_softmax(x: jax.Array, *, taylor_order: int = 0,
                   range_reduce: int = 2, block_rows: int = 256,
-                  interpret: bool = True) -> jax.Array:
-    """Softmax over the last axis of a 2-D array (rows, cols)."""
+                  interpret: bool = False) -> jax.Array:
+    """Softmax over the last axis of a 2-D array (rows, cols).
+
+    Up to ``block_rows`` rows run as one block.  More rows are zero-padded
+    up to a whole number of blocks (a padded row is a harmless uniform
+    softmax) and the padding is sliced off, so every row count lowers: the
+    NLB's B·81 rows at img=11 are a multiple of no TPU-aligned block.
+    """
     rows, cols = x.shape
     block_rows = min(block_rows, rows)
-    assert rows % block_rows == 0
-    return pl.pallas_call(
+    rows_p = -(-rows // block_rows) * block_rows
+    xp = jnp.pad(x, ((0, rows_p - rows), (0, 0))) if rows_p != rows else x
+    out = pl.pallas_call(
         functools.partial(_softmax_kernel, taylor_order=taylor_order,
                           range_reduce=range_reduce),
-        grid=(rows // block_rows,),
+        grid=(rows_p // block_rows,),
         in_specs=[pl.BlockSpec((block_rows, cols), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, cols), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((rows_p, cols), x.dtype),
         interpret=interpret,
-    )(x)
+    )(xp)
+    return out[:rows] if rows_p != rows else out

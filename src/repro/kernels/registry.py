@@ -7,8 +7,8 @@ Two tables:
   (``Conv2d`` -> conv2d_vmem, ``Linear`` -> smallfloat_matmul,
   ``Softmax`` / the NLB attention softmax -> fused_softmax, the whole NLB
   attention core -> flash_attention).  Each entry carries the unified
-  wrapper (oracle off-TPU, ``use_pallas=True`` routes to the
-  ``pl.pallas_call`` kernel, interpret mode off-accelerator), the raw
+  wrapper (the oracle by default; ``use_pallas=True`` routes to the
+  ``pl.pallas_call`` kernel, interpreted with ``interpret=True``), the raw
   kernel, and the pure-jnp oracle, so callers pick the execution mode
   without knowing the module layout.
 
@@ -126,6 +126,16 @@ _register_exemplars()
 # Generic tier: scalar-DFG opcode -> vectorised jnp compute
 # ---------------------------------------------------------------------------
 
+def _fmac(a):
+    """``a0 * a1 + a2`` with the product rounded on its own, as
+    ``emit.evaluate`` computes it.  XLA's CPU backend contracts a fused
+    multiply-add into one rounding; the NaN-preserving select between the
+    two ops keeps them apart."""
+    import jax.numpy as jnp
+    prod = a[0] * a[1]
+    return jnp.where(jnp.isnan(prod), jnp.nan, prod) + a[2]
+
+
 def _opcode_table():
     import jax.numpy as jnp
 
@@ -140,7 +150,7 @@ def _opcode_table():
         "minf": (2, lambda a: jnp.minimum(a[0], a[1])),
         "negf": (1, lambda a: -a[0]),
         "relu": (1, lambda a: jnp.maximum(a[0], 0.0)),
-        "fmac": (3, lambda a: a[0] * a[1] + a[2]),
+        "fmac": (3, _fmac),
         "load": (1, lambda a: a[0]),
         "store": (1, lambda a: a[0]),
         "copy": (1, lambda a: a[0]),

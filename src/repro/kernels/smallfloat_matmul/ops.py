@@ -1,7 +1,7 @@
 """Public jit'd wrapper for the smallfloat matmul kernel.
 
-``use_pallas=False`` (the CPU-container default) routes to the oracle;
-``use_pallas=True`` routes to the kernel (interpret mode off-TPU).
+``use_pallas=False`` routes to the oracle; ``use_pallas=True`` routes to
+the kernel, compiled for the TPU unless ``interpret=True``.
 """
 
 from __future__ import annotations
@@ -14,7 +14,7 @@ from repro.kernels.smallfloat_matmul.smallfloat_matmul import smallfloat_matmul
 
 def matmul(x: jax.Array, w: jax.Array, b=None, *, exp_bits=5,
            man_bits=4, fuse_relu: bool = False,
-           use_pallas: bool = False, interpret: bool = True) -> jax.Array:
+           use_pallas: bool = False, interpret: bool = False) -> jax.Array:
     """``exp_bits=None`` skips operand quantisation (plain fp32 matmul)."""
     if use_pallas:
         return smallfloat_matmul(x, w, b, exp_bits=exp_bits,

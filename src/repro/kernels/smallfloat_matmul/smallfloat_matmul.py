@@ -20,37 +20,26 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.precision import FloatFormat, quantize
+
 
 def _quantize_block(x, exp_bits, man_bits):
-    """RNE quantisation to (wE,wF) with FTZ + saturation (fp32 in/out).
+    """(wE,wF) quantisation of a VMEM block: ``precision.quantize`` itself.
 
     ``exp_bits=None`` means full fp32 — the identity — so one kernel serves
     both the reduced-precision MAC array and the plain fp32 fast path.
     """
     if exp_bits is None:
         return x
-    bias = (1 << (exp_bits - 1)) - 1
-    emax = bias
-    emin = 1 - bias
-    max_value = (2.0 - 2.0 ** (-man_bits)) * 2.0 ** emax
-    min_normal = 2.0 ** emin
-    sign = jnp.sign(x)
-    v = jnp.abs(x)
-    f, e = jnp.frexp(v)
-    m = f * 2.0
-    e = e - 1
-    scale = float(1 << man_bits)
-    q = jnp.round((m - 1.0) * scale)
-    carry = q >= scale
-    m_q = jnp.where(carry, 1.0, 1.0 + q / scale)
-    e_q = jnp.where(carry, e + 1, e)
-    out = sign * m_q * jnp.exp2(e_q.astype(jnp.float32))
-    out = jnp.where(v < min_normal * 0.5, 0.0, out)
-    out = jnp.where((v >= min_normal * 0.5) & (v < min_normal),
-                    sign * min_normal, out)
-    out = jnp.where(v > max_value, sign * max_value, out)
-    out = jnp.where(v == 0.0, x, out)
-    return out
+    return quantize(x, FloatFormat(exp_bits, man_bits))
+
+
+def _dot(x, w):
+    """fp32 MXU contraction at full fp32 precision (the TPU's default f32
+    matmul rounds operands to bf16)."""
+    return jax.lax.dot_general(x, w, (((1,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
 def _matmul_kernel(x_ref, w_ref, b_ref, o_ref, *, exp_bits, man_bits,
@@ -63,9 +52,7 @@ def _matmul_kernel(x_ref, w_ref, b_ref, o_ref, *, exp_bits, man_bits,
 
     x = _quantize_block(x_ref[...].astype(jnp.float32), exp_bits, man_bits)
     w = _quantize_block(w_ref[...].astype(jnp.float32), exp_bits, man_bits)
-    o_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    o_ref[...] += _dot(x, w)
 
     @pl.when(k == n_k - 1)
     def _finish():
@@ -82,18 +69,27 @@ def _matmul_kernel(x_ref, w_ref, b_ref, o_ref, *, exp_bits, man_bits,
 def smallfloat_matmul(x: jax.Array, w: jax.Array, b=None, *,
                       exp_bits: int = 5, man_bits: int = 4,
                       fuse_relu: bool = False, bm: int = 128, bn: int = 128,
-                      bk: int = 128, interpret: bool = True) -> jax.Array:
-    """x: (M, K), w: (K, N), b: (N,) or None  ->  (M, N) fp32."""
+                      bk: int = 128, interpret: bool = False) -> jax.Array:
+    """x: (M, K), w: (K, N), b: (N,) or None  ->  (M, N) fp32.
+
+    Rows are zero-padded up to a whole number of ``bm`` blocks and the
+    padding is sliced off, so any batch size lowers.
+    """
     m, kdim = x.shape
     k2, n = w.shape
-    assert kdim == k2
+    if kdim != k2:
+        raise ValueError(f"contraction dims differ: x {x.shape}, w {w.shape}")
     bm = min(bm, m)
     bn = min(bn, n)
     bk = min(bk, kdim)
-    assert m % bm == 0 and n % bn == 0 and kdim % bk == 0, (
-        "dims must tile evenly", (m, n, kdim), (bm, bn, bk))
+    if n % bn or kdim % bk:
+        raise ValueError(f"N and K must tile evenly: (N, K) = {(n, kdim)}, "
+                         f"blocks {(bn, bk)}")
+    mp = -(-m // bm) * bm
+    if mp != m:
+        x = jnp.pad(x, ((0, mp - m), (0, 0)))
     n_k = kdim // bk
-    grid = (m // bm, n // bn, n_k)
+    grid = (mp // bm, n // bn, n_k)
 
     in_specs = [
         pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
@@ -108,14 +104,15 @@ def smallfloat_matmul(x: jax.Array, w: jax.Array, b=None, *,
     kernel = functools.partial(
         _matmul_kernel if b is not None else _matmul_kernel_nobias,
         exp_bits=exp_bits, man_bits=man_bits, fuse_relu=fuse_relu, n_k=n_k)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((mp, n), jnp.float32),
         interpret=interpret,
     )(*args)
+    return out[:m] if mp != m else out
 
 
 def _matmul_kernel_nobias(x_ref, w_ref, o_ref, **kw):

@@ -85,9 +85,9 @@ def test_conv_dfg_real_pallas_call_interpret(conv_design, conv_feeds):
     CPU) — the CI pallas-smoke path."""
     g = conv_design.graph_opt
     ref = emit.evaluate(g, conv_feeds)
-    fn = emit.to_jax_fn(g, backend="pallas", use_pallas=True,
-                        interpret=True)
+    fn = emit.to_jax_fn(g, backend="pallas", use_pallas=True)
     assert fn.plan.use_pallas and fn.plan.interpret
+    assert "interpret=True" in fn.plan.summary()
     out = fn(conv_feeds)
     for k in ref:
         np.testing.assert_allclose(np.asarray(out[k]), ref[k],
@@ -138,6 +138,53 @@ def test_braggnn_nest_tier_matches_evaluate(bragg_design, bragg_feeds):
     for k in ref:
         np.testing.assert_allclose(np.asarray(out[k]), ref[k],
                                    rtol=1e-4, atol=1e-5)
+
+
+def test_braggnn_nest_tier_pallas_interpret_matches_evaluate(bragg_design,
+                                                           bragg_feeds):
+    """The chip path's kernels (conv2d_vmem, smallfloat_matmul,
+    fused_softmax) through ``pl.pallas_call``, here in the interpreter."""
+    g = bragg_design.graph_opt
+    ref = emit.evaluate(g, bragg_feeds)
+    fn = bragg_design.jax_fn(backend="pallas", use_pallas=True)
+    assert fn.plan.use_pallas and fn.plan.interpret
+    assert not fn.plan.fallbacks
+    assert "Pallas interpreter" in fn.plan.summary()
+    out = fn(bragg_feeds)
+    for k in ref:
+        np.testing.assert_allclose(np.asarray(out[k]), ref[k],
+                                   rtol=1e-4, atol=1e-5)
+
+
+def test_dfg_tier_on_tpu_runs_xla_bodies(conv_design, conv_feeds,
+                                         monkeypatch):
+    """On the TPU the DFG tier runs XLA segment bodies by rule, and
+    ``use_pallas=True`` is refused with the missing Mosaic gather named;
+    the nest tier defaults to Mosaic kernels there."""
+    from repro.core import emit_pallas
+    monkeypatch.setattr(emit_pallas, "_on_tpu", lambda: True)
+    g = conv_design.graph_opt
+    fn = emit.to_jax_fn(g, backend="pallas")
+    assert not fn.plan.use_pallas and not fn.plan.interpret
+    assert "XLA segment bodies" in fn.plan.summary()
+    ref = emit.evaluate(g, conv_feeds)
+    out = fn(conv_feeds)
+    for k in ref:
+        np.testing.assert_allclose(np.asarray(out[k]), ref[k],
+                                   rtol=1e-5, atol=1e-4)
+    with pytest.raises(NotImplementedError, match="Mosaic.*gather"):
+        emit.to_jax_fn(g, backend="pallas", use_pallas=True)
+
+
+def test_nest_tier_on_tpu_plans_mosaic_kernels(bragg_design, monkeypatch):
+    from repro.core import emit_pallas
+    monkeypatch.setattr(emit_pallas, "_on_tpu", lambda: True)
+    for fmt in (None, "5_4"):
+        plan = bragg_design.jax_fn(backend="pallas", fmt=fmt).plan
+        assert plan.use_pallas and not plan.interpret
+        assert not plan.fallbacks
+        assert "use_pallas=True interpret=False" in plan.summary()
+        assert plan.summary().endswith("Mosaic kernels")
 
 
 def test_braggnn_dfg_tier_quantised_matches_evaluate(bragg_design,

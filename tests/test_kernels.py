@@ -27,7 +27,8 @@ def test_smallfloat_matmul_sweep(m, k, n, dtype, em):
     key = jax.random.key(m * n + em[1])
     x = _r(jax.random.fold_in(key, 0), (m, k), dtype)
     w = _r(jax.random.fold_in(key, 1), (k, n), dtype)
-    got = smallfloat_matmul(x, w, exp_bits=em[0], man_bits=em[1])
+    got = smallfloat_matmul(x, w, exp_bits=em[0], man_bits=em[1],
+                            interpret=True)
     want = smallfloat_matmul_ref(x, w, exp_bits=em[0], man_bits=em[1])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-4)
@@ -38,7 +39,7 @@ def test_smallfloat_matmul_bias_relu():
     x = _r(jax.random.fold_in(key, 0), (128, 128), jnp.float32)
     w = _r(jax.random.fold_in(key, 1), (128, 128), jnp.float32)
     b = _r(jax.random.fold_in(key, 2), (128,), jnp.float32)
-    got = smallfloat_matmul(x, w, b, fuse_relu=True)
+    got = smallfloat_matmul(x, w, b, fuse_relu=True, interpret=True)
     want = smallfloat_matmul_ref(x, w, b, fuse_relu=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-4)
@@ -53,7 +54,7 @@ def test_conv2d_vmem_sweep(b, cin, cout, img, kk, fmt):
     x = _r(jax.random.fold_in(key, 0), (b, cin, img, img), jnp.float32)
     w = _r(jax.random.fold_in(key, 1), (cout, cin, kk, kk), jnp.float32)
     bias = _r(jax.random.fold_in(key, 2), (cout,), jnp.float32)
-    got = conv2d_vmem(x, w, bias, fmt=fmt, bb=min(4, b))
+    got = conv2d_vmem(x, w, bias, fmt=fmt, interpret=True)
     want = conv2d_ref(x, w, bias, fmt=fmt)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
@@ -69,7 +70,7 @@ def test_flash_attention_sweep(s, h, kv, d, window, cap):
     k = _r(jax.random.fold_in(key, 1), (2, s, kv, d), jnp.float32)
     v = _r(jax.random.fold_in(key, 2), (2, s, kv, d), jnp.float32)
     got = fa_ops.attention(q, k, v, causal=True, window=window,
-                           logit_cap=cap, use_pallas=True)
+                           logit_cap=cap, use_pallas=True, interpret=True)
     want = fa_ops.attention(q, k, v, causal=True, window=window,
                             logit_cap=cap, use_pallas=False)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -87,7 +88,8 @@ def test_flash_attention_matches_model_blockwise():
     pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
     a = nn_attn.blockwise_attention(q, k, v, q_pos=pos, k_pos=pos,
                                     causal=True, block_size=32)
-    b = fa_ops.attention(q, k, v, causal=True, use_pallas=True)
+    b = fa_ops.attention(q, k, v, causal=True, use_pallas=True,
+                         interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
                                atol=1e-4)
 
@@ -97,7 +99,7 @@ def test_flash_attention_matches_model_blockwise():
 def test_fused_softmax_sweep(rows, cols, taylor):
     key = jax.random.key(rows + cols)
     x = _r(key, (rows, cols), jnp.float32) * 3.0
-    got = fused_softmax(x, taylor_order=taylor)
+    got = fused_softmax(x, taylor_order=taylor, interpret=True)
     want = fused_softmax_ref(x, taylor_order=taylor)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -109,6 +111,83 @@ def test_fused_softmax_taylor_close_to_true_softmax():
     true softmax to ~1e-3 on the stabilised domain."""
     key = jax.random.key(9)
     x = _r(key, (64, 96), jnp.float32) * 2.0
-    approx = fused_softmax(x, taylor_order=8)
+    approx = fused_softmax(x, taylor_order=8, interpret=True)
     true = fused_softmax_ref(x, taylor_order=0)
     assert float(jnp.max(jnp.abs(approx - true))) < 5e-3
+
+
+#: BraggNN(s=1, img=11) conv shapes at a small odd batch: (x, w, bias)
+BRAGG_CONVS = [((5, 1, 11, 11), (16, 1, 3, 3), True),
+               ((5, 16, 9, 9), (8, 16, 1, 1), False),
+               ((5, 8, 9, 9), (16, 8, 1, 1), False),
+               ((5, 16, 9, 9), (8, 16, 3, 3), True),
+               ((5, 8, 7, 7), (2, 8, 3, 3), True)]
+
+
+@pytest.mark.parametrize("fmt", [None, (5, 4)])
+@pytest.mark.parametrize("xs,ws,bias", BRAGG_CONVS,
+                         ids=["conv1", "nlb_in", "nlb_out", "conv2a",
+                              "conv2b"])
+def test_conv2d_vmem_braggnn_shapes(xs, ws, bias, fmt):
+    """The im2col contraction at BraggNN's shapes, with a row block small
+    enough that the patch rows (B·Ho·Wo) span several padded blocks."""
+    key = jax.random.key(xs[1] * 100 + ws[0])
+    x = _r(jax.random.fold_in(key, 0), xs, jnp.float32)
+    w = _r(jax.random.fold_in(key, 1), ws, jnp.float32) * 0.3
+    b = _r(jax.random.fold_in(key, 2), (ws[0],), jnp.float32) \
+        if bias else None
+    got = conv2d_vmem(x, w, b, fmt=fmt, fuse_relu=bias, bm=64,
+                      interpret=True)
+    want = conv2d_ref(x, w, b, fmt=fmt, fuse_relu=bias)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("batch", [1, 3, 4, 5])
+def test_fused_softmax_unaligned_rows(batch):
+    """B·81 rows (the NLB softmax at img=11) with blocks that do not
+    divide them: the padded rows must not leak into the result."""
+    key = jax.random.key(batch)
+    x = _r(key, (batch * 81, 81), jnp.float32) * 2.0
+    got = fused_softmax(x, taylor_order=8, block_rows=64, interpret=True)
+    want = fused_softmax_ref(x, taylor_order=8)
+    assert got.shape == x.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_smallfloat_matmul_ragged_rows():
+    """M not a multiple of the row block: rows are padded and sliced."""
+    key = jax.random.key(7)
+    x = _r(jax.random.fold_in(key, 0), (200, 50), jnp.float32)
+    w = _r(jax.random.fold_in(key, 1), (50, 16), jnp.float32)
+    got = smallfloat_matmul(x, w, exp_bits=5, man_bits=4, interpret=True)
+    want = smallfloat_matmul_ref(x, w, exp_bits=5, man_bits=4)
+    assert got.shape == (200, 16)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("fmt", ["5_11", "5_4", "5_3"])
+def test_quantize_jit_bit_exact_vs_numpy(fmt):
+    """The jitted quantiser (kernels and the DFG tier) and the numpy
+    functional model land on the same lattice point for every value:
+    powers of two are built from exponent bits, not XLA's inexact exp2."""
+    from repro.core.precision import FORMATS, quantize, quantize_np
+    rng = np.random.default_rng(0)
+    x = (rng.standard_normal(200_000)
+         * np.exp(rng.uniform(-12, 12, 200_000))).astype(np.float32)
+    x[:4] = [0.0, -0.0, np.inf, np.nan]
+    got = np.asarray(jax.jit(lambda v: quantize(v, FORMATS[fmt]))(x))
+    np.testing.assert_array_equal(got, quantize_np(x, FORMATS[fmt]))
+
+
+def test_fmac_opcode_rounds_product_separately():
+    """The DFG tier's fmac rounds the product, then the sum, exactly as
+    ``emit.evaluate`` does — XLA must not contract it into one FMA."""
+    from repro.kernels.registry import OPCODE_KERNELS
+    rng = np.random.default_rng(1)
+    a = [rng.standard_normal(100_000).astype(np.float32) for _ in range(3)]
+    got = np.asarray(jax.jit(lambda *v: OPCODE_KERNELS["fmac"][1](v))(*a))
+    np.testing.assert_array_equal(got, a[0] * a[1] + a[2])

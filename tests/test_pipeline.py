@@ -247,3 +247,34 @@ def test_session_stats_accounting():
     st3 = s.stats()
     assert st3["misses"] == 2 and st3["recompiles"] == 2
     assert st3["memory_entries"] == 2
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "checkout"])
+def test_enable_compile_cache_path(from_env, tmp_path, monkeypatch):
+    """``$JAX_COMPILATION_CACHE_DIR`` wins and no other directory is set;
+    without it the cache sits at one fixed path inside the checkout."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.core import cachedir
+    prev_dir = jax.config.jax_compilation_cache_dir
+    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        path = cachedir.enable_compile_cache()
+        if from_env:
+            assert path == tmp_path
+            assert jax.config.jax_compilation_cache_dir == prev_dir
+        else:
+            assert path == cachedir.REPO_ROOT / ".jax_cache"
+            assert (cachedir.REPO_ROOT / "src" / "repro").is_dir()
+            assert jax.config.jax_compilation_cache_dir == str(path)
+            assert cachedir.enable_compile_cache() == path   # fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev_dir)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          prev_min)
+        compilation_cache.reset_cache()
