@@ -284,7 +284,6 @@ def _segment_fn(body, idx_flat: np.ndarray, use_pallas: bool,
 
 def _lower_dfg(g: Graph, *, fmt_obj, use_pallas: bool, interpret: bool,
                opcode_table, plan: PallasPlan):
-    import jax
     import jax.numpy as jnp
     from repro.core.precision import quantize
 
@@ -299,16 +298,12 @@ def _lower_dfg(g: Graph, *, fmt_obj, use_pallas: bool, interpret: bool,
 
     n_values = g.n_values
     compiled = []
-    step_labels = []      # one label per compiled step, for profiling spans
     for kind, payload in steps:
         if kind == "segment":
             body, idx_flat = _segment_body(payload, opcode_table, q,
                                            n_values)
             compiled.append(_segment_fn(body, idx_flat, use_pallas,
                                         interpret))
-            step_labels.append(
-                f"segment{sum(1 for s in step_labels if 'segment' in s)}"
-                f"[{len(payload)} groups]")
         else:
             oc, arg_idx, res_idx = payload
             jargs = [jnp.asarray(ai) for ai in arg_idx]
@@ -322,7 +317,6 @@ def _lower_dfg(g: Graph, *, fmt_obj, use_pallas: bool, interpret: bool,
                 return buf.at[:, jres].set(r, mode="drop")
 
             compiled.append(fb)
-            step_labels.append(f"fallback[{oc}]")
     input_rank = {name: len(next(iter(g.inputs[name])))
                   for name in input_scatter}
     cval = q(jnp.asarray(const_val)) if q is not None \
@@ -357,16 +351,6 @@ def _lower_dfg(g: Graph, *, fmt_obj, use_pallas: bool, interpret: bool,
             buf = step(buf)
         return _epilogue(buf, batch)
 
-    def profile(feeds):
-        # unjitted twin of ``run``: one span + device sync per fused
-        # segment / fallback step, so the per-kernel cost is observable
-        buf, batch = _prologue(feeds)
-        for label, step in zip(step_labels, compiled):
-            with obs.span(f"pallas.{label}", cat="pallas"):
-                buf = jax.block_until_ready(step(buf))
-        return _epilogue(buf, batch)
-
-    run.profile = profile
     return run
 
 
@@ -401,7 +385,6 @@ def _lower_module(module, *, fmt_obj, fmt_tuple, use_pallas: bool,
         weight_names.extend(n.weight_memrefs())
 
     steps: list[Callable] = []   # each: (x, w: dict) -> x
-    step_labels: list[str] = []  # one per step, for profiling spans
     i = 0
     while i < len(nodes):
         node = nodes[i]
@@ -460,7 +443,6 @@ def _lower_module(module, *, fmt_obj, fmt_tuple, use_pallas: bool,
         elif isinstance(node, nng.NonLocalBlock):
             steps.append(_nlb_step(node, conv_e, sm_e, fa_e, q, fmt_tuple,
                                    kw, nlb_flash, plan))
-            step_labels.append(_node_label(node))
             fuse_relu = False
             i += 1
             continue
@@ -507,13 +489,11 @@ def _lower_module(module, *, fmt_obj, fmt_tuple, use_pallas: bool,
             steps.append(_attention_step(node, mm_e, sm_e, fa_e, q,
                                          fmt_obj, fmt_tuple, kw, nlb_flash,
                                          plan))
-            step_labels.append(_node_label(node))
             fuse_relu = False
             i += 1
             continue
         elif isinstance(node, nng.MLP):
             steps.append(_mlp_step(node, mm_e, q, fmt_obj, plan, kw))
-            step_labels.append(_node_label(node))
             fuse_relu = False
             i += 1
             continue
@@ -528,8 +508,6 @@ def _lower_module(module, *, fmt_obj, fmt_tuple, use_pallas: bool,
         else:  # pragma: no cover - ModuleGraph validates the vocabulary
             raise NotImplementedError(type(node).__name__)
         steps.append(step)
-        step_labels.append(_node_label(node) + (":relu" if fuse_relu
-                                                else ""))
         i += 2 if fuse_relu else 1
 
     # the output memref is the last allocating node's (OutputReLU rewrites
@@ -540,26 +518,13 @@ def _lower_module(module, *, fmt_obj, fmt_tuple, use_pallas: bool,
     out_shape = module.shapes()[-1]
 
     def run(x, weights):
-        for step in steps:
-            x = step(x, weights)
-        return {out_name: x.reshape((x.shape[0],) + tuple(out_shape))}
+        # runs only while jax.jit traces it: a retrace shows by name
+        with obs.span("nest.trace", cat="pallas"):
+            for step in steps:
+                x = step(x, weights)
+            return {out_name: x.reshape((x.shape[0],) + tuple(out_shape))}
 
-    def profile(x, weights):
-        # unjitted twin of ``run``: one span + device sync per registry
-        # kernel, so the per-kernel cost is observable
-        import jax
-        for label, step in zip(step_labels, steps):
-            with obs.span(f"pallas.kernel.{label}", cat="pallas"):
-                x = jax.block_until_ready(step(x, weights))
-        return {out_name: x.reshape((x.shape[0],) + tuple(out_shape))}
-
-    run.profile = profile
     return run, weight_names, out_name
-
-
-def _node_label(node) -> str:
-    return str(getattr(node, "name", None) or getattr(node, "label", None)
-               or type(node).__name__)
 
 
 def _einsum(spec, a, b):
@@ -790,7 +755,6 @@ def to_pallas_fn(g: Graph, *, module=None, fmt=None, mode: str = "auto",
         in_name = module.input_name
         in_shape = tuple(module.input_shape)
         rank = len(in_shape)
-        profiled = [False]   # first obs-enabled call runs the span'd twin
 
         def run(feeds):
             missing = [n for n in weight_names if n not in feeds]
@@ -802,14 +766,13 @@ def to_pallas_fn(g: Graph, *, module=None, fmt=None, mode: str = "auto",
             if in_shape[0] == 1:
                 # collapse the loop-nest's per-sample singleton batch axis
                 x = x.reshape((x.shape[0],) + in_shape[1:])
-            w = {name: np.asarray(feeds[name], dtype=np.float32)
-                 for name in weight_names}
-            wn = _normalize_weights(w, module)
-            if obs.enabled() and not profiled[0]:
-                profiled[0] = True
-                with obs.span("pallas.profile", cat="pallas", mode=mode):
-                    return dict(core.profile(x, wn))
-            return dict(jcore(x, wn))
+            with obs.span("nest.weights", cat="pallas"):
+                w = {name: np.asarray(feeds[name], dtype=np.float32)
+                     for name in weight_names}
+                wn = _normalize_weights(w, module)
+            # dispatch, and the host-to-device copy of every argument
+            with obs.span("nest.launch", cat="pallas"):
+                return dict(jcore(x, wn))
 
         run.plan = plan
         return run
@@ -823,16 +786,7 @@ def to_pallas_fn(g: Graph, *, module=None, fmt=None, mode: str = "auto",
                fused_scatters=plan.fused_scatters,
                fallbacks=len(plan.fallbacks))
     _plan_metrics(plan)
-    jcore = jax.jit(core)
-    profiled = [False]       # first obs-enabled call runs the span'd twin
-
-    def run(feeds):
-        if obs.enabled() and not profiled[0]:
-            profiled[0] = True
-            with obs.span("pallas.profile", cat="pallas", mode=mode):
-                return core.profile(feeds)
-        return jcore(feeds)
-
+    run = jax.jit(core)
     run.plan = plan
     return run
 

@@ -520,7 +520,12 @@ class Design:
                               **(pallas_kw or {}))
             served = pfn.plan.summary()
             fallbacks = list(pfn.plan.fallbacks)
-            run_one = lambda x: pfn(self.feeds(x))
+
+            def run_one(x):
+                with obs.span("nest.call", cat="pallas"):
+                    with obs.span("nest.feeds", cat="pallas"):
+                        feeds = self.feeds(x)
+                    return pfn(feeds)
         else:
             raise ValueError(f"unknown backend {backend!r} "
                              f"(expected 'tensor', 'simd' or 'pallas')")

@@ -16,6 +16,16 @@ environment; export the recorded run with :func:`export_chrome_trace`
 (opens in ``chrome://tracing`` / Perfetto) and summarise it with
 ``python -m repro.obs <trace.json>``.
 
+Two sinks.  While a JAX profiler session captures (``jax.profiler.trace``,
+``start_trace``), :func:`span` also opens a
+``jax.profiler.TraceAnnotation`` under the span's bare name, whether
+recording is on or not, so the program's spans land in the profile on the
+device trace's clock.  Disabled and not capturing, :func:`span` costs one
+check of the profiler's state and returns the shared no-op.  JAX is never
+imported here: forwarding starts once the program has loaded
+``jax.profiler``.  :func:`record_span` and :func:`event` stay in memory
+only (a retroactive span has no place on the profiler's timeline).
+
     from repro import obs
     obs.enable()
     with obs.span("compile", design="braggnn"):
@@ -30,12 +40,13 @@ from __future__ import annotations
 
 import os
 import pathlib
+import sys
 import time
 from typing import Any, Dict, Optional
 
 from repro.obs.logs import get_logger, setup_logging
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import NOOP_SPAN, Span, Tracer
+from repro.obs.trace import NOOP_SPAN, AnnotationSpan, Span, Tracer
 from repro.obs import export as _export
 
 __all__ = [
@@ -80,16 +91,42 @@ def reset() -> None:
 # Each returns/does nothing after a single flag check when disabled; this
 # is the contract that keeps instrumented hot paths near-free by default.
 
+#: ``jax.profiler.TraceAnnotation`` and its ``is_enabled``, once the
+#: program has loaded ``jax.profiler`` (None until then)
+_Annotation = None
+_profiler_on = None
+
+
+def _capturing() -> bool:
+    """Whether a JAX profiler session is capturing on this process."""
+    global _Annotation, _profiler_on
+    if _profiler_on is None:
+        prof = sys.modules.get("jax.profiler")
+        if prof is None or not hasattr(prof, "TraceAnnotation"):
+            return False
+        _Annotation = prof.TraceAnnotation
+        _profiler_on = _Annotation.is_enabled
+    return _profiler_on()
+
+
 def span(name: str, cat: str = "", **attrs: Any):
     """``with obs.span("passes.cse", ops=n) as sp:`` — a nested span on
-    the process tracer, or the shared no-op when disabled."""
+    the process tracer, or the shared no-op when disabled.  While a
+    profile is captured the span is also a ``TraceAnnotation(name)``."""
+    if _capturing():
+        if not _enabled:
+            return AnnotationSpan(_Annotation(name))
+        active = tracer.span(name, cat, **attrs)
+        active.annotation = _Annotation(name)
+        return active
     if not _enabled:
         return NOOP_SPAN
     return tracer.span(name, cat, **attrs)
 
 
 def record_span(name: str, t0: float, t1: float, **kwargs: Any):
-    """Retroactive span from explicit ``time.monotonic()`` bounds."""
+    """Retroactive span from explicit ``time.monotonic()`` bounds, kept
+    in memory only."""
     if not _enabled:
         return NOOP_SPAN
     return tracer.record(name, t0, t1, **kwargs)

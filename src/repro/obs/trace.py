@@ -9,6 +9,10 @@ retroactive spans can be recorded from explicit timestamps
 (``tracer.record(...)``) — that is how per-request serving spans are
 reconstructed from ``QueuedRequest`` timestamps after the fact.
 
+A span may carry a profiler annotation (``annotation``: any context
+manager, in practice ``jax.profiler.TraceAnnotation``), entered and exited
+with it; :func:`repro.obs.span` attaches one while a profile is captured.
+
 The module is stdlib-only by design: it must import (and no-op) in any
 environment the compiler runs in, including ones without jax/numpy.
 Chrome-trace rendering of the recorded spans lives in
@@ -71,16 +75,40 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
+class AnnotationSpan:
+    """A span that only forwards to a profiler annotation: what
+    ``obs.span`` returns while a profile is captured and recording is
+    off.  Nothing is kept in memory, and ``set`` does nothing."""
+
+    __slots__ = ("_annotation",)
+
+    def __init__(self, annotation: Any):
+        self._annotation = annotation
+
+    def __enter__(self) -> "AnnotationSpan":
+        self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc: Any) -> bool:
+        self._annotation.__exit__(*exc)
+        return False
+
+    def set(self, **attrs: Any) -> "AnnotationSpan":
+        return self
+
+
 class _ActiveSpan:
     """Context manager binding one ``Span`` to a ``Tracer``: entry reads
     the clock and pushes onto the thread-local nesting stack, exit pops
-    and appends the finished span to the tracer."""
+    and appends the finished span to the tracer.  A profiler
+    ``annotation``, when set, opens and closes inside those bounds."""
 
-    __slots__ = ("span", "_tracer")
+    __slots__ = ("span", "_tracer", "annotation")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self.span = span
         self._tracer = tracer
+        self.annotation: Any = None
 
     def __enter__(self) -> Span:
         stack = self._tracer._stack()
@@ -88,9 +116,13 @@ class _ActiveSpan:
             self.span.parent_id = stack[-1].span_id
         stack.append(self.span)
         self.span.t0 = time.monotonic()
+        if self.annotation is not None:
+            self.annotation.__enter__()
         return self.span
 
     def __exit__(self, *exc: Any) -> bool:
+        if self.annotation is not None:
+            self.annotation.__exit__(*exc)
         self.span.t1 = time.monotonic()
         stack = self._tracer._stack()
         if stack and stack[-1] is self.span:

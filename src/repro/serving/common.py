@@ -98,13 +98,15 @@ class RequestQueue:
     """Thread-safe FIFO of :class:`QueuedRequest` with depth telemetry.
 
     Owns rid assignment and the submit timestamp so every engine reports
-    comparable latencies.  Depth telemetry is recorded two ways: the
-    legacy ``depth_samples`` value list, and ``depth_events`` — the full
-    ``(monotonic_t, depth)`` transition log from every push/pop/requeue
-    plus any timer-driven ``sample_depth()`` calls.  ``depth_stats()``
-    integrates that step function for *time-weighted* mean/p95/max, so a
-    bursty queue that sits deep between dispatches is reported at its
-    true depth instead of only at the instants the engine touched it.
+    comparable latencies.  Depth telemetry is kept as running state in
+    bounded memory: every push/pop/requeue and every timer-driven
+    ``sample_depth()`` call is one ``(monotonic_t, depth)`` observation,
+    counted per depth (``max_depth``, ``mean_depth`` over the
+    observations) and integrated as a step function into seconds spent
+    at each depth.  ``depth_stats()`` reads that for *time-weighted*
+    mean/p95/max, so a bursty queue that sits deep between dispatches is
+    reported at its true depth instead of only at the instants the
+    engine touched it.
     ``requeue_front`` puts a failed batch back at the head *in order*,
     which is what keeps replica restarts from dropping or reordering
     in-flight requests.
@@ -115,8 +117,7 @@ class RequestQueue:
         self._cond = threading.Condition()
         self._next_rid = 0
         self.submitted = 0
-        self.depth_samples: list[int] = []
-        self.depth_events: list[tuple[float, int]] = []
+        self._reset_depth()
 
     def __len__(self) -> int:
         with self._cond:
@@ -181,11 +182,37 @@ class RequestQueue:
 
     # -- telemetry ----------------------------------------------------------
 
+    def _reset_depth(self) -> None:
+        self._depth_count: dict[int, int] = {}     # observations per depth
+        self._depth_dwell: dict[int, float] = {}   # seconds at each depth
+        self._depth_first_t: Optional[float] = None
+        self._depth_last: Optional[tuple[float, int]] = None
+
+    def _observe_depth(self, t: float, depth: int) -> None:
+        """Fold one ``(t, depth)`` observation into the running state:
+        the previous depth held from its observation until ``t``."""
+        self._depth_count[depth] = self._depth_count.get(depth, 0) + 1
+        if self._depth_last is None:
+            self._depth_first_t = t
+        else:
+            t0, d0 = self._depth_last
+            self._depth_dwell[d0] = self._depth_dwell.get(d0, 0.0) + (t - t0)
+        self._depth_last = (t, depth)
+
+    def _replay_depth_events(self, events) -> None:
+        with self._cond:
+            self._reset_depth()
+            for t, depth in events:
+                self._observe_depth(t, depth)
+
+    #: assigning a ``(monotonic_t, depth)`` log replays it into the
+    #: running state, in place of what was observed
+    depth_events = property(fset=_replay_depth_events)
+
     def _note_depth(self) -> None:
         """Record the current depth (call under ``self._cond``)."""
         depth = len(self._items)
-        self.depth_samples.append(depth)
-        self.depth_events.append((time.monotonic(), depth))
+        self._observe_depth(time.monotonic(), depth)
         obs.observe("serve.queue_depth", depth)
 
     def sample_depth(self) -> int:
@@ -198,37 +225,40 @@ class RequestQueue:
 
     @property
     def max_depth(self) -> int:
-        return max(self.depth_samples, default=0)
+        with self._cond:
+            return max(self._depth_count, default=0)
 
     @property
     def mean_depth(self) -> float:
-        return (float(np.mean(self.depth_samples))
-                if self.depth_samples else 0.0)
+        with self._cond:
+            n = sum(self._depth_count.values())
+            total = sum(d * c for d, c in self._depth_count.items())
+        return total / n if n else 0.0
 
     def depth_stats(self) -> dict[str, float]:
-        """Time-weighted depth statistics over the transition log.
+        """Time-weighted depth statistics over the observations.
 
-        Each recorded depth holds from its event until the next one; the
-        step function is integrated exactly, so 300 ms spent at depth 8
-        dominates a handful of instantaneous dispatch touches.  With
-        fewer than two events this degrades to the plain values.  Returns
-        ``{"max", "mean", "p95"}``.
+        Each observed depth holds from its observation until the next
+        one; the step function is integrated exactly, so 300 ms spent at
+        depth 8 dominates a handful of instantaneous dispatch touches.
+        With fewer than two observations, or all at one instant, this
+        degrades to the plain values.  Returns ``{"max", "mean", "p95"}``.
         """
         with self._cond:
-            events = list(self.depth_events)
-        if not events:
+            count = dict(self._depth_count)
+            weight = dict(self._depth_dwell)
+            first_t, last = self._depth_first_t, self._depth_last
+        if last is None:
             return {"max": 0, "mean": 0.0, "p95": 0.0}
-        if len(events) == 1:
-            d = float(events[0][1])
+        n = sum(count.values())
+        if n == 1:
+            d = float(last[1])
             return {"max": int(d), "mean": d, "p95": d}
-        total = events[-1][0] - events[0][0]
+        total = last[0] - first_t
         if total <= 0:
-            vals = [d for _, d in events]
-            return {"max": max(vals), "mean": float(np.mean(vals)),
+            vals = np.repeat(list(count), list(count.values()))
+            return {"max": max(count), "mean": float(np.mean(vals)),
                     "p95": float(np.percentile(vals, 95))}
-        weight: dict[int, float] = {}
-        for (t0, d), (t1, _) in zip(events, events[1:]):
-            weight[d] = weight.get(d, 0.0) + (t1 - t0)
         mean = sum(d * w for d, w in weight.items()) / total
         p95 = float(max(weight))       # fallback if rounding never trips
         acc = 0.0
@@ -237,7 +267,7 @@ class RequestQueue:
             if acc >= 0.95 * total:
                 p95 = float(d)
                 break
-        return {"max": max(d for _, d in events), "mean": mean, "p95": p95}
+        return {"max": max(count), "mean": mean, "p95": p95}
 
 
 class DropOldestRing:

@@ -427,26 +427,12 @@ class TriggerLoop:
                                     daemon=True)
         t_start = time.perf_counter()
         producer.start()
-        while True:
-            frames = self.ring.pop_many(self.window)
+        while not (done.is_set() and not len(self.ring)):
+            # from the end of one decision to the start of the next
+            with obs.span("trigger.wait", cat="trigger"):
+                frames = self._next_window(feed.frame_rate_hz, done)
             if not frames:
-                if done.is_set() and not len(self.ring):
-                    break
-                time.sleep(1e-4)
-                continue
-            if len(frames) < self.window and not done.is_set():
-                # partial window mid-stream: wait (bounded by the time the
-                # feed needs to deliver the rest, plus slack) rather than
-                # dispatching a padded window per straggler
-                deadline = time.perf_counter() + \
-                    (self.window - len(frames) + 1.0) / feed.frame_rate_hz
-                while len(frames) < self.window and \
-                        time.perf_counter() < deadline:
-                    more = self.ring.pop_many(self.window - len(frames))
-                    if more:
-                        frames.extend(more)
-                    else:
-                        time.sleep(1e-4)
+                break
             n_real = len(frames)
             t_ref = [f.arrival_t for f in frames]
             if n_real < self.window:
@@ -455,3 +441,29 @@ class TriggerLoop:
         producer.join()
         report.wall_s = time.perf_counter() - t_start
         report.dropped = self.ring.dropped
+
+    def _next_window(self, frame_rate_hz: float,
+                     done: threading.Event) -> list[Frame]:
+        """Poll the ring for the next window's frames; empty once the
+        stream has ended and the ring is drained."""
+        while True:
+            frames = self.ring.pop_many(self.window)
+            if frames:
+                break
+            if done.is_set() and not len(self.ring):
+                return []
+            time.sleep(1e-4)
+        if len(frames) < self.window and not done.is_set():
+            # partial window mid-stream: wait (bounded by the time the
+            # feed needs to deliver the rest, plus slack) rather than
+            # dispatching a padded window per straggler
+            deadline = time.perf_counter() + \
+                (self.window - len(frames) + 1.0) / frame_rate_hz
+            while len(frames) < self.window and \
+                    time.perf_counter() < deadline:
+                more = self.ring.pop_many(self.window - len(frames))
+                if more:
+                    frames.extend(more)
+                else:
+                    time.sleep(1e-4)
+        return frames
