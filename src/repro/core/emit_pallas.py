@@ -693,8 +693,11 @@ def to_pallas_fn(g: Graph, *, module=None, fmt=None, mode: str = "auto",
     The returned callable maps a feed dict (memref name -> array, weights
     batched or not) to ``{output name: (batch,) + shape}`` exactly like
     ``emit.to_jax_fn``'s emission, is internally jitted (do NOT wrap it in
-    ``jax.jit`` — the nest tier normalises weight feeds host-side), and
-    carries its :class:`PallasPlan` as ``.plan``.
+    ``jax.jit`` — the nest tier normalises fed weights host-side), and
+    carries its :class:`PallasPlan` as ``.plan``.  In the nest tier a
+    bound module's weights are normalised and put on the device once, at
+    lowering; a call then copies only its input, and a weight present in
+    the feeds takes the fed array's place for that call.
 
     ``mode='auto'`` picks the nest-pattern tier when ``module`` is given,
     else the generic DFG tier.  ``fmt`` (a FloPoCo key or ``FloatFormat``)
@@ -756,8 +759,18 @@ def to_pallas_fn(g: Graph, *, module=None, fmt=None, mode: str = "auto",
         in_shape = tuple(module.input_shape)
         rank = len(in_shape)
 
+        # the bound weights, normalised and on the device for the life of
+        # this callable (no module-level cache: they go with the design)
+        resident = {}
+        if module.params is not None:
+            resident = jax.device_put(_normalize_weights(
+                module.weight_feeds(), module))
+            obs.inc("nest.weight_uploads")
+
         def run(feeds):
-            missing = [n for n in weight_names if n not in feeds]
+            fed = [n for n in weight_names if n in feeds]
+            missing = [n for n in weight_names
+                       if n not in feeds and n not in resident]
             if missing:
                 raise KeyError(f"missing weight feeds {missing}")
             x = np.asarray(feeds[in_name], dtype=np.float32)
@@ -766,11 +779,17 @@ def to_pallas_fn(g: Graph, *, module=None, fmt=None, mode: str = "auto",
             if in_shape[0] == 1:
                 # collapse the loop-nest's per-sample singleton batch axis
                 x = x.reshape((x.shape[0],) + in_shape[1:])
-            with obs.span("nest.weights", cat="pallas"):
-                w = {name: np.asarray(feeds[name], dtype=np.float32)
-                     for name in weight_names}
-                wn = _normalize_weights(w, module)
-            # dispatch, and the host-to-device copy of every argument
+            if fed:
+                obs.inc("nest.calls_fed")
+                with obs.span("nest.weights", cat="pallas"):
+                    w = {name: np.asarray(feeds[name], dtype=np.float32)
+                         for name in fed}
+                    wn = {**resident, **_normalize_weights(w, module)}
+            else:
+                obs.inc("nest.calls_resident")
+                wn = resident
+            # dispatch, and the host-to-device copy of the input (and of
+            # any fed weights)
             with obs.span("nest.launch", cat="pallas"):
                 return dict(jcore(x, wn))
 

@@ -487,7 +487,8 @@ class Design:
         """``(run_one, served, fallbacks)`` for one serving backend.
 
         ``run_one`` takes one batch — a bare input array or a feed dict
-        for ``simd``/``pallas`` (module weights merged via :meth:`feeds`),
+        for ``simd``/``pallas`` (module weights merged via :meth:`feeds`,
+        or held on the device by the Pallas nest tier),
         the fused forward's ``(B, ...)`` array for ``tensor`` — and
         returns the outputs.  Shared by :meth:`serve` and the async
         :class:`~repro.serving.design_engine.DesignEngine`, so both paths
@@ -514,17 +515,25 @@ class Design:
             # merges any bound module weights
             run_one = lambda x: jfn(self.feeds(x))
         elif backend == "pallas":
-            # already internally jitted; the nest tier normalises weight
-            # feeds host-side, so no extra jax.jit wrapper here
+            # already internally jitted, so no extra jax.jit wrapper here.
+            # The nest tier holds the bound weights on the device, so a
+            # call passes only the input (a feed dict passes unchanged, and
+            # any weights in it are used instead); the DFG tier takes every
+            # weight in its feeds, merged via feeds()
             pfn = self.jax_fn(backend="pallas", fmt=fmt,
                               **(pallas_kw or {}))
             served = pfn.plan.summary()
             fallbacks = list(pfn.plan.fallbacks)
+            if pfn.plan.mode == "nests":
+                as_feeds = lambda x: (x if isinstance(x, dict)
+                                      else self._coerce_input(x))
+            else:
+                as_feeds = self.feeds
 
             def run_one(x):
                 with obs.span("nest.call", cat="pallas"):
                     with obs.span("nest.feeds", cat="pallas"):
-                        feeds = self.feeds(x)
+                        feeds = as_feeds(x)
                     return pfn(feeds)
         else:
             raise ValueError(f"unknown backend {backend!r} "

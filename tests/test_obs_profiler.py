@@ -3,7 +3,8 @@
 Kept in one file: a process runs one profiler session at a time.  While a
 capture runs, ``obs.span`` opens a ``TraceAnnotation`` under the span's
 name, so the nest tier's host path (``nest.call`` around ``nest.feeds``,
-``nest.weights`` and ``nest.launch``) lands on the profile's host plane.
+``nest.weights`` when the call feeds weights, and ``nest.launch``) lands on
+the profile's host plane.
 """
 
 import glob
@@ -25,6 +26,8 @@ from repro.obs.trace import NOOP_SPAN
 
 IMG = 8
 NEST = ("nest.call", "nest.feeds", "nest.weights", "nest.launch")
+#: a call on the bound weights held on the device opens no ``nest.weights``
+RESIDENT = ("nest.call", "nest.feeds", "nest.launch")
 
 
 @pytest.fixture(autouse=True)
@@ -79,19 +82,27 @@ def test_nest_spans_land_in_the_profile(design, tmp_path):
     run_one, _, _ = design._runner("pallas", None, None)
     x = np.random.default_rng(0).normal(
         0, 0.5, (2, 1, IMG, IMG)).astype(np.float32)
+    fed = design.feeds(x)                       # the weights with the call
     jax.block_until_ready(run_one(x))           # compile outside the capture
+    jax.block_until_ready(run_one(fed))
     assert not obs.enabled()
     with jax.profiler.trace(str(tmp_path), profiler_options=_options()):
         out = jax.block_until_ready(run_one(x))
+        jax.block_until_ready(run_one(fed))
     assert not obs.tracer.spans()               # recording stayed off
     ev = _host_events(tmp_path, NEST + ("nest.trace",))
-    assert [len(ev[n]) for n in NEST] == [1, 1, 1, 1]
-    (call,) = ev["nest.call"]
-    for name in NEST[1:]:
-        assert _inside(ev[name][0], call), name
-    # the feeds are merged before the nest tier's own work starts
-    assert ev["nest.feeds"][0][1] <= ev["nest.weights"][0][0] \
-        <= ev["nest.weights"][0][1] <= ev["nest.launch"][0][0]
+    assert [len(ev[n]) for n in NEST] == [2, 2, 1, 2]
+    resident, with_weights = sorted(ev["nest.call"])
+    for name in RESIDENT[1:]:
+        first, second = sorted(ev[name])
+        assert _inside(first, resident), name
+        assert _inside(second, with_weights), name
+    # only the call with fed weights prepares them, after its feeds and
+    # before its launch
+    (weights,) = ev["nest.weights"]
+    assert _inside(weights, with_weights)
+    assert sorted(ev["nest.feeds"])[1][1] <= weights[0] \
+        <= weights[1] <= sorted(ev["nest.launch"])[1][0]
     assert not ev["nest.trace"]                 # warm: no retrace
     (name,) = out
     np.testing.assert_array_equal(np.asarray(out[name]),
@@ -106,10 +117,12 @@ def test_enabled_spans_reach_both_sinks_and_a_retrace_shows(design,
     with jax.profiler.trace(str(tmp_path), profiler_options=_options()):
         jax.block_until_ready(run_one(x))
     names = [s.name for s in obs.tracer.spans()]
-    for name in NEST + ("nest.trace",):
+    for name in RESIDENT + ("nest.trace",):
         assert names.count(name) == 1, (name, names)
+    assert "nest.weights" not in names          # the weights are resident
     ev = _host_events(tmp_path, NEST + ("nest.trace",))
-    assert [len(ev[n]) for n in NEST + ("nest.trace",)] == [1] * 5
+    assert [len(ev[n]) for n in RESIDENT + ("nest.trace",)] == [1] * 4
+    assert not ev["nest.weights"]
     assert _inside(ev["nest.trace"][0], ev["nest.launch"][0])
     by_name = {s.name: s for s in obs.tracer.spans()}
     assert by_name["nest.feeds"].parent_id == by_name["nest.call"].span_id
