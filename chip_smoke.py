@@ -15,7 +15,11 @@ entry points a user calls, in one process:
 3. ``design.engine(backend="pallas")`` answers requests in every warmed
    bucket;
 4. ``design.trigger(backend="pallas", window=4)`` decides a seeded
-   ``DetectorFeed`` twice, and the two runs must agree exactly.
+   ``DetectorFeed`` twice, and the two runs must agree exactly;
+5. BraggNN(s=4), the original widths (64 -> 32 -> 8 channels, flattening
+   to 200), is trained the same way, compiled with ``hls.compile`` and
+   served once through ``design.serve(backend="pallas")`` in fp32 and at
+   ``fmt="5_4"``, checked against the float32 reference.
 
 Compile seconds and warm µs/sample at batch 64 are printed for
 information; they are host-clock readings of one run, not a benchmark.
@@ -236,6 +240,45 @@ def phase_trigger(design) -> None:
     log("trigger: the two same-seed runs decide identically")
 
 
+def phase_wide(hls) -> None:
+    """BraggNN(s=4) through ``hls.compile`` and ``Design.serve``, fp32 and
+    (5,4), against the float32 reference at the s=1 tolerances."""
+    import jax
+    import numpy as np
+
+    from repro.models import braggnn
+
+    model = braggnn.build(4, IMG)
+    params = train(model, TRAIN_STEPS)
+    t0 = time.perf_counter()
+    design = hls.compile(model.bind(params), name="braggnn_s4_smoke")
+    log(f"hls.compile s=4: {time.perf_counter() - t0:.1f} s (host clock)")
+    x, _ = braggnn.synthetic_peaks(jax.random.key(SEED + 8), BATCH, img=IMG)
+    with jax.default_matmul_precision("highest"):
+        ref = np.asarray(jax.jit(braggnn.forward)(params, x))
+    scale = float(np.max(np.abs(ref)))
+    check(scale > 0.1, "degenerate s=4 reference outputs")
+    for fmt in (None, "5_4"):
+        rep = design.serve([np.asarray(x)], backend="pallas", fmt=fmt,
+                           collect=True)
+        log(f"s=4 serve[{fmt or 'fp32'}]: {rep.served}")
+        check("Mosaic kernels" in rep.served and not rep.fallbacks,
+              f"s=4 served by {rep.served}, fallbacks {rep.fallbacks}")
+        got = out_array(rep.outputs[0], BATCH)
+        check(bool(np.all(np.isfinite(got))), "s=4 non-finite outputs")
+        err = float(np.max(np.abs(got - ref)))
+        if fmt is None:
+            log(f"s=4 fp32: max abs err vs reference {err:.3e} "
+                f"(tol {TOL_FP32_VS_REF:g})")
+            check(err <= TOL_FP32_VS_REF, "s=4 fp32 vs reference")
+        else:
+            log(f"s=4 (5,4): max abs err vs fp32 reference {err:.3e} = "
+                f"{err / scale:.3%} of max |out| "
+                f"(tol {TOL_54_VS_REF_REL:.0%})")
+            check(err / scale <= TOL_54_VS_REF_REL,
+                  "s=4 (5,4) vs fp32 reference")
+
+
 def main() -> None:
     device = device_info()
     log(f"device: {device}")
@@ -261,6 +304,7 @@ def main() -> None:
     phase_timing(fn, feeds)
     phase_engine(design, x, want)
     phase_trigger(design)
+    phase_wide(hls)
     print(json.dumps({"ok": True, "device": device}), flush=True)
 
 

@@ -89,6 +89,8 @@ class PallasPlan:
     n_segments: int = 0                        #: fused kernels (dfg tier)
     fused_scatters: int = 0                    #: scatter->gather pairs elided
     kernels: dict = dataclasses.field(default_factory=dict)
+    #: each ``Linear``'s ``smallfloat_matmul`` blocking, by node name
+    blocks: dict = dataclasses.field(default_factory=dict)
     fallbacks: list = dataclasses.field(default_factory=list)
     notes: list = dataclasses.field(default_factory=list)
 
@@ -363,6 +365,8 @@ def _lower_module(module, *, fmt_obj, fmt_tuple, use_pallas: bool,
     import jax.numpy as jnp
     from jax import lax
     from repro.core.precision import quantize
+    from repro.kernels.smallfloat_matmul.smallfloat_matmul import (
+        blocking as mm_blocking)
     from repro.nn import graph as nng
 
     if module.input_shape[0] != 1 and len(module.input_shape) != 2:
@@ -427,6 +431,8 @@ def _lower_module(module, *, fmt_obj, fmt_tuple, use_pallas: bool,
             eb = fmt_obj.exp_bits if fmt_obj is not None else None
             mb = fmt_obj.man_bits if fmt_obj is not None else None
             plan.record_kernel(mm_e.name + (":relu" if fuse_relu else ""))
+            plan.blocks[node.name] = mm_blocking(node.in_features,
+                                                 node.out_features)
 
             def step(x, w, wn=wn, bn=bn, has_b=has_b, fr=fuse_relu,
                      eb=eb, mb=mb):
@@ -819,6 +825,8 @@ def _plan_metrics(plan: PallasPlan) -> None:
     obs.inc("pallas.fallbacks", len(plan.fallbacks))
     for kname, n in plan.kernels.items():
         obs.inc(f"pallas.kernel.{kname}", n)
+    for blk in plan.blocks.values():
+        obs.inc(f"pallas.kernel.smallfloat_matmul:{blk.tag}")
 
 
 def _normalize_weights(w: dict[str, np.ndarray], module) -> dict:

@@ -9,12 +9,19 @@ inputs, and the accumulator stays wide (fp32) like the DSP48 accumulator.
 
 Grid: (M/bm, N/bn, K/bk), K innermost; the output block is revisited across
 the K dimension and accumulated in place (init at k==0), the canonical TPU
-matmul schedule.  Block shapes default to MXU-aligned (128, 128, 128).
+matmul schedule.  Block shapes default to MXU-aligned (128, 128, 128), each
+cut to its dimension where that is smaller.  Any K and N lower
+(:func:`blocking`): a dimension that its block does not divide is taken
+whole as one block, which Mosaic accepts at any extent (BraggNN(s=4)'s
+first dense layer contracts over K = 200); only where a whole block would
+not fit VMEM is the dimension zero-padded up to whole blocks instead, which
+is exact because ``quantize(0) = 0``.  Rows are always padded.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +47,47 @@ def _dot(x, w):
     return jax.lax.dot_general(x, w, (((1,), (0,)), ((), ())),
                                precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
+
+
+#: VMEM that one grid step's blocks may take (x, w, bias and output, each
+#: double-buffered) before a dimension is padded rather than taken whole;
+#: a quarter of v5e's 16 MiB scoped default, leaving the rest to the
+#: quantiser's temporaries
+VMEM_BLOCK_BYTES = 4 << 20
+
+
+class Blocking(NamedTuple):
+    """How a (K, N) weight is blocked: the K and N blocks, K and N padded
+    to whole blocks, and a tag: ``"tiled"`` where the blocks divide K and
+    N, else the dimensions taken whole or padded (``"wholek"``,
+    ``"padn"``, ``"wholek:wholen"``, ...)."""
+
+    bk: int
+    bn: int
+    kp: int
+    np_: int
+    tag: str
+
+
+def blocking(k: int, n: int, *, bm: int = 128, bn: int = 128,
+             bk: int = 128) -> Blocking:
+    """The blocks ``smallfloat_matmul`` uses for a (K, N) weight.  It does
+    not depend on the rows: the VMEM check assumes a full ``bm`` block."""
+    bk, bn = min(bk, k), min(bn, n)
+
+    def fits(bk_, bn_):
+        return 2 * 4 * (bm * bk_ + bk_ * bn_ + bn_ + bm * bn_) \
+            <= VMEM_BLOCK_BYTES
+
+    tags = []
+    if k % bk:
+        bk = k if fits(k, bn) else bk
+        tags.append("wholek" if bk == k else "padk")
+    if n % bn:
+        bn = n if fits(bk, n) else bn
+        tags.append("wholen" if bn == n else "padn")
+    return Blocking(bk, bn, -(-k // bk) * bk, -(-n // bn) * bn,
+                    ":".join(tags) or "tiled")
 
 
 def _matmul_kernel(x_ref, w_ref, b_ref, o_ref, *, exp_bits, man_bits,
@@ -73,23 +121,24 @@ def smallfloat_matmul(x: jax.Array, w: jax.Array, b=None, *,
     """x: (M, K), w: (K, N), b: (N,) or None  ->  (M, N) fp32.
 
     Rows are zero-padded up to a whole number of ``bm`` blocks and the
-    padding is sliced off, so any batch size lowers.
+    padding is sliced off, so any batch size lowers; K and N are blocked
+    as :func:`blocking` says.
     """
     m, kdim = x.shape
     k2, n = w.shape
     if kdim != k2:
         raise ValueError(f"contraction dims differ: x {x.shape}, w {w.shape}")
+    blk = blocking(kdim, n, bm=bm, bn=bn, bk=bk)
+    bn, bk, kp, np_ = blk.bn, blk.bk, blk.kp, blk.np_
     bm = min(bm, m)
-    bn = min(bn, n)
-    bk = min(bk, kdim)
-    if n % bn or kdim % bk:
-        raise ValueError(f"N and K must tile evenly: (N, K) = {(n, kdim)}, "
-                         f"blocks {(bn, bk)}")
     mp = -(-m // bm) * bm
-    if mp != m:
-        x = jnp.pad(x, ((0, mp - m), (0, 0)))
-    n_k = kdim // bk
-    grid = (mp // bm, n // bn, n_k)
+    if (mp, kp) != (m, kdim):
+        x = jnp.pad(x, ((0, mp - m), (0, kp - kdim)))
+    if (kp, np_) != (kdim, n):
+        w = jnp.pad(w, ((0, kp - kdim), (0, np_ - n)))
+        b = None if b is None else jnp.pad(b, (0, np_ - n))
+    n_k = kp // bk
+    grid = (mp // bm, np_ // bn, n_k)
 
     in_specs = [
         pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
@@ -99,7 +148,7 @@ def smallfloat_matmul(x: jax.Array, w: jax.Array, b=None, *,
     if b is not None:
         # bias kept 2-D: TPU VMEM tiles are (sublane, lane)-shaped
         in_specs.append(pl.BlockSpec((1, bn), lambda i, j, k: (0, j)))
-        args.append(b.reshape(1, n))
+        args.append(b.reshape(1, np_))
 
     kernel = functools.partial(
         _matmul_kernel if b is not None else _matmul_kernel_nobias,
@@ -109,10 +158,10 @@ def smallfloat_matmul(x: jax.Array, w: jax.Array, b=None, *,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, n), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         interpret=interpret,
     )(*args)
-    return out[:m] if mp != m else out
+    return out[:m, :n] if (mp, np_) != (m, n) else out
 
 
 def _matmul_kernel_nobias(x_ref, w_ref, o_ref, **kw):

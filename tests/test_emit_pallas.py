@@ -156,6 +156,32 @@ def test_braggnn_nest_tier_pallas_interpret_matches_evaluate(bragg_design,
                                    rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("s,blocks", [
+    # s=1 keeps the blocking it always had: each dimension one block
+    (1, [(50, 16, "tiled"), (16, 8, "tiled"), (8, 4, "tiled"),
+         (4, 2, "tiled")]),
+    # s=4: K = 200 divides by no 128-block and is taken whole
+    (4, [(200, 64, "wholek"), (64, 32, "tiled"), (32, 16, "tiled"),
+         (16, 2, "tiled")]),
+])
+def test_nest_tier_records_matmul_blocking(s, blocks):
+    from repro import obs
+    obs.enable()
+    obs.reset()
+    try:
+        fn = to_pallas_fn(None, module=braggnn.build(s, 11), mode="nests")
+        counters = obs.snapshot()["counters"]
+    finally:
+        obs.disable()
+        obs.reset()
+    got = [(b.bk, b.bn, b.tag) for b in fn.plan.blocks.values()]
+    assert list(fn.plan.blocks) == [f"dense{i}" for i in range(4)]
+    assert got == blocks
+    for tag in {t for _, _, t in blocks}:
+        assert counters[f"pallas.kernel.smallfloat_matmul:{tag}"] == \
+            sum(t == tag for _, _, t in blocks)
+
+
 def test_dfg_tier_on_tpu_runs_xla_bodies(conv_design, conv_feeds,
                                          monkeypatch):
     """On the TPU the DFG tier runs XLA segment bodies by rule, and

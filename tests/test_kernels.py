@@ -12,7 +12,8 @@ from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.fused_softmax.fused_softmax import fused_softmax
 from repro.kernels.fused_softmax.ref import fused_softmax_ref
 from repro.kernels.smallfloat_matmul.ref import smallfloat_matmul_ref
-from repro.kernels.smallfloat_matmul.smallfloat_matmul import smallfloat_matmul
+from repro.kernels.smallfloat_matmul.smallfloat_matmul import (
+    blocking, smallfloat_matmul)
 
 
 def _r(key, shape, dtype):
@@ -165,6 +166,32 @@ def test_smallfloat_matmul_ragged_rows():
     got = smallfloat_matmul(x, w, exp_bits=5, man_bits=4, interpret=True)
     want = smallfloat_matmul_ref(x, w, exp_bits=5, man_bits=4)
     assert got.shape == (200, 16)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("k,n,tag", [
+    (200, 64, "wholek"),      # BraggNN(s=4)'s dense0
+    (150, 48, "wholek"),
+    (64, 200, "wholen"),
+    (4300, 2, "padk"),        # a whole K block would not fit VMEM
+    (8, 4100, "padn"),
+])
+@pytest.mark.parametrize("fmt", [None, (5, 4)], ids=["fp32", "5_4"])
+def test_smallfloat_matmul_untiled_k_and_n(k, n, tag, fmt):
+    """K or N that no 128-block divides: taken whole as one block, or
+    zero-padded where a whole block would not fit VMEM; rows ragged too."""
+    eb, mb = fmt if fmt is not None else (None, None)
+    assert blocking(k, n).tag == tag
+    key = jax.random.key(k * n)
+    x = _r(jax.random.fold_in(key, 0), (300, k), jnp.float32) / np.sqrt(k)
+    w = _r(jax.random.fold_in(key, 1), (k, n), jnp.float32)
+    b = _r(jax.random.fold_in(key, 2), (n,), jnp.float32)
+    got = smallfloat_matmul(x, w, b, exp_bits=eb, man_bits=mb,
+                            fuse_relu=True, interpret=True)
+    want = smallfloat_matmul_ref(x, w, b, exp_bits=eb, man_bits=mb,
+                                 fuse_relu=True)
+    assert got.shape == (300, n)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-4)
 

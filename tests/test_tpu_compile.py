@@ -4,8 +4,9 @@ No chip is attached: the TPU compiler (Mosaic for the kernels, XLA for the
 program around them) compiles for a v5e described by its topology.  This
 catches what interpret mode cannot — block shapes the TPU tiling refuses,
 in-kernel reshapes across the lane axis, operations with no Mosaic
-lowering — at BraggNN(s=1, img=11)'s real shapes.  Nothing runs, so these
-tests say nothing about results or times.
+lowering — at BraggNN(img=11)'s real shapes, at s=1 and at s=4 (the
+original widths).  Nothing runs, so these tests say nothing about results
+or times.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and a test worker
@@ -39,10 +40,18 @@ CONVS = [
     ("nlb.out_cnn", (BATCH, 8, 9, 9), (16, 8, 1, 1), False),
     ("conv2a", (BATCH, 16, 9, 9), (8, 16, 3, 3), True),
     ("conv2b", (BATCH, 8, 7, 7), (2, 8, 3, 3), True),
+    # s=4: 64 -> 32 -> 8 channels; conv2a's patch block is (512, 576)
+    ("s4.conv1", (BATCH, 1, 11, 11), (64, 1, 3, 3), True),
+    ("s4.nlb.theta", (BATCH, 64, 9, 9), (32, 64, 1, 1), False),
+    ("s4.nlb.out_cnn", (BATCH, 32, 9, 9), (64, 32, 1, 1), False),
+    ("s4.conv2a", (BATCH, 64, 9, 9), (32, 64, 3, 3), True),
+    ("s4.conv2b", (BATCH, 32, 7, 7), (8, 32, 3, 3), True),
 ]
 
-#: BraggNN(s=1, img=11) dense layers: (K, N)
-DENSES = [(50, 16), (16, 8), (8, 4), (4, 2)]
+#: BraggNN dense layers, s=1 then s=4: (K, N); s=4's K = 200 is blocked
+#: whole (no 128-block divides it)
+DENSES = [(50, 16), (16, 8), (8, 4), (4, 2),
+          (200, 64), (64, 32), (32, 16), (16, 2)]
 
 FMTS = [None, (5, 4)]
 
@@ -121,11 +130,13 @@ def test_fused_softmax_compiles(one_chip, batch):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("fmt", [None, "5_4"], ids=["fp32", "5_4"])
-def test_braggnn_nest_tier_compiles(one_chip, fmt):
-    """The whole jitted nest-tier BraggNN(s=1, img=11) program, as
+@pytest.mark.parametrize("s,fmt", [(1, None), (1, "5_4"), (4, None),
+                                   (4, "5_4")],
+                         ids=["fp32", "5_4", "s4-fp32", "s4-5_4"])
+def test_braggnn_nest_tier_compiles(one_chip, s, fmt):
+    """The whole jitted nest-tier BraggNN(s, img=11) program, as
     ``Design.jax_fn(backend='pallas')`` runs it on the chip."""
-    m = braggnn.build(1, 11)
+    m = braggnn.build(s, 11)
     module = m.bind(m.init_params(jax.random.PRNGKey(0)))
     fmt_obj = FORMATS[fmt] if fmt is not None else None
     fmt_tuple = ((fmt_obj.exp_bits, fmt_obj.man_bits)
