@@ -91,6 +91,8 @@ class PallasPlan:
     kernels: dict = dataclasses.field(default_factory=dict)
     #: each ``Linear``'s ``smallfloat_matmul`` blocking, by node name
     blocks: dict = dataclasses.field(default_factory=dict)
+    #: each conv's ``conv2d_vmem`` form (``conv_form``), by node name
+    convs: dict = dataclasses.field(default_factory=dict)
     fallbacks: list = dataclasses.field(default_factory=list)
     notes: list = dataclasses.field(default_factory=list)
 
@@ -365,6 +367,7 @@ def _lower_module(module, *, fmt_obj, fmt_tuple, use_pallas: bool,
     import jax.numpy as jnp
     from jax import lax
     from repro.core.precision import quantize
+    from repro.kernels.conv2d_vmem.conv2d_vmem import conv_form
     from repro.kernels.smallfloat_matmul.smallfloat_matmul import (
         blocking as mm_blocking)
     from repro.nn import graph as nng
@@ -384,6 +387,7 @@ def _lower_module(module, *, fmt_obj, fmt_tuple, use_pallas: bool,
         else (lambda x: x)
 
     nodes = list(module.nodes)
+    in_shapes = [tuple(module.input_shape)] + module.shapes()[:-1]
     weight_names: list[str] = []
     for n in nodes:
         weight_names.extend(n.weight_memrefs())
@@ -401,6 +405,9 @@ def _lower_module(module, *, fmt_obj, fmt_tuple, use_pallas: bool,
             if node.stride == 1 and node.padding == 0:
                 plan.record_kernel(conv_e.name + (":relu" if fuse_relu
                                                  else ""))
+                _, cin, h, wd = in_shapes[i]
+                plan.convs[node.name] = conv_form(
+                    cin, h, wd, node.out_channels, node.kernel, node.kernel)
 
                 def step(x, w, wn=wn, bn=bn, has_b=has_b, fr=fuse_relu):
                     return q(conv_e.fn(x, w[wn], w[bn] if has_b else None,
@@ -447,6 +454,12 @@ def _lower_module(module, *, fmt_obj, fmt_tuple, use_pallas: bool,
                 y = sm_e.fn(x, taylor_order=node.taylor_order, **kw)
                 return jnp.maximum(y, 0.0) if fr else y
         elif isinstance(node, nng.NonLocalBlock):
+            _, c1, h, wd = in_shapes[i]
+            c2 = node.mid_channels
+            for sub, cin, cout in (("theta", c1, c2), ("phi", c1, c2),
+                                   ("g", c1, c2), ("out_cnn", c2, c1)):
+                plan.convs[f"{node.name}.{sub}"] = conv_form(
+                    cin, h, wd, cout, 1, 1)
             steps.append(_nlb_step(node, conv_e, sm_e, fa_e, q, fmt_tuple,
                                    kw, nlb_flash, plan))
             fuse_relu = False
@@ -827,6 +840,8 @@ def _plan_metrics(plan: PallasPlan) -> None:
         obs.inc(f"pallas.kernel.{kname}", n)
     for blk in plan.blocks.values():
         obs.inc(f"pallas.kernel.smallfloat_matmul:{blk.tag}")
+    for form in plan.convs.values():
+        obs.inc(f"pallas.kernel.conv2d_vmem:{form}")
 
 
 def _normalize_weights(w: dict[str, np.ndarray], module) -> dict:

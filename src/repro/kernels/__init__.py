@@ -8,7 +8,9 @@ Each kernel directory holds:
   ref.py    — the pure-jnp oracle
 
 smallfloat_matmul — reduced-precision MAC array (paper §4.2)
-conv2d_vmem       — weights-resident BraggNN conv (paper's no-BRAM result)
+conv2d_vmem       — weights-resident BraggNN conv (paper's no-BRAM result):
+                    small 3×3 convs unrolled into one lane-dense
+                    contraction, the rest as im2col
 flash_attention   — blockwise attention (32k prefill path)
 fused_softmax     — fused softmax incl. Taylor-exp mode (paper §3/§4.1)
 

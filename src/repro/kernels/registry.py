@@ -91,8 +91,9 @@ def _register_exemplars() -> None:
         kernel=_conv_mod.conv2d_vmem,
         oracle=_conv_ref.conv2d_ref,
         accelerates=("Conv2d", "nlb.conv1x1"),
-        description="weights-resident valid conv, optional fused ReLU + "
-                    "(wE,wF) operand quantisation"))
+        description="weights-resident valid conv as one contraction "
+                    "(small 3x3 convs unrolled, the rest im2col), optional "
+                    "fused ReLU + (wE,wF) operand quantisation"))
     register(KernelEntry(
         name="smallfloat_matmul",
         fn=_mm_ops.matmul,

@@ -182,6 +182,27 @@ def test_nest_tier_records_matmul_blocking(s, blocks):
             sum(t == tag for _, _, t in blocks)
 
 
+@pytest.mark.parametrize("s", [1, 4])
+def test_nest_tier_records_conv_forms(s):
+    """BraggNN's three 3×3 convs take the unrolled form and the non-local
+    block's four 1×1 convs im2col, at s=1 and at s=4."""
+    from repro import obs
+    obs.enable()
+    obs.reset()
+    try:
+        fn = to_pallas_fn(None, module=braggnn.build(s, 11), mode="nests")
+        counters = obs.snapshot()["counters"]
+    finally:
+        obs.disable()
+        obs.reset()
+    assert fn.plan.convs == {
+        "conv1": "unrolled", "nlb.theta": "im2col", "nlb.phi": "im2col",
+        "nlb.g": "im2col", "nlb.out_cnn": "im2col", "conv2a": "unrolled",
+        "conv2b": "unrolled"}
+    assert counters["pallas.kernel.conv2d_vmem:unrolled"] == 3
+    assert counters["pallas.kernel.conv2d_vmem:im2col"] == 4
+
+
 def test_dfg_tier_on_tpu_runs_xla_bodies(conv_design, conv_feeds,
                                          monkeypatch):
     """On the TPU the DFG tier runs XLA segment bodies by rule, and

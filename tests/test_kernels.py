@@ -6,7 +6,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.conv2d_vmem.conv2d_vmem import conv2d_vmem
+from repro.core.precision import FloatFormat, quantize
+from repro.kernels.conv2d_vmem import conv2d_vmem as conv_mod
+from repro.kernels.conv2d_vmem.conv2d_vmem import (
+    conv2d_vmem, conv_form, unroll_weights)
 from repro.kernels.conv2d_vmem.ref import conv2d_ref
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.fused_softmax.fused_softmax import fused_softmax
@@ -48,7 +51,7 @@ def test_smallfloat_matmul_bias_relu():
 
 
 @pytest.mark.parametrize("b,cin,cout,img,kk", [
-    (8, 1, 16, 11, 3), (4, 3, 8, 9, 3), (2, 16, 8, 9, 1)])
+    (8, 1, 16, 11, 3), (4, 3, 8, 9, 3), (2, 16, 8, 9, 1), (2, 1, 4, 64, 3)])
 @pytest.mark.parametrize("fmt", [None, (5, 4)])
 def test_conv2d_vmem_sweep(b, cin, cout, img, kk, fmt):
     key = jax.random.key(b * img)
@@ -130,8 +133,9 @@ BRAGG_CONVS = [((5, 1, 11, 11), (16, 1, 3, 3), True),
                          ids=["conv1", "nlb_in", "nlb_out", "conv2a",
                               "conv2b"])
 def test_conv2d_vmem_braggnn_shapes(xs, ws, bias, fmt):
-    """The im2col contraction at BraggNN's shapes, with a row block small
-    enough that the patch rows (B·Ho·Wo) span several padded blocks."""
+    """BraggNN's convs in the form ``conv_form`` gives them (the 3×3 ones
+    unrolled, the 1×1 ones as im2col, whose patch rows B·Ho·Wo then span
+    several padded row blocks)."""
     key = jax.random.key(xs[1] * 100 + ws[0])
     x = _r(jax.random.fold_in(key, 0), xs, jnp.float32)
     w = _r(jax.random.fold_in(key, 1), ws, jnp.float32) * 0.3
@@ -218,3 +222,106 @@ def test_fmac_opcode_rounds_product_separately():
     a = [rng.standard_normal(100_000).astype(np.float32) for _ in range(3)]
     got = np.asarray(jax.jit(lambda *v: OPCODE_KERNELS["fmac"][1](v))(*a))
     np.testing.assert_array_equal(got, a[0] * a[1] + a[2])
+
+
+#: BraggNN's 3×3 convs, s=1 then s=4: (Cin, H, Cout)
+BRAGG_3X3 = [(1, 11, 16), (16, 9, 8), (8, 7, 2),
+             (1, 11, 64), (64, 9, 32), (32, 7, 8)]
+BRAGG_3X3_IDS = ["conv1", "conv2a", "conv2b",
+                 "s4.conv1", "s4.conv2a", "s4.conv2b"]
+
+
+def _bragg_3x3_inputs(cin, h, cout, batch):
+    key = jax.random.key(cin * 100 + cout)
+    x = _r(jax.random.fold_in(key, 0), (batch, cin, h, h), jnp.float32)
+    w = _r(jax.random.fold_in(key, 1), (cout, cin, 3, 3), jnp.float32) \
+        / np.sqrt(cin)
+    b = _r(jax.random.fold_in(key, 2), (cout,), jnp.float32)
+    return x, w, b
+
+
+@pytest.mark.parametrize("fmt", [None, (5, 4)])
+@pytest.mark.parametrize("cin,h,cout", BRAGG_3X3, ids=BRAGG_3X3_IDS)
+def test_conv2d_vmem_unrolled_braggnn(cin, h, cout, fmt):
+    """The unrolled form at BraggNN's 3×3 shapes, s=1 and s=4, against the
+    oracle, with 11 samples in row blocks of 8 (the last one padded)."""
+    assert conv_form(cin, h, h, cout, 3, 3) == "unrolled"
+    x, w, b = _bragg_3x3_inputs(cin, h, cout, 11)
+    got = conv2d_vmem(x, w, b, fmt=fmt, fuse_relu=True, bm=8,
+                      interpret=True)
+    want = conv2d_ref(x, w, b, fmt=fmt, fuse_relu=True)
+    assert got.shape == want.shape == (11, cout, h - 2, h - 2)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.fixture
+def im2col_only(monkeypatch):
+    """Every conv lowered as im2col: no unrolled matrix fits a zero
+    budget.  The jitted wrapper's traces are dropped on both sides, since
+    the form is fixed when it traces."""
+    monkeypatch.setattr(conv_mod, "UNROLL_BYTES", 0)
+    conv2d_vmem.clear_cache()
+    yield
+    conv2d_vmem.clear_cache()
+
+
+@pytest.mark.parametrize("fmt", [None, (5, 4)])
+@pytest.mark.parametrize("cin,h,cout", BRAGG_3X3[:3], ids=BRAGG_3X3_IDS[:3])
+def test_conv2d_vmem_im2col_3x3(im2col_only, cin, h, cout, fmt):
+    """The im2col form still serves a 3×3 conv (one whose unrolled matrix
+    would not fit), at BraggNN(s=1)'s shapes."""
+    assert conv_form(cin, h, h, cout, 3, 3) == "im2col"
+    x, w, b = _bragg_3x3_inputs(cin, h, cout, 5)
+    got = conv2d_vmem(x, w, b, fmt=fmt, fuse_relu=True, bm=64,
+                      interpret=True)
+    want = conv2d_ref(x, w, b, fmt=fmt, fuse_relu=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("cin,h,cout", [(2, 5, 3), (3, 6, 2)])
+def test_unroll_weights_holds_w_bit_for_bit(cin, h, cout):
+    """Each tap's weight sits where the tap meets its output pixel, bit
+    for bit (a -0.0 keeps its sign), and every other entry is +0.0."""
+    kh = kw = 3
+    w = np.array(_r(jax.random.key(h), (cout, cin, kh, kw), jnp.float32))
+    w[0, 0, 1, 2] = -0.0
+    t = np.asarray(unroll_weights(jnp.asarray(w), h, h))
+    ho = wo = h - 2
+    assert t.shape == (cin * h * h, cout * ho * wo)
+    want = np.zeros_like(t)
+    hit = np.zeros(t.shape, bool)
+    for co in range(cout):
+        for ci in range(cin):
+            for i in range(kh):
+                for j in range(kw):
+                    for y in range(ho):
+                        for x in range(wo):
+                            r = (ci * h + y + i) * h + x + j
+                            c = (co * ho + y) * wo + x
+                            want[r, c] = w[co, ci, i, j]
+                            hit[r, c] = True
+    np.testing.assert_array_equal(t.view(np.uint32), want.view(np.uint32))
+    assert hit.sum() == cout * cin * kh * kw * ho * wo
+    assert not t.view(np.uint32)[~hit].any()
+
+
+def test_quantize_keeps_signed_zeros():
+    """(5,4) maps ±0 to ±0, so quantising the weights before they are
+    unrolled equals quantising the unrolled matrix."""
+    z = jnp.asarray([0.0, -0.0], jnp.float32)
+    got = np.asarray(quantize(z, FloatFormat(5, 4)))
+    np.testing.assert_array_equal(got.view(np.uint32),
+                                  np.asarray(z).view(np.uint32))
+
+
+@pytest.mark.parametrize("cin,h,cout,k,form", [
+    *[(c, h, o, 3, "unrolled") for c, h, o in BRAGG_3X3],
+    (16, 9, 8, 1, "im2col"), (8, 9, 16, 1, "im2col"),
+    (64, 9, 32, 1, "im2col"), (32, 9, 64, 1, "im2col"),
+    (1, 64, 16, 3, "im2col"),
+], ids=[*BRAGG_3X3_IDS, "nlb_in", "nlb_out", "s4.nlb_in", "s4.nlb_out",
+        "img64"])
+def test_conv_form(cin, h, cout, k, form):
+    assert conv_form(cin, h, h, cout, k, k) == form

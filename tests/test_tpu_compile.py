@@ -14,6 +14,7 @@ that is not given this file must not try.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -32,6 +33,9 @@ from repro.models import braggnn  # noqa: E402
 
 #: the batch the whole-program compile uses (the benchmark's µs/sample batch)
 BATCH = 64
+
+#: the batch of the benchmark's offline cells
+OFFLINE_BATCH = 4096
 
 #: BraggNN(s=1, img=11) convs: (name, x shape, w shape, has bias)
 CONVS = [
@@ -130,12 +134,8 @@ def test_fused_softmax_compiles(one_chip, batch):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("s,fmt", [(1, None), (1, "5_4"), (4, None),
-                                   (4, "5_4")],
-                         ids=["fp32", "5_4", "s4-fp32", "s4-5_4"])
-def test_braggnn_nest_tier_compiles(one_chip, s, fmt):
-    """The whole jitted nest-tier BraggNN(s, img=11) program, as
-    ``Design.jax_fn(backend='pallas')`` runs it on the chip."""
+def _lower_braggnn(s, fmt):
+    """The nest-tier BraggNN(s, img=11) core, its bound module and plan."""
     m = braggnn.build(s, 11)
     module = m.bind(m.init_params(jax.random.PRNGKey(0)))
     fmt_obj = FORMATS[fmt] if fmt is not None else None
@@ -147,11 +147,48 @@ def test_braggnn_nest_tier_compiles(one_chip, s, fmt):
         module, fmt_obj=fmt_obj, fmt_tuple=fmt_tuple, use_pallas=True,
         interpret=False, nlb_flash=False, plan=plan)
     assert not plan.fallbacks
-    for kname in ("conv2d_vmem", "smallfloat_matmul", "fused_softmax"):
-        assert any(k.startswith(kname) for k in plan.kernels), plan.kernels
     weights = {name: tuple(np.shape(v))
                for name, v in module.weight_feeds().items()}
     assert sorted(weights) == sorted(weight_names)
+    return core, module, plan, weights
+
+
+@pytest.mark.parametrize("s,fmt", [(1, None), (1, "5_4"), (4, None),
+                                   (4, "5_4")],
+                         ids=["fp32", "5_4", "s4-fp32", "s4-5_4"])
+def test_braggnn_nest_tier_compiles(one_chip, s, fmt):
+    """The whole jitted nest-tier BraggNN(s, img=11) program, as
+    ``Design.jax_fn(backend='pallas')`` runs it on the chip."""
+    core, module, plan, weights = _lower_braggnn(s, fmt)
+    for kname in ("conv2d_vmem", "smallfloat_matmul", "fused_softmax"):
+        assert any(k.startswith(kname) for k in plan.kernels), plan.kernels
     text = _compile(core, one_chip, (BATCH,) + module.input_shape[1:],
                     weights)
     assert text.count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("fmt", FMTS, ids=["fp32", "5_4"])
+def test_conv2d_vmem_unrolled_compiles_at_offline_batch(one_chip, fmt):
+    """s=4's conv2a unrolled at the offline cells' batch: its 5184 × 1568
+    matrix (32.5 MB) is blocked in N to fit VMEM."""
+    def fn(x, w, b):
+        return conv2d_vmem(x, w, b, fmt=fmt, fuse_relu=True)
+    text = _compile(fn, one_chip, (OFFLINE_BATCH, 64, 9, 9), (32, 64, 3, 3),
+                    (32,))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("s,fmt", [(1, None), (1, "5_4"), (4, None)],
+                         ids=["fp32", "5_4", "s4-fp32"])
+def test_braggnn_nest_tier_compiles_at_offline_batch(one_chip, s, fmt):
+    """The whole program at the offline cells' batch: one ``conv2d_vmem``
+    call per conv layer (7), and no im2col patch matrix for the 3×3
+    convs, which take the unrolled form."""
+    core, module, plan, weights = _lower_braggnn(s, fmt)
+    b, c1, c2 = OFFLINE_BATCH, 16 * s, 8 * s
+    text = _compile(core, one_chip, (b,) + module.input_shape[1:], weights)
+    assert len(re.findall(r"%conv2d_vmem(?:\.\d+)? = \S+ custom-call\(",
+                          text)) == 7
+    for patches in (f"f32[{b * 81},9]", f"f32[{b * 49},{c1 * 9}]",
+                    f"f32[{b},7,7,{c1},9]", f"f32[{b * 25},{c2 * 9}]"):
+        assert patches not in text
