@@ -7,16 +7,12 @@ Reproduces, per precision ((5,11) -> (5,4) -> (5,3)):
   * an Alveo-U280-capacity schedule (DSP pool capped at 9024) — the
     apples-to-apples capacity point against the paper's device;
   * the SLL-crossing wire count that forced (5,4) -> (5,3) (§4.2);
-  * behavioural accuracy of the quantised functional model vs fp32;
-  * measured CPU throughput of the deployable artifacts, one figure per
-    serving backend: the emitted SIMD design, the fused tensor path (jit),
-    and the Pallas emission backend (registry kernels over the bridged
-    nests), fp32 and (5,4).
+  * behavioural accuracy of the quantised functional model vs fp32.
+
+Serving speed is measured on the chip by ``bench/``, not here.
 """
 
 from __future__ import annotations
-
-import time
 
 import numpy as np
 
@@ -94,60 +90,6 @@ def run(s: int = 1, img: int = 11) -> dict:
         denom = np.abs(ref).max() + 1e-9
         out["quant_err"][key] = float(np.abs(q - ref).max() / denom)
 
-    # measured CPU throughput of the deployable paths, per backend
-    fn = design.jax_fn()
-    batch = 64
-    feeds_b = verify.random_feeds(g_raw, batch=batch, seed=1, scale=0.4)
-    import jax
-    jfn = jax.jit(fn)
-    o = jfn(feeds_b)
-    jax.block_until_ready(o)
-    t0 = time.perf_counter()
-    for _ in range(5):
-        jax.block_until_ready(jfn(feeds_b))
-    out["simd_us_per_sample_cpu"] = (time.perf_counter() - t0) / (
-        5 * batch) * 1e6
-
-    from repro.models import braggnn as bnn
-    params = bnn.params_from_feeds(feeds_b, s=s)
-    # feeds carry (batch,) + memref shape (1, 1, img, img): collapse the
-    # per-sample singleton batch of the memref into the throughput batch
-    x = np.asarray(feeds_b["input"]).reshape(batch, 1, img, img)
-    tfn = jax.jit(lambda p, xx: bnn.forward(p, xx, s=s, fmt="5_4"))
-    jax.block_until_ready(tfn(params, x))
-    t0 = time.perf_counter()
-    for _ in range(20):
-        jax.block_until_ready(tfn(params, x))
-    out["tensor_us_per_sample_cpu"] = (time.perf_counter() - t0) / (
-        20 * batch) * 1e6
-
-    # Pallas emission backend (nest-pattern tier through the kernel
-    # registry).  Weight feeds must be shared across the batch (the
-    # random_feeds weights vary per sample), so rebuild them from the
-    # same params the tensor path uses.
-    module = bnn.build(s, img=img, params=params)
-    pfeeds = dict(module.weight_feeds())
-    pfeeds["input"] = np.asarray(feeds_b["input"])
-
-    def _time_pallas(fmt):
-        pfn = emit.to_jax_fn(g, backend="pallas", module=module, fmt=fmt)
-        jax.block_until_ready(pfn(pfeeds)["dense_3_out"])
-        t0 = time.perf_counter()
-        for _ in range(20):
-            jax.block_until_ready(pfn(pfeeds)["dense_3_out"])
-        return (time.perf_counter() - t0) / (20 * batch) * 1e6, pfn.plan
-
-    out["pallas_us_per_sample_cpu"], plan = _time_pallas(None)
-    pallas_54_us, _ = _time_pallas("5_4")
-    out["pallas_plan"] = plan.summary()
-    #: one µs/sample figure per serving backend (tensor + pallas_5_4 run
-    #: the (5,4) quantised model; simd + pallas are the fp32 designs)
-    out["backends"] = {
-        "simd": round(out["simd_us_per_sample_cpu"], 1),
-        "tensor": round(out["tensor_us_per_sample_cpu"], 1),
-        "pallas": round(out["pallas_us_per_sample_cpu"], 1),
-        "pallas_5_4": round(pallas_54_us, 1),
-    }
     return out
 
 
@@ -172,9 +114,6 @@ def main(print_csv: bool = True, s: int = 1, img: int = 11) -> dict:
         log.info("# quant rel-err vs fp32: %s",
                  ", ".join(f"{k}={v:.4f}"
                            for k, v in out["quant_err"].items()))
-        log.info("# CPU throughput (us/sample): %s",
-                 ", ".join(f"{k}={v}" for k, v in out["backends"].items()))
-        log.info("# pallas plan: %s", out["pallas_plan"])
     return out
 
 

@@ -1,14 +1,14 @@
 """Serving benchmark: sustained QPS + tail latency under bursty open load.
 
     PYTHONPATH=src python -m benchmarks.bench_serving [--fast]
-        [--backends tensor,pallas] [--assert-healthy]
+        [--assert-healthy]
 
 The paper's headline is µs/sample in a warm loop; a deployed detector
 pipeline instead sees an *open-loop* arrival process — requests arrive on
 the experiment's clock whether or not the replica keeps up.  This bench
 drives :class:`repro.serving.design_engine.DesignEngine` over a compiled
 BraggNN(s=1) with a seeded bursty schedule (Poisson base rate with
-periodic burst windows) and reports, per serving backend:
+periodic burst windows) and reports:
 
   * sustained QPS (completed / span of completions),
   * p50/p95/p99 per-request latency (queueing + batching + compute),
@@ -44,7 +44,7 @@ log = obs.get_logger(__name__)
 class BurstyLoad:
     """Open-loop arrival schedule: Poisson base rate + burst windows.
 
-    Deterministic given ``seed`` — every backend (and every PR) sees the
+    Deterministic given ``seed`` — every run (and every PR) sees the
     same arrival times.  Requests ``burst_len``-out-of-``burst_every`` are
     drawn at ``burst_qps``; arrivals never wait for completions.
     """
@@ -92,10 +92,9 @@ def _samples(img: int, n: int = 32, seed: int = 1) -> list[np.ndarray]:
             for _ in range(n)]
 
 
-def _bench_backend(design, backend: str, load: BurstyLoad, img: int,
-                   max_batch: int) -> dict:
-    eng = design.engine(backend=backend, fmt=None, max_batch=max_batch,
-                        max_delay_ms=2.0)
+def _bench_engine(design, load: BurstyLoad, img: int,
+                  max_batch: int) -> dict:
+    eng = design.engine(fmt=None, max_batch=max_batch, max_delay_ms=2.0)
     with eng:
         reqs = load.drive(eng, _samples(img))
         for r in reqs:
@@ -119,10 +118,9 @@ def _bench_backend(design, backend: str, load: BurstyLoad, img: int,
     }
 
 
-def main(fast: bool = False, backends=None) -> dict:
+def main(fast: bool = False) -> dict:
     img = 9 if fast else 11
     max_batch = 8 if fast else 16
-    backends = tuple(backends) if backends else ("tensor", "pallas")
     load = BurstyLoad(n_requests=60 if fast else 240)
 
     model = braggnn.build(1, img)
@@ -133,15 +131,15 @@ def main(fast: bool = False, backends=None) -> dict:
     # engine's bucket warm-up — everything a brand-new replica pays
     t0 = time.perf_counter()
     design = hls.Session().compile(bound, name="braggnn_serve")
-    design.engine(backend="tensor", max_batch=max_batch)
+    design.engine(max_batch=max_batch)
     cold_s = time.perf_counter() - t0
 
     out: dict = {"model": f"braggnn_s1_img{img}", "max_batch": max_batch,
-                 "load": load.describe(), "backends": {}}
+                 "load": load.describe()}
 
     with tempfile.TemporaryDirectory() as td:
         artifact = pathlib.Path(td) / "braggnn_s1.design"
-        design.save(artifact, backend="tensor")
+        design.save(artifact)
         out["artifact_bytes"] = artifact.stat().st_size
 
         # warm boot: one disk read + the SAME bucket warm-up, no compile
@@ -156,38 +154,33 @@ def main(fast: bool = False, backends=None) -> dict:
         print(f"serving_warm_boot,{warm_s * 1e6:.0f},"
               f"{out['warm_speedup']}x_faster")
 
-        for backend in backends:
-            res = _bench_backend(warmed, backend, load, img, max_batch)
-            out["backends"][backend] = res
-            print(f"serving_{backend},{res['p95_ms'] * 1e3:.0f},"
-                  f"{res['qps']}qps")
-            sys.stdout.flush()
+        res = out["engine"] = _bench_engine(warmed, load, img, max_batch)
+        print(f"serving_engine,{res['p95_ms'] * 1e3:.0f},{res['qps']}qps")
+        sys.stdout.flush()
     return out
 
 
 def check_healthy(result: dict) -> list[str]:
-    """Sanity assertions for CI: every backend completed everything."""
+    """Sanity assertions for CI: the engine completed everything."""
     problems = []
     if result["warm_boot_s"] >= result["cold_compile_s"]:
         problems.append(
             f"warm boot ({result['warm_boot_s']}s) not faster than cold "
             f"compile ({result['cold_compile_s']}s)")
-    for name, b in result["backends"].items():
-        if b["qps"] <= 0:
-            problems.append(f"{name}: qps {b['qps']} <= 0")
-        if b["dropped"]:
-            problems.append(f"{name}: dropped {b['dropped']} requests")
-        if b["completed"] != result["load"]["n_requests"]:
-            problems.append(f"{name}: completed {b['completed']} != "
-                            f"submitted {result['load']['n_requests']}")
+    b = result["engine"]
+    if b["qps"] <= 0:
+        problems.append(f"qps {b['qps']} <= 0")
+    if b["dropped"]:
+        problems.append(f"dropped {b['dropped']} requests")
+    if b["completed"] != result["load"]["n_requests"]:
+        problems.append(f"completed {b['completed']} != "
+                        f"submitted {result['load']['n_requests']}")
     return problems
 
 
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
-    ap.add_argument("--backends", default=None,
-                    help="comma-separated subset of tensor,simd,pallas")
     ap.add_argument("--out", default=None, help="write result JSON here")
     ap.add_argument("--assert-healthy", action="store_true",
                     help="exit 1 unless QPS>0 and zero dropped everywhere")
@@ -195,13 +188,12 @@ if __name__ == "__main__":
     obs.setup_logging()
     from repro.core.cachedir import enable_compile_cache
     enable_compile_cache()
-    result = main(fast=a.fast,
-                  backends=a.backends.split(",") if a.backends else None)
-    for name, b in result["backends"].items():
-        log.info("# %s: %s qps, p50 %sms / p95 %sms / p99 %sms, "
-                 "max queue %s, %s dispatches %s", name, b["qps"],
-                 b["p50_ms"], b["p95_ms"], b["p99_ms"],
-                 b["max_queue_depth"], b["dispatches"], b["batch_hist"])
+    result = main(fast=a.fast)
+    b = result["engine"]
+    log.info("# engine: %s qps, p50 %sms / p95 %sms / p99 %sms, "
+             "max queue %s, %s dispatches %s", b["qps"],
+             b["p50_ms"], b["p95_ms"], b["p99_ms"],
+             b["max_queue_depth"], b["dispatches"], b["batch_hist"])
     log.info("# boot: cold %ss vs warm %ss (%sx)",
              result["cold_compile_s"], result["warm_boot_s"],
              result["warm_speedup"])

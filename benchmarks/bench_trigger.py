@@ -1,8 +1,7 @@
 """Hard-real-time trigger: sustained frame rate, deadlines, drops.
 
 The deployment figure OpenHLS is actually judged on: BraggNN serving a
-fixed-rate detector stream as a trigger.  Per serving backend this
-benchmark runs a seeded :class:`~repro.trigger.DetectorFeed` (event rate
+fixed-rate detector stream as a trigger.  This benchmark runs a seeded :class:`~repro.trigger.DetectorFeed` (event rate
 + pileup bursts) through a pre-warmed :class:`~repro.trigger.TriggerLoop`
 in realtime mode and reports
 
@@ -30,21 +29,21 @@ from repro.models import braggnn
 log = obs.get_logger(__name__)
 
 #: per-decision deadline (µs) for the realtime run — generous enough that
-#: a warm CPU-simulated backend holds it, tight enough that a regression
+#: a warm CPU-simulated design holds it, tight enough that a regression
 #: (or an unwarmed shape on the hot path) shows up as misses
 DEADLINE_US = 50_000.0
 
 
-def run_backend(design, backend: str, *, img: int, n_frames: int,
-                rate_hz: float, window: int) -> dict:
+def run_stream(design, *, img: int, n_frames: int, rate_hz: float,
+               window: int) -> dict:
     budget = trigger.TriggerBudget(max_latency_us=DEADLINE_US)
     t0 = time.perf_counter()
-    loop = design.trigger(backend=backend, window=window, budget=budget)
+    loop = design.trigger(window=window, budget=budget)
     loop.calibrate(trigger.DetectorFeed(img=img, seed=11), 64)
     build_s = time.perf_counter() - t0
     feed = trigger.DetectorFeed(img=img, frame_rate_hz=rate_hz, seed=11)
     rep = loop.run(feed, n_frames, realtime=True)
-    log.info("  %s: %s", backend, rep.summary())
+    log.info("  %s", rep.summary())
     out = rep.to_json()
     out.update(build_s=round(build_s, 2), threshold=loop.threshold,
                configured_fps=rate_hz,
@@ -55,13 +54,11 @@ def run_backend(design, backend: str, *, img: int, n_frames: int,
     return out
 
 
-def main(fast: bool = False, backends=None) -> dict:
+def main(fast: bool = False) -> dict:
     img = 9 if fast else 11
     n_frames = 200 if fast else 1000
     rate_hz = 500.0 if fast else 1000.0
     window = 4
-    backends = tuple(backends) if backends else \
-        (("tensor",) if fast else ("tensor", "pallas"))
 
     model = braggnn.build(1, img)
     params = model.init_params(jax.random.key(0))
@@ -88,12 +85,9 @@ def main(fast: bool = False, backends=None) -> dict:
                  "deadline_us": DEADLINE_US,
                  "sample_latency_us": deployed.sample_latency_us,
                  "full_capacity_check": full_check.to_json(),
-                 "budget_check": part_check.to_json(),
-                 "backends": {}}
-    for backend in backends:
-        out["backends"][backend] = run_backend(
-            deployed, backend, img=img, n_frames=n_frames, rate_hz=rate_hz,
-            window=window)
+                 "budget_check": part_check.to_json()}
+    out["stream"] = run_stream(deployed, img=img, n_frames=n_frames,
+                               rate_hz=rate_hz, window=window)
     return out
 
 

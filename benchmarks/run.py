@@ -97,23 +97,19 @@ def write_report(results: dict, args, out_path=None) -> pathlib.Path:
     # machine-readable across PRs
     pass_times = dict(old.get("pass_times_s") or {})
     compiler = dict(old.get("compiler") or {})
-    backends = dict(old.get("backends_us_per_sample") or {})
     serving = dict(old.get("serving") or {})
     bragg = results.get("bench_braggnn", {}).get("result") or {}
     if isinstance(bragg, dict) and "pass_s" in bragg:
         pass_times["braggnn"] = bragg["pass_s"]
         compiler["braggnn"] = {k: bragg[k] for k in _COMPILER_FIELDS
                                if k in bragg}
-    if isinstance(bragg, dict) and "backends" in bragg:
-        # per-serving-backend µs/sample — the serving-perf trajectory
-        backends["braggnn"] = bragg["backends"]
     srv = results.get("bench_serving", {}).get("result") or {}
     if isinstance(srv, dict) and srv:
         # sustained QPS / tail latency / warm-boot trajectory
         serving = _jsonable(srv)
     trig = dict(old.get("trigger") or {})
     tr = results.get("bench_trigger", {}).get("result") or {}
-    if isinstance(tr, dict) and tr.get("backends"):
+    if isinstance(tr, dict) and tr.get("stream"):
         # sustained fps / deadline-miss % / drop % trajectory
         trig = _jsonable(tr)
     scaling = dict(old.get("compiler_scaling") or {})
@@ -128,7 +124,6 @@ def write_report(results: dict, args, out_path=None) -> pathlib.Path:
         "args": {"fast": args.fast, "only": args.only},
         "pass_times_s": pass_times,
         "compiler": compiler,
-        "backends_us_per_sample": backends,
         "serving": serving,
         "trigger": trig,
         "compiler_scaling": scaling,
@@ -174,21 +169,6 @@ def compare_with_previous(report: dict, path: pathlib.Path) -> None:
                  (f" (was {old_b['pass_ops_per_s']:,})"
                   if old_b.get("pass_ops_per_s") else ""))
 
-    def _backends(b):
-        if isinstance(b.get("backends"), dict):
-            return b["backends"]
-        # pre-backends reports carried two flat keys
-        legacy = {"simd": b.get("simd_us_per_sample_cpu"),
-                  "tensor": b.get("tensor_us_per_sample_cpu")}
-        return {k: round(v, 1) for k, v in legacy.items() if v is not None}
-
-    old_bk, new_bk = _backends(old_b), _backends(new_b)
-    if new_bk:
-        log.info("#   serving backends (us/sample): %s",
-                 ", ".join(f"{name} {old_bk.get(name, '-')} -> "
-                           f"{new_bk.get(name, '-')}"
-                           for name in sorted(set(old_bk) | set(new_bk))))
-
 
 def compare_serving(report: dict, path: pathlib.Path) -> None:
     """Per-metric before/after diff of the ``serving`` section (engine QPS,
@@ -196,46 +176,41 @@ def compare_serving(report: dict, path: pathlib.Path) -> None:
     previous = sorted(p for p in REPO_ROOT.glob("BENCH_*.json")
                       if p.resolve() != path.resolve())
     new_s = report.get("serving") or {}
-    if not (previous and new_s.get("backends")):
+    if not (previous and new_s.get("engine")):
         return
     try:
         old = json.loads(previous[-1].read_text())
     except (OSError, json.JSONDecodeError):
         return
     old_s = old.get("serving") or {}
-    old_bk = old_s.get("backends") or {}
+    nb, ob = new_s["engine"], old_s.get("engine") or {}
     log.info("# serving vs %s:", previous[-1].name)
-    for name in sorted(new_s["backends"]):
-        nb, ob = new_s["backends"][name], old_bk.get(name) or {}
-        for metric in ("qps", "p50_ms", "p95_ms", "p99_ms",
-                       "max_queue_depth"):
-            log.info("#   %s.%s: %s -> %s", name, metric,
-                     ob.get(metric, "-"), nb.get(metric, "-"))
+    for metric in ("qps", "p50_ms", "p95_ms", "p99_ms", "max_queue_depth"):
+        log.info("#   engine.%s: %s -> %s", metric, ob.get(metric, "-"),
+                 nb.get(metric, "-"))
     for metric in ("cold_compile_s", "warm_boot_s", "warm_speedup"):
         log.info("#   %s: %s -> %s", metric, old_s.get(metric, "-"),
                  new_s.get(metric, "-"))
 
 
 def compare_trigger(report: dict, path: pathlib.Path) -> None:
-    """Per-backend before/after diff of the ``trigger`` section (sustained
-    fps, deadline-miss %, drop %, p99 decision latency) against the most
+    """Before/after diff of the ``trigger`` section (sustained fps,
+    deadline-miss %, drop %, p99 decision latency) against the most
     recent other report."""
     previous = sorted(p for p in REPO_ROOT.glob("BENCH_*.json")
                       if p.resolve() != path.resolve())
     new_t = report.get("trigger") or {}
-    if not (previous and new_t.get("backends")):
+    if not (previous and new_t.get("stream")):
         return
     try:
         old = json.loads(previous[-1].read_text())
     except (OSError, json.JSONDecodeError):
         return
-    old_bk = (old.get("trigger") or {}).get("backends") or {}
+    nb, ob = new_t["stream"], (old.get("trigger") or {}).get("stream") or {}
     log.info("# trigger vs %s:", previous[-1].name)
-    for name in sorted(new_t["backends"]):
-        nb, ob = new_t["backends"][name], old_bk.get(name) or {}
-        for metric in ("sustained_fps", "miss_pct", "drop_pct", "p99_us"):
-            log.info("#   %s.%s: %s -> %s", name, metric,
-                     ob.get(metric, "-"), nb.get(metric, "-"))
+    for metric in ("sustained_fps", "miss_pct", "drop_pct", "p99_us"):
+        log.info("#   stream.%s: %s -> %s", metric, ob.get(metric, "-"),
+                 nb.get(metric, "-"))
     check = new_t.get("budget_check") or {}
     if check:
         log.info("#   budget check vs %s: %s", check.get("part", "?"),

@@ -8,17 +8,17 @@ few steps on seeded synthetic Bragg peaks and then driven once through the
 entry points a user calls, in one process:
 
 1. ``hls.compile(module)`` builds the ``Design``;
-2. ``design.jax_fn(backend="pallas")`` runs the nest-pattern tier with
+2. ``design.jax_fn()`` runs the nest-pattern tier with
    Mosaic-compiled ``pl.pallas_call`` kernels, in fp32 and at
    ``fmt="5_4"``, checked against a float32 ``jax.numpy`` reference and
    against the functional simulator ``emit.evaluate``;
-3. ``design.engine(backend="pallas")`` answers requests in every warmed
+3. ``design.engine()`` answers requests in every warmed
    bucket;
-4. ``design.trigger(backend="pallas", window=4)`` decides a seeded
+4. ``design.trigger(window=4)`` decides a seeded
    ``DetectorFeed`` twice, and the two runs must agree exactly;
 5. BraggNN(s=4), the original widths (64 -> 32 -> 8 channels, flattening
    to 200), is trained the same way, compiled with ``hls.compile`` and
-   served once through ``design.serve(backend="pallas")`` in fp32 and at
+   served once through ``design.serve`` in fp32 and at
    ``fmt="5_4"``, checked against the float32 reference.
 
 Compile seconds and warm µs/sample at batch 64 are printed for
@@ -151,7 +151,7 @@ def phase_jax_fn(design, params, x):
     check(scale > 0.1, "degenerate reference outputs")
 
     for fmt in (None, "5_4"):
-        fn = design.jax_fn(backend="pallas", fmt=fmt)
+        fn = design.jax_fn(fmt=fmt)
         check_plan(fn.plan, fmt)
         feeds = design.feeds(np.asarray(x))
         t0 = time.perf_counter()
@@ -195,7 +195,7 @@ def phase_engine(design, x, want) -> None:
     """One synchronous dispatch per warmed bucket; every request must
     complete, none may drop, and answers must match the direct call."""
     import numpy as np
-    eng = design.engine(backend="pallas", max_batch=32)
+    eng = design.engine(max_batch=32)
     log(f"engine: served by {eng.report().served}")
     offset = 0
     for bucket in eng.buckets:
@@ -224,7 +224,7 @@ def phase_trigger(design) -> None:
 
     runs = []
     for i in range(2):
-        loop = design.trigger(backend="pallas", window=4)
+        loop = design.trigger(window=4)
         thr = loop.calibrate(DetectorFeed(img=IMG, seed=SEED + 11), 64)
         rep = loop.run(DetectorFeed(img=IMG, seed=SEED + 11),
                        TRIGGER_FRAMES)
@@ -259,8 +259,7 @@ def phase_wide(hls) -> None:
     scale = float(np.max(np.abs(ref)))
     check(scale > 0.1, "degenerate s=4 reference outputs")
     for fmt in (None, "5_4"):
-        rep = design.serve([np.asarray(x)], backend="pallas", fmt=fmt,
-                           collect=True)
+        rep = design.serve([np.asarray(x)], fmt=fmt, collect=True)
         log(f"s=4 serve[{fmt or 'fp32'}]: {rep.served}")
         check("Mosaic kernels" in rep.served and not rep.fallbacks,
               f"s=4 served by {rep.served}, fallbacks {rep.fallbacks}")

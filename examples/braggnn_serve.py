@@ -11,8 +11,9 @@ into the declarative module graph (``models.braggnn.build``), and compiles
 it through the public API — ``repro.hls.compile`` auto-lowers the module
 to the paper's loop nests via the bridge (bit-identical to the hand-
 written ``frontend.braggnn``).  Batched peak-localisation requests are
-then served through ``Design.serve``'s fused reduced-precision tensor
-path — (5,4) by default, or whatever format the tuned candidate carries.
+then served through ``Design.serve`` on the design's compiled rendering
+(the nest tier's registry kernels) — at (5,4) by default, or whatever
+format the tuned candidate carries.
 
 ``--tuned`` auto-loads the best known compile configuration from the
 persistent ``TuningDB`` via ``Design.apply_tuned`` (populate it with
@@ -97,8 +98,8 @@ def serve_engine(design, serve_fmt, save_path=None) -> None:
     summary (and where a poisoned replica would warm-boot from)."""
     x, y = braggnn.synthetic_peaks(jax.random.key(7), 256)
     samples = jnp.asarray(x)[:, None]            # (N, 1, img, img) memrefs
-    eng = design.engine(backend="tensor", fmt=serve_fmt, max_batch=16,
-                        max_delay_ms=2.0, artifact_path=save_path)
+    eng = design.engine(fmt=serve_fmt, max_batch=16, max_delay_ms=2.0,
+                        artifact_path=save_path)
     with eng:
         reqs = [eng.submit(s) for s in samples]
         for r in reqs:
@@ -135,8 +136,7 @@ def _run(args) -> None:
             serve_engine(design, serve_fmt, save_path=args.load)
         else:
             x, _ = braggnn.synthetic_peaks(jax.random.key(7), 1024)
-            log.info("%s", design.serve([x] * 10, fmt=serve_fmt,
-                                        backend="tensor").summary())
+            log.info("%s", design.serve([x] * 10, fmt=serve_fmt).summary())
         return
 
     # --- describe once, train, bind ----------------------------------------
@@ -184,16 +184,16 @@ def _run(args) -> None:
 
     # --- serve batches at the deployed precision ---------------------------
     x, y = braggnn.synthetic_peaks(jax.random.key(7), 1024)
-    report = design.serve([x] * 10, fmt=serve_fmt, backend="tensor",
-                          collect=True)
-    pred = report.outputs[-1]
+    report = design.serve([x] * 10, fmt=serve_fmt, collect=True)
+    (pred,) = report.outputs[-1].values()
+    pred = jnp.reshape(pred, y.shape)
     err_px = float(jnp.mean(jnp.abs(pred / 10.0 - y))) * 11
     log.info("%s; mean localisation error %.3f px", report.summary(),
              err_px)
 
     # --- warm-boot artifact + async engine ---------------------------------
     if args.save:
-        path = design.save(args.save, backend="tensor", fmt=serve_fmt)
+        path = design.save(args.save, fmt=serve_fmt)
         log.info("saved warm-boot artifact: %s (%s bytes)", path,
                  f"{path.stat().st_size:,}")
     if args.engine:

@@ -7,7 +7,7 @@ One ``repro.hls.compile()`` call runs the whole Fig. 1 flow: the conv2d
 loop nest is symbolically interpreted into an SSA DFG (store-load
 forwarding included), optimised, scheduled, and returned as a ``Design``
 handle.  We then behaviourally verify it, quantise to FloPoCo (5,4), and
-run the emitted SIMD design.  ``--pipeline`` selects which registered
+run the design's compiled rendering.  ``--pipeline`` selects which registered
 passes run (comma-separated, in order) instead of the default §3.2
 pipeline.
 """
@@ -51,23 +51,22 @@ def main(argv=None) -> None:
     log.info("%s", design.report())
 
     # 3. one behavioural testbench covers it all (§3.2): optimised DFG and
-    # emitted SIMD design vs the interpreter reference, plus the FloPoCo
+    # compiled rendering vs the interpreter reference, plus the FloPoCo
     # (5,4) functional model
     report = design.verify(batch=4, seed=0, fmt=FP_5_4)
     log.info("%s", report.summary())
     log.info("(5,4) max abs deviation vs fp32: %.4f",
              report.max_abs_err_quant)
     assert report.passed, "behavioural verification failed"
-    log.info("emitted SIMD design matches the functional "
+    log.info("compiled rendering matches the functional "
              "simulation  [OK]")
 
-    # 4. the deployable path: run a fresh batch through the jitted design
-    import jax
-    fn = jax.jit(design.jax_fn())
+    # 4. the deployable path: run a fresh batch through the compiled design
+    fn = design.jax_fn()
     from repro.core import verify
     feeds = verify.random_feeds(design.graph_opt, batch=4, seed=1)
     got = np.asarray(fn(feeds)["out"])
-    log.info("served a batch of 4 through the SIMD design: out %s",
+    log.info("served a batch of 4 through the compiled design: out %s",
              got.shape)
 
     # 5. a second compile of the same program is a cache hit

@@ -4,7 +4,7 @@ Pipeline (paper Fig. 1):
     frontend (loop nests)  ->  symbolic interpretation (interp)  ->
     SSA DFG (ir)  ->  passes (forwarding/relu/fmac/trees/cse/dce)  ->
     resource-constrained list scheduling (schedule)  ->
-    emission (emit: functional sim + SIMD JAX design)  ->
+    emission (emit: functional sim; emit_pallas: compiled rendering)  ->
     behavioural verification (verify)
 
 plus the two TPU-scale adaptations:

@@ -1,32 +1,28 @@
-"""Design emission + functional simulation (paper §3.1 item 4, §3.2).
+"""Functional simulation of a scheduled DFG, and the levelised groups that
+its compiled rendering fuses (paper §3.1 item 4, §3.2).
 
-Three execution backends for a scheduled DFG:
-
-  * ``evaluate``      — numpy functional simulation.  With a ``FloatFormat``
-                        this becomes the FloPoCo functional model (quantise
-                        after every operation), i.e. the reference the
-                        paper's testbenches compare RTL against.  The DFG is
-                        levelised and each (level, opcode) group executes as
-                        one vectorised gather/compute/scatter over a dense
-                        ``(n_values, batch)`` value matrix — bit-identical
-                        to the historical per-op program-order loop (which
-                        survives in ``repro.core.legacy``; route through it
-                        with ``REPRO_LEGACY_IR=1``).
-  * ``to_jax_fn``     — "RTL emission" for TPU: the DFG is levelised by its
-                        schedule and each (cycle-level, opcode) group becomes
-                        one vectorised gather/compute/scatter — a SIMD
-                        rendering of the fully scheduled design.  The emitted
-                        function is jittable and exactly evaluates the DFG.
-  * the tensor path   — production inference uses the tensor-level model
-                        (``repro.models``) with ``precision.quantize``
-                        inserted per the chosen format; the scalar DFG
-                        backends above serve as its behavioural oracle.
+  * ``evaluate``       — numpy functional simulation.  With a ``FloatFormat``
+                         this becomes the FloPoCo functional model (quantise
+                         after every operation), i.e. the reference the
+                         paper's testbenches compare RTL against.  The DFG is
+                         levelised and each (level, opcode) group executes as
+                         one vectorised gather/compute/scatter over a dense
+                         ``(n_values, batch)`` value matrix — bit-identical
+                         to the historical per-op program-order loop (which
+                         survives in ``repro.core.legacy``; route through it
+                         with ``REPRO_LEGACY_IR=1``).
+  * ``levelize`` / ``compile_groups`` / ``io_tables`` — the Kahn-wave
+                         levels, the per-(level, opcode) gather/scatter index
+                         arrays and the constant/input/output tables of a
+                         DFG.  The DFG tier of :mod:`repro.core.emit_pallas`
+                         fuses runs of these groups into compiled segments;
+                         that module is the one rendering a design serves
+                         through.
 """
-
 from __future__ import annotations
 
 import os
-from typing import Callable, Optional
+from typing import Optional
 
 import numpy as np
 
@@ -119,8 +115,7 @@ def compile_groups(c: GraphCols, n_values: int
     """Precompute the gather/scatter index arrays per (level, opcode) group.
 
     Returns ``(level, opcode, [arg index arrays], result index array)``
-    tuples in level order — the shared unit of emission for the SIMD
-    rendering (:func:`to_jax_fn`) and the Pallas backend
+    tuples in level order — the unit of emission of the DFG tier
     (``repro.core.emit_pallas``), which fuses contiguous runs of them into
     compiled kernels.
     """
@@ -138,8 +133,8 @@ def compile_groups(c: GraphCols, n_values: int
 def io_tables(g: Graph):
     """Constant / input-scatter / output-gather index tables of a DFG.
 
-    Shared by every vectorised emitter: ``const_idx``/``const_val`` seed the
-    value buffer, ``input_scatter[name] = (vids, idx tuples)`` place feeds,
+    The DFG tier's prologue and epilogue: ``const_idx``/``const_val`` seed
+    the value buffer, ``input_scatter[name] = (vids, idx tuples)`` place feeds,
     ``output_gather[name] = (vids, shape)`` assemble outputs.
     """
     const_idx = np.array(sorted(g.consts), dtype=np.int32)
@@ -264,104 +259,3 @@ def evaluate(g: Graph, feeds: dict[str, np.ndarray], *,
             M[res[rows][rmask]] = r[rmask]
 
     return _assemble_outputs(g, batch, M.__getitem__)
-
-
-# ---------------------------------------------------------------------------
-# SIMD emission: the TPU rendering of the fully scheduled design
-# ---------------------------------------------------------------------------
-
-#: valid values for the ``backend=`` of :func:`to_jax_fn` (and the emission
-#: half of ``Design.serve``): the SIMD interpretation vs the Pallas-native
-#: compiled rendering
-EMIT_BACKENDS = ("simd", "pallas")
-
-
-def to_jax_fn(g: Graph, *, backend: str = "simd", **pallas_kw
-              ) -> Callable[[dict[str, "np.ndarray"]], dict[str, "np.ndarray"]]:
-    """Emit a jittable function that exactly evaluates the DFG.
-
-    ``backend='simd'`` (default): the DFG is levelised (ASAP with unit
-    delays); each (level, opcode) group becomes one gather -> vector op ->
-    scatter.  This is the SIMD analogue of RTL emission: every op executes
-    at its scheduled level, with no dynamic control flow — the XLA program
-    is the FSM.
-
-    ``backend='pallas'``: contiguous runs of levelised groups are fused
-    into compiled kernels instead of interpreted — see
-    :func:`repro.core.emit_pallas.to_pallas_fn`, which also accepts
-    ``module=`` for the nest-pattern fast path (extra keywords are
-    forwarded).  The returned callable carries its lowering ``.plan``.
-    """
-    if backend not in EMIT_BACKENDS:
-        raise ValueError(f"unknown emission backend {backend!r} "
-                         f"(valid: {', '.join(EMIT_BACKENDS)})")
-    if backend == "pallas":
-        from repro.core.emit_pallas import to_pallas_fn
-        return to_pallas_fn(g, **pallas_kw)
-    if pallas_kw:
-        raise TypeError(f"backend='simd' takes no extra keywords, got "
-                        f"{sorted(pallas_kw)}")
-    import jax
-    import jax.numpy as jnp
-
-    c = g.cols()
-    compiled_groups = [(oc, arg_idx, res_idx) for _lv, oc, arg_idx, res_idx
-                       in compile_groups(c, g.n_values)]
-    const_idx, const_val, input_scatter, output_gather = io_tables(g)
-    n_values = g.n_values
-
-    def run(feeds: dict[str, jax.Array]) -> dict[str, jax.Array]:
-        # batch = leading axis of the first *batched* feed (mirrors
-        # ``evaluate``): unbatched feeds — typically weights — broadcast
-        batch = 1
-        for name in input_scatter:
-            rank = len(next(iter(g.inputs[name])))
-            shp = jnp.shape(feeds[name])
-            if len(shp) == rank + 1:
-                batch = shp[0]
-                break
-        buf = jnp.zeros((batch, n_values), dtype=jnp.float32)
-        buf = buf.at[:, const_idx].set(const_val[None, :])
-        for name, (vids, idxs) in input_scatter.items():
-            arr = jnp.asarray(feeds[name], dtype=jnp.float32)
-            if arr.ndim == len(idxs[0]):
-                arr = arr[None]
-            flat = jnp.stack([arr[(slice(None),) + i] for i in idxs], axis=1)
-            buf = buf.at[:, vids].set(flat)
-        for oc, arg_idx, res_idx in compiled_groups:
-            a = [buf[:, ai] for ai in arg_idx]
-            if oc == "mulf":
-                r = a[0] * a[1]
-            elif oc == "addf":
-                r = a[0] + a[1]
-            elif oc == "subf":
-                r = a[0] - a[1]
-            elif oc == "divf":
-                r = a[0] / a[1]
-            elif oc == "sqrtf":
-                r = jnp.sqrt(a[0])
-            elif oc == "maxf":
-                r = jnp.maximum(a[0], a[1])
-            elif oc == "minf":
-                r = jnp.minimum(a[0], a[1])
-            elif oc == "negf":
-                r = -a[0]
-            elif oc == "relu":
-                r = jnp.maximum(a[0], 0.0)
-            elif oc == "fmac":
-                r = a[0] * a[1] + a[2]
-            elif oc == "cmpugt":
-                r = (a[0] > a[1]).astype(jnp.float32)
-            elif oc == "select":
-                r = jnp.where(a[0] > 0.5, a[1], a[2])
-            elif oc in ("load", "store", "copy"):
-                r = a[0]
-            else:  # pragma: no cover
-                raise NotImplementedError(oc)
-            buf = buf.at[:, res_idx].set(r)
-        outs = {}
-        for name, (vids, shape) in output_gather.items():
-            outs[name] = buf[:, vids].reshape((batch,) + shape)
-        return outs
-
-    return run

@@ -1,10 +1,7 @@
-"""Pallas-native emission: compile the scheduled design, don't interpret it.
+"""Pallas-native emission: the one rendering every design serves through.
 
-``emit.to_jax_fn`` renders the levelised DFG as one gather/compute/scatter
-per (level, opcode) group — faithful, but *interpretive*: every value
-round-trips through a ``(batch, n_values)`` buffer, and on CPU the result
-is ~69x slower than the hand-written tensor path (BENCH_2026-07-28.json).
-This module is the compiled rendering, with two tiers:
+The functional simulator (``emit.evaluate``) is the reference; this module
+compiles the scheduled design instead of interpreting it, with two tiers:
 
 **Nest-pattern tier** (``mode='nests'``) — when the design carries the
 ``ModuleGraph`` it was bridged from, each node lowers through the kernel
@@ -120,7 +117,7 @@ class PallasPlan:
 
 def _fallback_compute(oc: str, a: list):
     """The tensor-path rendering of one unkernelled group (mirrors
-    ``emit.to_jax_fn``'s op table for the opcodes outside the registry)."""
+    ``emit.evaluate``'s op table for the opcodes outside the registry)."""
     import jax.numpy as jnp
     if oc == "cmpugt":
         return (a[0] > a[1]).astype(jnp.float32)
@@ -711,7 +708,7 @@ def to_pallas_fn(g: Graph, *, module=None, fmt=None, mode: str = "auto",
 
     The returned callable maps a feed dict (memref name -> array, weights
     batched or not) to ``{output name: (batch,) + shape}`` exactly like
-    ``emit.to_jax_fn``'s emission, is internally jitted (do NOT wrap it in
+    ``emit.evaluate``, is internally jitted (do NOT wrap it in
     ``jax.jit`` — the nest tier normalises fed weights host-side), and
     carries its :class:`PallasPlan` as ``.plan``.  In the nest tier a
     bound module's weights are normalised and put on the device once, at

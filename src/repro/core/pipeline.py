@@ -404,9 +404,9 @@ class CompiledDesign:
 
     Bundles the raw (traced) graph, the optimised graph, the resource-
     constrained ``Schedule``, per-pass ``PassReport``s, stage timings, and
-    the content hash that keys the design cache.  The emitted jittable SIMD
-    function is materialised lazily via :meth:`jax_fn` (and therefore not
-    pickled into the on-disk cache — it is re-emitted on load).
+    the content hash that keys the design cache.  The compiled rendering
+    is lowered on demand by :meth:`jax_fn`; nothing compiled is pickled
+    into the on-disk cache.
 
     ``timings`` always describe the compile that *built* the artifact; a
     cache-served design keeps its original build cost.
@@ -425,8 +425,6 @@ class CompiledDesign:
     #: stay ``None`` for unpipelined designs.
     stages: Optional[list[list[int]]] = None
     stage_ii: Optional[int] = None
-    _jax_fn: Optional[Callable] = dataclasses.field(
-        default=None, repr=False, compare=False)
 
     # -- derived metrics ----------------------------------------------------
 
@@ -466,27 +464,17 @@ class CompiledDesign:
         ops = sum(r.ops_before for r in self.pass_reports if not r.skipped)
         return ops / wall if wall > 0 else 0.0
 
-    # -- execution backends -------------------------------------------------
+    # -- execution -----------------------------------------------------------
 
-    def jax_fn(self, *, backend: str = "simd", **pallas_kw) -> Callable:
-        """The emitted design as a callable, materialised on first use.
-
-        ``backend='simd'`` (cached): the jittable gather/compute/scatter
-        interpretation.  ``backend='pallas'``: the compiled rendering
-        (``emit_pallas``), rebuilt per call since its lowering depends on
-        the extra keywords (``module=``, ``fmt=``, ``use_pallas=``, ...).
-        """
-        if backend != "simd":
-            return emit.to_jax_fn(self.graph_opt, backend=backend,
-                                  **pallas_kw)
-        if pallas_kw:
-            raise TypeError(f"backend='simd' takes no extra keywords, got "
-                            f"{sorted(pallas_kw)}")
-        if self._jax_fn is None:
-            with obs.span("emit.simd", cat="compile", design=self.name,
-                          ops=len(self.graph_opt.ops)):
-                self._jax_fn = emit.to_jax_fn(self.graph_opt)
-        return self._jax_fn
+    def jax_fn(self, **pallas_kw) -> Callable:
+        """The compiled rendering of the design
+        (:func:`repro.core.emit_pallas.to_pallas_fn`), lowered afresh per
+        call since the lowering depends on the keywords (``module=``,
+        ``fmt=``, ``use_pallas=``, ...).  Without ``module=`` it is the DFG
+        tier over ``graph_opt``.  ``to_pallas_fn`` is looked up on its
+        module at call time, so a patched one is honoured."""
+        from repro.core import emit_pallas
+        return emit_pallas.to_pallas_fn(self.graph_opt, **pallas_kw)
 
     def evaluate(self, feeds: dict, *, fmt: Optional[FloatFormat] = None,
                  raw: bool = False) -> dict:
@@ -505,13 +493,6 @@ class CompiledDesign:
                 f"({self.latency_us:.2f} us, "
                 f"{self.sample_latency_us:.2f} us/sample), "
                 f"resources={res}, hash={self.design_hash[:12]}")
-
-    # -- pickling (the lazy jax fn is a closure: drop it) --------------------
-
-    def __getstate__(self):
-        state = self.__dict__.copy()
-        state["_jax_fn"] = None
-        return state
 
 
 # ---------------------------------------------------------------------------
@@ -575,8 +556,8 @@ class DesignCache:
     """In-memory + optional on-disk cache of ``CompiledDesign`` artifacts.
 
     Keyed by the design hash (graph fingerprint + config key).  The disk
-    layer stores one pickle per design under ``cache_dir``; loads re-emit
-    the jax fn lazily.
+    layer stores one pickle per design under ``cache_dir``; a loaded design
+    is lowered again on its first ``jax_fn`` call.
     """
 
     def __init__(self, cache_dir: Optional[Union[str, Path]] = None, *,
@@ -767,42 +748,3 @@ class CompilerDriver:
             timings=timings, stages=stages, stage_ii=stage_ii)
         self.cache.put(key, design)
         return design
-
-
-# ---------------------------------------------------------------------------
-# Deprecated entry points (forward to repro.hls, the public front door)
-# ---------------------------------------------------------------------------
-
-#: shims that already warned this process (each warns exactly once)
-_DEPRECATION_WARNED: set[str] = set()
-
-
-def _warn_deprecated(key: str, msg: str) -> None:
-    if key in _DEPRECATION_WARNED:
-        return
-    _DEPRECATION_WARNED.add(key)
-    import warnings
-    warnings.warn(msg, DeprecationWarning, stacklevel=3)
-
-
-def default_driver() -> CompilerDriver:
-    """Deprecated: use ``repro.hls`` (``hls.compile`` / ``hls.Session``)."""
-    _warn_deprecated(
-        "default_driver",
-        "repro.core.pipeline.default_driver() is deprecated; use "
-        "repro.hls.compile(...) or an explicit repro.hls.Session")
-    from repro import hls
-    return hls._default_session().driver
-
-
-def compile(program: Union[BuildFn, Graph], *, name: str = "design",
-            config: Optional[CompilerConfig] = None) -> CompiledDesign:
-    """Deprecated: use ``repro.hls.compile`` (returns a rich ``Design``;
-    its ``.compiled`` is this function's historical return value)."""
-    _warn_deprecated(
-        "pipeline.compile",
-        "repro.core.pipeline.compile() is deprecated; use "
-        "repro.hls.compile(...) — the returned Design wraps the same "
-        "CompiledDesign (design.compiled)")
-    from repro import hls
-    return hls.compile(program, name=name, config=config).compiled

@@ -52,7 +52,7 @@ class TestbenchReport:
     max_abs_err_opt: float        # optimised DFG vs raw DFG
     max_abs_err_ref: float        # raw DFG vs tensor reference (if given)
     max_abs_err_quant: float      # quantised functional model vs raw DFG
-    max_abs_err_jax: float        # emitted SIMD design vs raw DFG
+    max_abs_err_jax: float        # compiled rendering (DFG tier) vs raw DFG
     build_seconds: float
     passed: bool
 
@@ -63,7 +63,7 @@ class TestbenchReport:
                 f"err(opt)={self.max_abs_err_opt:.2e}, "
                 f"err(ref)={self.max_abs_err_ref:.2e}, "
                 f"err(quant)={self.max_abs_err_quant:.2e}, "
-                f"err(simd)={self.max_abs_err_jax:.2e}")
+                f"err(dfg)={self.max_abs_err_jax:.2e}")
 
 
 def _max_err(a: dict[str, np.ndarray], b: dict[str, np.ndarray]) -> float:
@@ -96,6 +96,10 @@ def run_testbench(
     compiles it through ``CompilerDriver``) or an already-compiled
     ``design`` — the testbench then consumes the ``CompiledDesign``
     artifact directly instead of re-running the flow.
+
+    With ``check_jax`` the design's compiled rendering
+    (``CompiledDesign.jax_fn``, the DFG tier of ``emit_pallas``) runs the
+    same feeds and must match the raw DFG within ``atol``.
 
     ``feed_transforms``: per-input-name callables applied to the random
     feeds (e.g. ``abs`` for a variance input).

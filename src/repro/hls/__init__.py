@@ -14,7 +14,7 @@ High-level representations of DNNs in, deployable low-level designs out
 (auto-lowered to the paper's loop nests by :mod:`repro.hls.bridge` —
 bit-identical to the hand-written programs), a loop-nest build callable,
 or a traced ``Graph``.  The returned :class:`Design` carries the verbs:
-``run`` (vectorised evaluate), ``jax_fn`` (emitted SIMD design),
+``run`` (vectorised evaluate), ``jax_fn`` (the compiled rendering),
 ``verify`` (behavioural testbench), ``tune`` / ``apply_tuned``
 (``repro.tune`` search, persisted + auto-loaded via the ``TuningDB``),
 ``with_config`` (recompile sharing the trace), ``serve`` (warmed batched

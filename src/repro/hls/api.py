@@ -78,7 +78,6 @@ class ServeReport:
     queue; the percentiles are over per-batch dispatch latencies.
     """
 
-    backend: str
     fmt: Optional[str]
     batches: int = 0
     samples: int = 0
@@ -87,7 +86,7 @@ class ServeReport:
     #: per-batch outputs, only kept when ``collect=True``
     outputs: Optional[list] = None
     #: what actually served — the Pallas lowering's plan summary (tier,
-    #: fused kernels, fallback count); equals ``backend`` otherwise
+    #: fused kernels, fallback count)
     served: Optional[str] = None
     #: per-group / per-node tensor-path fallbacks the Pallas lowering took
     fallbacks: list = dataclasses.field(default_factory=list)
@@ -106,12 +105,22 @@ class ServeReport:
     def summary(self) -> str:
         fmt = "fp32" if self.fmt in (None, "fp32") else \
             f"({self.fmt.replace('_', ',')})"
-        served = self.served or self.backend
         return (f"served {self.samples} samples in {self.batches} batches: "
                 f"{self.us_per_sample:.2f} us/sample, batch p50 "
                 f"{self.p50_ms:.2f} / p95 {self.p95_ms:.2f} / p99 "
-                f"{self.p99_ms:.2f} ms [{served} backend, {fmt}; "
+                f"{self.p99_ms:.2f} ms [{self.served}, {fmt}; "
                 f"warm-up {self.warmup_s:.2f}s]")
+
+
+def _check_backend(backend: str) -> None:
+    """``backend=`` selects nothing: every design serves through its
+    compiled rendering.  The keyword stays for callers that still name
+    that path."""
+    if backend != "pallas":
+        raise ValueError(
+            f"backend={backend!r}: 'pallas' is the only serving path — "
+            f"every design serves through its compiled rendering "
+            f"(emit_pallas.to_pallas_fn)")
 
 
 # ---------------------------------------------------------------------------
@@ -267,26 +276,17 @@ class Design:
         """
         return self._compiled.evaluate(self.feeds(inputs), fmt=fmt, raw=raw)
 
-    def jax_fn(self, *, backend: str = "simd", **pallas_kw) -> Callable:
-        """The emitted design as a callable.
-
-        ``backend='simd'`` (default): the jittable SIMD interpretation.
-        ``backend='pallas'``: the compiled Pallas rendering — fused
-        levelised op groups, registry kernels for bridged modules (the
-        source ``ModuleGraph`` is passed automatically when the design was
-        compiled from one); extra keywords (``fmt=``, ``mode=``,
+    def jax_fn(self, **pallas_kw) -> Callable:
+        """The design's compiled rendering as a callable: registry kernels
+        over the source ``ModuleGraph`` (the nest tier, when the design was
+        compiled from one — it is passed automatically), else fused
+        levelised op groups (the DFG tier).  Keywords (``fmt=``, ``mode=``,
         ``use_pallas=``, ...) forward to
         :func:`repro.core.emit_pallas.to_pallas_fn`, and the result
         carries its lowering ``.plan``.
         """
-        from repro.core.emit import EMIT_BACKENDS
-        if backend not in EMIT_BACKENDS:
-            raise ValueError(f"unknown emission backend {backend!r} "
-                             f"(valid: {', '.join(EMIT_BACKENDS)})")
-        if backend == "pallas":
-            pallas_kw.setdefault("module", self._module)
-            return self._compiled.jax_fn(backend="pallas", **pallas_kw)
-        return self._compiled.jax_fn()
+        pallas_kw.setdefault("module", self._module)
+        return self._compiled.jax_fn(**pallas_kw)
 
     # -- verification -------------------------------------------------------
 
@@ -296,9 +296,9 @@ class Design:
         """Behavioural testbench vs the interpreter reference (paper §3.2).
 
         Random vectors through the raw DFG, the optimised DFG, the
-        emitted SIMD design, and (with ``fmt``) the FloPoCo functional
-        model; returns a ``TestbenchReport`` whose ``passed`` folds the
-        tolerances.  ``ref_fn`` optionally adds an independent
+        compiled rendering's DFG tier, and (with ``fmt``) the FloPoCo
+        functional model; returns a ``TestbenchReport`` whose ``passed``
+        folds the tolerances.  ``ref_fn`` optionally adds an independent
         tensor-level reference.
         """
         from repro.core.verify import run_testbench
@@ -419,32 +419,25 @@ class Design:
     # -- serving ------------------------------------------------------------
 
     def serve(self, batch_iter: Iterable, *, fmt: Optional[str] = None,
-              backend: Optional[str] = None, collect: bool = False,
+              backend: str = "pallas", collect: bool = False,
               on_batch=None, pallas_kw: Optional[dict] = None
               ) -> ServeReport:
         """The warmed batched serving loop.
 
-        ``backend='tensor'`` jits the module's fused tensor-level forward
-        (requires a bound ``ModuleGraph`` with a ``forward_fn``) at FloPoCo
-        format key ``fmt``; ``backend='simd'`` jits the emitted SIMD design
-        (fp32); ``backend='pallas'`` runs the compiled Pallas rendering
-        (registry kernels / fused op-group segments — extra lowering
-        keywords via ``pallas_kw``), recording which tier actually served
-        and any per-group tensor fallbacks in the report.  Default: tensor
-        when available, else simd.  The first batch warms the jit (timed
-        separately); every batch is then blocked-on individually,
-        server-style.  ``on_batch(i, out)`` is called per batch;
-        ``collect=True`` additionally keeps outputs.
+        Runs the compiled rendering (:meth:`jax_fn`) at FloPoCo format key
+        ``fmt`` — registry kernels for a bridged module, fused op-group
+        segments otherwise; extra lowering keywords via ``pallas_kw`` —
+        recording which tier served and any per-group tensor fallbacks in
+        the report.  The first batch warms the jit (timed separately);
+        every batch is then blocked-on individually, server-style.
+        ``on_batch(i, out)`` is called per batch; ``collect=True``
+        additionally keeps outputs.
         """
         import jax
-        if backend is None:
-            backend = ("tensor" if self._module is not None
-                       and self._module.forward_fn is not None
-                       and self._module.params is not None else "simd")
-        run_one, served, fallbacks = self._runner(backend, fmt, pallas_kw)
+        _check_backend(backend)
+        run_one, served, fallbacks = self._runner(fmt, pallas_kw)
 
-        report = ServeReport(backend=backend, fmt=fmt,
-                             outputs=[] if collect else None,
+        report = ServeReport(fmt=fmt, outputs=[] if collect else None,
                              served=served, fallbacks=fallbacks)
         it = iter(batch_iter)
         try:
@@ -452,8 +445,7 @@ class Design:
         except StopIteration:
             return report
         t0 = time.perf_counter()
-        with obs.span("serve.warmup", cat="serve", backend=backend,
-                      design=self.name):
+        with obs.span("serve.warmup", cat="serve", design=self.name):
             jax.block_until_ready(run_one(first))    # compile + warm
         report.warmup_s = time.perf_counter() - t0
 
@@ -461,8 +453,7 @@ class Design:
         batch_s: list[float] = []
         for i, x in enumerate(itertools.chain((first,), it)):
             t0 = time.perf_counter()
-            with obs.span("serve.batch", cat="serve", backend=backend,
-                          batch=i):
+            with obs.span("serve.batch", cat="serve", batch=i):
                 out = jax.block_until_ready(run_one(x))
             batch_s.append(time.perf_counter() - t0)
             report.wall_s += batch_s[-1]
@@ -478,84 +469,56 @@ class Design:
         report.p95_ms = pct["p95"] * 1e3
         report.p99_ms = pct["p99"] * 1e3
         if report.samples:
-            obs.gauge(f"serve.us_per_sample.{backend}",
-                      report.us_per_sample)
+            obs.gauge("serve.us_per_sample", report.us_per_sample)
         return report
 
-    def _runner(self, backend: str, fmt: Optional[str],
-                pallas_kw: Optional[dict]):
-        """``(run_one, served, fallbacks)`` for one serving backend.
+    def _runner(self, fmt: Optional[str], pallas_kw: Optional[dict]):
+        """``(run_one, served, fallbacks)`` over the compiled rendering.
 
         ``run_one`` takes one batch — a bare input array or a feed dict
-        for ``simd``/``pallas`` (module weights merged via :meth:`feeds`,
-        or held on the device by the Pallas nest tier),
-        the fused forward's ``(B, ...)`` array for ``tensor`` — and
-        returns the outputs.  Shared by :meth:`serve` and the async
-        :class:`~repro.serving.design_engine.DesignEngine`, so both paths
-        serve through identical compiled programs.
+        (module weights merged via :meth:`feeds`, or held on the device by
+        the nest tier) — and returns the output memrefs as a dict.  Shared
+        by :meth:`serve`, the async
+        :class:`~repro.serving.design_engine.DesignEngine` and the trigger
+        loop, so every path serves through identical compiled programs.
         """
-        import jax
-        served = None
-        fallbacks: list = []
-        if backend == "tensor":
-            if (self._module is None or self._module.forward_fn is None
-                    or self._module.params is None):
-                raise ValueError("tensor backend needs a ModuleGraph with "
-                                 "bound params and a forward_fn")
-            params = self._module.params
-            fwd = self._module.forward_fn
-            fn = jax.jit(lambda p, x: fwd(p, x, fmt=fmt))
-            run_one = lambda x: fn(params, x)
-        elif backend == "simd":
-            if fmt not in (None, "fp32"):
-                raise ValueError("the emitted SIMD design runs fp32; use "
-                                 "backend='tensor' for quantised serving")
-            jfn = jax.jit(self._compiled.jax_fn())
-            # feeds() accepts bare input arrays or (partial) feed dicts and
-            # merges any bound module weights
-            run_one = lambda x: jfn(self.feeds(x))
-        elif backend == "pallas":
-            # already internally jitted, so no extra jax.jit wrapper here.
-            # The nest tier holds the bound weights on the device, so a
-            # call passes only the input (a feed dict passes unchanged, and
-            # any weights in it are used instead); the DFG tier takes every
-            # weight in its feeds, merged via feeds()
-            pfn = self.jax_fn(backend="pallas", fmt=fmt,
-                              **(pallas_kw or {}))
-            served = pfn.plan.summary()
-            fallbacks = list(pfn.plan.fallbacks)
-            if pfn.plan.mode == "nests":
-                as_feeds = lambda x: (x if isinstance(x, dict)
-                                      else self._coerce_input(x))
-            else:
-                as_feeds = self.feeds
-
-            def run_one(x):
-                with obs.span("nest.call", cat="pallas"):
-                    with obs.span("nest.feeds", cat="pallas"):
-                        feeds = as_feeds(x)
-                    return pfn(feeds)
+        # already internally jitted, so no extra jax.jit wrapper here.
+        # The nest tier holds the bound weights on the device, so a
+        # call passes only the input (a feed dict passes unchanged, and
+        # any weights in it are used instead); the DFG tier takes every
+        # weight in its feeds, merged via feeds()
+        pfn = self.jax_fn(fmt=fmt, **(pallas_kw or {}))
+        served = pfn.plan.summary()
+        fallbacks = list(pfn.plan.fallbacks)
+        if pfn.plan.mode == "nests":
+            as_feeds = lambda x: (x if isinstance(x, dict)
+                                  else self._coerce_input(x))
         else:
-            raise ValueError(f"unknown backend {backend!r} "
-                             f"(expected 'tensor', 'simd' or 'pallas')")
+            as_feeds = self.feeds
+
+        def run_one(x):
+            with obs.span("nest.call", cat="pallas"):
+                with obs.span("nest.feeds", cat="pallas"):
+                    feeds = as_feeds(x)
+                return pfn(feeds)
         return run_one, served, fallbacks
 
-    def engine(self, **kw):
+    def engine(self, *, backend: str = "pallas", **kw):
         """An async adaptive-batching engine over this design.
 
         Returns a :class:`repro.serving.design_engine.DesignEngine`:
         requests queue and dispatch in bucket-snapped pre-warmed batches
         (size or deadline trigger), with fault-tolerant replica restart.
-        ``backend``/``fmt``/``buckets`` default from the saved artifact's
-        manifest when this design came from :func:`load`; pass
-        ``artifact_path=`` so replica restarts warm-boot from disk.  All
+        ``fmt``/``buckets`` default from the saved artifact's manifest
+        when this design came from :func:`load`; pass ``artifact_path=``
+        so replica restarts warm-boot from disk.  All
         :class:`DesignEngine` keywords forward.
         """
         from repro.serving.design_engine import DesignEngine
+        _check_backend(backend)
         manifest = self.manifest or {}
-        for key in ("backend", "fmt"):
-            if kw.get(key) is None and manifest.get(key) is not None:
-                kw[key] = manifest[key]
+        if kw.get("fmt") is None and manifest.get("fmt") is not None:
+            kw["fmt"] = manifest["fmt"]
         # the saved warmed-bucket set only defaults when the caller pinned
         # neither buckets nor max_batch — an explicit max_batch must win
         # (the engine derives its buckets from it)
@@ -584,32 +547,31 @@ class Design:
         from repro.trigger import check_design
         return check_design(self, budget, part=part)
 
-    def trigger(self, **kw):
+    def trigger(self, *, backend: str = "pallas", **kw):
         """A streaming trigger loop over this design.
 
         Returns a :class:`repro.trigger.TriggerLoop` (pre-warmed on
         construction): feed it a :class:`repro.trigger.DetectorFeed` via
         ``loop.run(feed, n_frames, realtime=...)`` for accept/reject
         decisions with per-window deadline accounting.  All
-        ``TriggerLoop`` keywords forward (``backend``, ``budget``,
+        ``TriggerLoop`` keywords forward (``fmt``, ``budget``,
         ``threshold``, ``window``, ``capacity``...).
         """
         from repro.trigger import TriggerLoop
+        _check_backend(backend)
         return TriggerLoop(self, **kw)
 
     # -- persistence (warm-boot artifacts) -----------------------------------
 
     def save(self, path: Union[str, Path], *,
              buckets: Optional[Sequence[int]] = None,
-             backend: Optional[str] = None,
              fmt: Optional[str] = None) -> Path:
         """Persist a warm-boot artifact: design + weights + bucket manifest.
 
         The artifact bundles the full ``CompiledDesign`` (graphs, schedule,
         pass reports), the bound module with its trained params (numpy-
-        ified; an unpicklable ``forward_fn`` is dropped, disabling only the
-        tensor backend), the example inputs, and a serving manifest
-        (``buckets``/``backend``/``fmt`` defaults for :meth:`engine`).
+        ified), the example inputs, and a serving manifest
+        (``buckets``/``fmt`` defaults for :meth:`engine`).
         :func:`load` boots a replica from it without re-tracing or
         re-running passes — and the engine's restart path re-loads it when
         a replica is poisoned.  Written through the versioned pickle layer
@@ -622,23 +584,14 @@ class Design:
         if module is not None:
             params = _np_tree(module.params) \
                 if module.params is not None else None
-            fwd = module.forward_fn
-            if fwd is not None:
-                import pickle
-                try:
-                    pickle.dumps(fwd)
-                except Exception:
-                    fwd = None      # lambda forward: artifact serves via
-                    #                 simd/pallas only
             module_payload = ModuleGraph(
                 module.name, module.input_shape, module.nodes,
                 input_name=module.input_name, params=params,
-                forward_fn=fwd, meta=module.meta)
+                meta=module.meta)
         if buckets is None:
             from repro.serving.design_engine import default_buckets
             buckets = default_buckets(32)
-        manifest = {"buckets": list(buckets), "backend": backend,
-                    "fmt": fmt, "name": self.name,
+        manifest = {"buckets": list(buckets), "fmt": fmt, "name": self.name,
                     "design_hash": self.design_hash,
                     "fingerprint": self.fingerprint}
         example = self.example_inputs
@@ -835,7 +788,7 @@ def load(path: Union[str, Path], *,
     cold-boot-vs-warm-boot gap ``benchmarks/bench_serving.py`` measures.
     The artifact's warmed-bucket manifest rides along on
     ``design.manifest`` and defaults :meth:`Design.engine`'s
-    backend/fmt/buckets; the manifest also remembers this path, so engine
+    fmt/buckets; the manifest also remembers this path, so engine
     replica restarts re-load from it automatically.
     """
     from repro.core.pipeline import load_artifact

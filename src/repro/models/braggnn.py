@@ -1,18 +1,15 @@
 """BraggNN in JAX (paper Listing 5) — the production tensor-level twin of
 the scalar loop-nest program in ``repro.core.frontend.braggnn``.
 
-Used three ways:
-  * as the oracle the scalar DFG is behaviourally verified against;
-  * as the deployable low-latency inference path (fused jit, weights
-    quantised to FloPoCo (wE,wF) and resident in VMEM via the Pallas conv /
-    matmul kernels);
+Used two ways:
+  * as the oracle the scalar DFG and its compiled rendering are
+    behaviourally verified against;
   * as a trainable model (QAT with ``ste_quantize``) for the precision
     study (paper Fig. 7).
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -65,11 +62,8 @@ def build(s: int = 1, img: int = 11, *, params=None,
             nodes.append(nng.ReLU(out_name_=f"dense_{li}_relu",
                                   label_=f"dense.{li}.relu"))
     nodes.append(nng.OutputReLU(label_="dense.final_relu"))
-    # functools.partial (not a lambda) keeps the module picklable, which is
-    # what lets Design.save persist the tensor serving backend
     return nng.ModuleGraph(
         "braggnn", (1, 1, img, img), nodes, params=params,
-        forward_fn=functools.partial(forward, s=s),
         meta={"s": s, "img": img})
 
 

@@ -16,7 +16,6 @@ approximation error.
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -46,8 +45,6 @@ def build(seq: int = 16, d_model: int = 64, n_heads: int = 4,
     ]
     return nng.ModuleGraph(
         "encoder_block", (seq, d_model), nodes, params=params,
-        forward_fn=functools.partial(forward, n_heads=n_heads,
-                                     taylor_order=taylor_order, eps=eps),
         meta={"seq": seq, "d_model": d_model, "n_heads": n_heads,
               "ffn": ffn, "taylor_order": taylor_order})
 

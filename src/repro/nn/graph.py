@@ -28,7 +28,7 @@ bit-identical (fingerprint-equal) to the hand-written one.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Optional, Sequence
+from typing import Any, Optional, Sequence
 
 import numpy as np
 
@@ -505,15 +505,12 @@ class ModuleGraph:
     per-sample batch axis of the loop-nest program).  ``params`` optionally
     binds a trained param tree (structure of :meth:`specs`); bound modules
     compile to designs that :meth:`~repro.hls.Design.run` with the trained
-    weights without the caller passing weight feeds.  ``forward_fn`` is the
-    optional fused tensor-level twin ``(params, x, fmt=None) -> y`` used by
-    ``Design.serve``'s tensor backend.
+    weights without the caller passing weight feeds.
     """
 
     def __init__(self, name: str, input_shape: Sequence[int],
                  nodes: Sequence[Node], *, input_name: str = "input",
                  params: Any = None,
-                 forward_fn: Optional[Callable] = None,
                  meta: Optional[dict] = None):
         if not nodes:
             raise ValueError("ModuleGraph needs at least one node")
@@ -529,7 +526,6 @@ class ModuleGraph:
         self.input_name = input_name
         self.nodes = tuple(nodes)
         self.params = params
-        self.forward_fn = forward_fn
         self.meta = dict(meta or {})
 
     # -- shapes & parameters -------------------------------------------------
@@ -568,15 +564,15 @@ class ModuleGraph:
         """A copy of this module with ``params`` bound as the weights."""
         return ModuleGraph(self.name, self.input_shape, self.nodes,
                            input_name=self.input_name, params=params,
-                           forward_fn=self.forward_fn, meta=self.meta)
+                           meta=self.meta)
 
     # -- feeds ---------------------------------------------------------------
 
     def weight_feeds(self, params: Any = None) -> dict[str, np.ndarray]:
         """memref-name feed dict for the bound (or given) param tree.
 
-        Feeds are unbatched — ``emit.evaluate`` / ``to_jax_fn`` broadcast
-        weight feeds across the batch axis.
+        Feeds are unbatched — ``emit.evaluate`` and the compiled rendering
+        broadcast weight feeds across the batch axis.
         """
         params = self.params if params is None else params
         if params is None:

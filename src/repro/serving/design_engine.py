@@ -22,9 +22,9 @@ batch is re-queued at the head *in order*, so no request is dropped and a
 drained rerun is bit-identical to an uninterrupted one.  A
 ``StepWatchdog`` records straggler dispatches.
 
-All three emission backends serve: ``tensor`` (fused jit forward),
-``simd`` (emitted design), ``pallas`` (compiled rendering).  The engine
-reports sustained QPS, p50/p95/p99 latency and queue depth — the numbers
+The engine serves through the design's compiled rendering (the same
+``Design._runner`` the sync path uses) and reports sustained QPS,
+p50/p95/p99 latency and queue depth — the numbers
 ``benchmarks/bench_serving.py`` tracks instead of µs/sample-in-a-warm-loop.
 """
 
@@ -66,7 +66,6 @@ class EngineReport:
     serving paths land in one table.
     """
 
-    backend: str
     fmt: Optional[str]
     #: last replica boot time (runner build + bucket warm-up), seconds
     boot_s: float = 0.0
@@ -95,7 +94,7 @@ class EngineReport:
     mean_queue_depth: float = 0.0
     p95_queue_depth: float = 0.0
     straggler_dispatches: list = dataclasses.field(default_factory=list)
-    #: what actually served (the Pallas plan summary when applicable)
+    #: what actually served (the Pallas plan summary)
     served: Optional[str] = None
     fallbacks: list = dataclasses.field(default_factory=list)
 
@@ -106,7 +105,7 @@ class EngineReport:
         return (f"served {self.completed}/{self.submitted} requests @ "
                 f"{self.qps:.1f} req/s: p50 {self.p50_ms:.2f} / "
                 f"p95 {self.p95_ms:.2f} / p99 {self.p99_ms:.2f} ms "
-                f"[{self.served or self.backend} backend, {fmt}; "
+                f"[{self.served}, {fmt}; "
                 f"{self.dispatches} dispatches ({hist}), "
                 f"max queue {self.max_queue_depth}, "
                 f"{self.restarts} restarts, {self.dropped} dropped; "
@@ -117,7 +116,7 @@ class DesignEngine:
     """Adaptive-batching request engine fronting one compiled ``Design``.
 
     Construct via :meth:`repro.hls.Design.engine` (which defaults
-    ``backend``/``fmt``/``buckets`` from the saved artifact's warmed-bucket
+    ``fmt``/``buckets`` from the saved artifact's warmed-bucket
     manifest when the design was loaded with ``hls.load``).
 
     Two run modes:
@@ -131,8 +130,7 @@ class DesignEngine:
         max_batch)``), which is what the bit-identity tests rely on.
     """
 
-    def __init__(self, design, *, backend: Optional[str] = None,
-                 fmt: Optional[str] = None,
+    def __init__(self, design, *, fmt: Optional[str] = None,
                  buckets: Optional[Sequence[int]] = None,
                  max_batch: int = 32, max_delay_ms: float = 2.0,
                  artifact_path: Optional[Union[str, Path]] = None,
@@ -140,12 +138,6 @@ class DesignEngine:
                  watchdog: Optional[StepWatchdog] = None,
                  max_restarts: int = 4, max_retries: int = 2,
                  pallas_kw: Optional[dict] = None, warm: bool = True):
-        if backend is None:
-            module = design.module
-            backend = ("tensor" if module is not None
-                       and module.forward_fn is not None
-                       and module.params is not None else "simd")
-        self.backend = backend
         self.fmt = fmt
         self.buckets = (tuple(sorted(set(int(b) for b in buckets)))
                         if buckets else default_buckets(max_batch))
@@ -162,14 +154,9 @@ class DesignEngine:
 
         self._design = design
         self._input_name, self._input_shape = design._input_memref()
-        if backend == "tensor" and self._input_shape[0] != 1:
-            raise ValueError(
-                f"tensor backend batches over the memref's leading "
-                f"singleton axis; input {self._input_name!r} has shape "
-                f"{self._input_shape}")
         self._queue = RequestQueue()
         self._finished: list[QueuedRequest] = []
-        self._report = EngineReport(backend=backend, fmt=fmt)
+        self._report = EngineReport(fmt=fmt)
         self._thread: Optional[threading.Thread] = None
         self._stop_evt = threading.Event()
         self._t_first: Optional[float] = None
@@ -190,18 +177,17 @@ class DesignEngine:
         import jax
         t0 = time.perf_counter()
         with obs.span("serve.boot", cat="serve", source=source,
-                      backend=self.backend, buckets=list(self.buckets)):
+                      buckets=list(self.buckets)):
             if source == "artifact":
                 import repro.hls as hls
                 self._design = hls.load(self.artifact_path)
             self._run_one, served, fallbacks = self._design._runner(
-                self.backend, self.fmt, self.pallas_kw)
+                self.fmt, self.pallas_kw)
             self._report.served = served
             self._report.fallbacks = list(fallbacks)
             for b in self.buckets:                   # pre-warm every shape
                 zeros = np.zeros((b,) + self._input_shape, np.float32)
-                jax.block_until_ready(
-                    self._run_one(self._as_backend_batch(zeros)))
+                jax.block_until_ready(self._run_one(zeros))
         boot_s = time.perf_counter() - t0
         obs.inc("serve.boots")
         self._report.boot_s = boot_s
@@ -236,15 +222,6 @@ class DesignEngine:
 
     # -- dispatch -----------------------------------------------------------
 
-    def _as_backend_batch(self, stacked: np.ndarray):
-        """A (bucket,)+memref batch -> what this backend's runner takes."""
-        if self.backend == "tensor":
-            # collapse the memref's per-sample singleton batch axis into
-            # the throughput batch (the fused forward is (B, C, H, W))
-            return stacked.reshape(stacked.shape[0],
-                                   *self._input_shape[1:])
-        return stacked        # simd/pallas runners coerce via design.feeds
-
     def _bucket_for(self, n: int) -> int:
         for b in self.buckets:
             if b >= n:
@@ -252,9 +229,7 @@ class DesignEngine:
         return self.max_batch
 
     def _split(self, out, i: int):
-        if isinstance(out, dict):
-            return {k: np.asarray(v)[i] for k, v in out.items()}
-        return np.asarray(out)[i]
+        return {k: np.asarray(v)[i] for k, v in out.items()}
 
     def _dispatch(self, reqs: list[QueuedRequest]) -> None:
         """Run one snapped batch; on failure, restart the replica and
@@ -282,8 +257,7 @@ class DesignEngine:
             t0 = time.perf_counter()
             try:
                 self.injector.check(idx)
-                out = jax.block_until_ready(
-                    self._run_one(self._as_backend_batch(stacked)))
+                out = jax.block_until_ready(self._run_one(stacked))
             except Exception as exc:
                 rep.restarts += 1
                 obs.inc("serve.restarts")
@@ -430,6 +404,6 @@ class DesignEngine:
             rep.wall_s = self._t_last - self._t_first
             rep.qps = rep.completed / rep.wall_s
         if rep.completed and rep.compute_s:
-            obs.gauge(f"serve.us_per_sample.{self.backend}",
+            obs.gauge("serve.us_per_sample",
                       rep.compute_s / rep.completed * 1e6)
         return rep

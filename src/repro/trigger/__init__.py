@@ -16,7 +16,7 @@ This package makes that setting first-class instead of folklore:
   * :mod:`repro.trigger.stream` — :class:`DetectorFeed` (seeded
     Bragg-peak frames with pileup bursts), the drop-oldest ring, and
     :class:`TriggerLoop` emitting accept/reject decisions with
-    per-window deadline accounting on any emission backend.
+    per-window deadline accounting over the compiled design.
 
 Quickstart::
 
@@ -27,7 +27,7 @@ Quickstart::
                                    part="alveo_u280", margin=0.1)
     design.check_budget(budget=budget).raise_if_failed()
 
-    loop = trigger.TriggerLoop(design, budget=budget, backend="pallas")
+    loop = trigger.TriggerLoop(design, budget=budget)
     report = loop.run(trigger.DetectorFeed(img=11, frame_rate_hz=2000),
                       n_frames=1000, realtime=True)
     print(report.summary())     # sustained fps, miss %, drop %, p99 µs

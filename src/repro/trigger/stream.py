@@ -10,13 +10,13 @@ Three pieces:
 
   * :class:`DetectorFeed` — seeded synthetic Bragg-peak frame generator
     with a configurable event rate and periodic **pileup bursts**
-    (several peaks per frame), so every backend and every PR sees the
+    (several peaks per frame), so every run and every PR sees the
     same stream bit-for-bit;
   * the bounded drop-oldest ring
     (:class:`repro.serving.common.DropOldestRing`) between producer and
     trigger — the explicit overrun policy;
   * :class:`TriggerLoop` — pulls fixed-size windows, runs them through a
-    pre-warmed ``Design._runner`` (any emission backend), applies a
+    pre-warmed ``Design._runner`` (the compiled rendering), applies a
     threshold predicate, and emits :class:`TriggerDecision` records with
     per-window deadline accounting (met/missed, slack µs).
 
@@ -136,10 +136,11 @@ class TriggerDecision:
 class TriggerReport:
     """Stream-level accounting of one :meth:`TriggerLoop.run`."""
 
-    backend: str
     fmt: Optional[str]
     window: int
     realtime: bool
+    #: what decided — the Pallas lowering's plan summary
+    served: Optional[str] = None
     frames: int = 0               # offered by the feed
     processed: int = 0            # reached a decision
     dropped: int = 0              # lost to ring overrun
@@ -176,7 +177,7 @@ class TriggerReport:
                 f"{self.sustained_fps:.0f} fps sustained, decision p50 "
                 f"{self.p50_us:.0f} / p95 {self.p95_us:.0f} / p99 "
                 f"{self.p99_us:.0f} us{deadline} "
-                f"[{self.backend} backend, warm-up {self.warmup_s:.2f}s]")
+                f"[{self.served}, warm-up {self.warmup_s:.2f}s]")
 
     def to_json(self) -> dict:
         d = {k: v for k, v in dataclasses.asdict(self).items()
@@ -195,10 +196,8 @@ def threshold_predicate(threshold: float) -> Callable:
     """The stock predicate: accept when any output magnitude clears
     ``threshold``.  Batched: returns per-sample ``(accepts, scores)``."""
     def predicate(outputs) -> tuple[np.ndarray, np.ndarray]:
-        vals = (outputs.values() if isinstance(outputs, dict)
-                else (outputs,))
         score = None
-        for v in vals:
+        for v in outputs.values():
             arr = np.abs(np.asarray(v, dtype=np.float32))
             s = arr.reshape(arr.shape[0], -1).max(axis=1)
             score = s if score is None else np.maximum(score, s)
@@ -210,8 +209,8 @@ class TriggerLoop:
     """Streaming accept/reject over a pre-warmed compiled design.
 
     ``design`` is a ``repro.hls.Design``; the loop serves through the
-    same ``Design._runner`` the sync/async serving paths use, so any
-    emission backend (``tensor`` / ``simd`` / ``pallas``) triggers.
+    same ``Design._runner`` the sync/async serving paths use: the
+    design's compiled rendering decides.
     ``window`` frames are stacked into one fixed-shape inference (the
     only shape warmed — no re-jits on the hot path); ``predicate``
     maps the window's outputs to per-frame ``(accepts, scores)``
@@ -221,8 +220,7 @@ class TriggerLoop:
     one ``trigger.window`` span per dispatched window).
     """
 
-    def __init__(self, design, *, backend: Optional[str] = None,
-                 fmt: Optional[str] = None,
+    def __init__(self, design, *, fmt: Optional[str] = None,
                  budget: Optional[TriggerBudget] = None,
                  threshold: float = 0.75,
                  predicate: Optional[Callable] = None,
@@ -230,13 +228,7 @@ class TriggerLoop:
                  pallas_kw: Optional[dict] = None, warm: bool = True):
         if window < 1:
             raise ValueError(f"window must be >= 1, got {window}")
-        if backend is None:
-            module = design.module
-            backend = ("tensor" if module is not None
-                       and module.forward_fn is not None
-                       and module.params is not None else "simd")
         self.design = design
-        self.backend = backend
         self.fmt = fmt
         self.budget = budget
         self.window = window
@@ -244,8 +236,8 @@ class TriggerLoop:
         self._user_predicate = predicate
         self.ring = DropOldestRing(capacity)
         self._input_name, self._input_shape = design._input_memref()
-        self._run_one, self._served, _ = design._runner(
-            backend, fmt, dict(pallas_kw or {}))
+        self._run_one, self.served, _ = design._runner(
+            fmt, dict(pallas_kw or {}))
         self.warmup_s = 0.0
         if warm:
             self.warmup()
@@ -280,15 +272,15 @@ class TriggerLoop:
         for frame in feed.frames(n_frames):
             batch.append(frame)
             if len(batch) == self.window:
-                out = jax.block_until_ready(self._run_one(self._as_batch(
-                    np.stack([f.data for f in batch]).astype(np.float32))))
+                out = jax.block_until_ready(self._run_one(
+                    np.stack([f.data for f in batch]).astype(np.float32)))
                 scores.extend(np.asarray(score_of(out)[1]).reshape(-1))
                 batch = []
         if batch:
             n_real = len(batch)
-            out = jax.block_until_ready(self._run_one(self._as_batch(
+            out = jax.block_until_ready(self._run_one(
                 np.stack([f.data for f in self._pad(batch)]
-                         ).astype(np.float32))))
+                         ).astype(np.float32)))
             scores.extend(np.asarray(score_of(out)[1]).reshape(-1)[:n_real])
         self.threshold = float(np.quantile(np.asarray(scores), quantile))
         return self.threshold
@@ -299,17 +291,10 @@ class TriggerLoop:
         t0 = time.perf_counter()
         zeros = np.zeros((self.window,) + tuple(self._input_shape),
                          np.float32)
-        with obs.span("trigger.warmup", cat="trigger", backend=self.backend,
-                      window=self.window):
-            jax.block_until_ready(self._run_one(self._as_batch(zeros)))
+        with obs.span("trigger.warmup", cat="trigger", window=self.window):
+            jax.block_until_ready(self._run_one(zeros))
         self.warmup_s = time.perf_counter() - t0
         return self.warmup_s
-
-    def _as_batch(self, stacked: np.ndarray):
-        if self.backend == "tensor":
-            # fused forward batches over the memref's singleton axis
-            return stacked.reshape(stacked.shape[0], *self._input_shape[1:])
-        return stacked
 
     def _decide(self, frames: list[Frame], n_real: int, t_ref: list[float],
                 report: TriggerReport) -> None:
@@ -319,8 +304,8 @@ class TriggerLoop:
         idx = report.windows
         report.windows += 1
         with obs.span("trigger.window", cat="trigger", window=idx,
-                      frames=n_real, backend=self.backend) as sp:
-            out = jax.block_until_ready(self._run_one(self._as_batch(stacked)))
+                      frames=n_real) as sp:
+            out = jax.block_until_ready(self._run_one(stacked))
             accepts, scores = self.predicate(out)
             t_done = time.perf_counter()
             accepts = np.asarray(accepts).reshape(-1)[:n_real]
@@ -372,7 +357,7 @@ class TriggerLoop:
         queueing, and a trigger slower than the feed *loses frames*
         (reported, never blocking the producer).
         """
-        report = TriggerReport(backend=self.backend, fmt=self.fmt,
+        report = TriggerReport(fmt=self.fmt, served=self.served,
                                window=self.window, realtime=realtime,
                                frames=n_frames, warmup_s=self.warmup_s,
                                deadline_us=self.budget.max_latency_us

@@ -4,7 +4,7 @@
     PYTHONPATH=src python -m repro.tune --config braggnn --dry --budget 3
     PYTHONPATH=src python -m repro.tune --config braggnn --show
 
-``--dry`` skips wall-clocking the emitted SIMD design and relies on the
+``--dry`` skips wall-clocking the compiled rendering and relies on the
 scheduled-latency objective plus the roofline CPU estimate — the CI-safe
 mode.  Results persist to the ``TuningDB`` (``--db`` overrides the shared
 versioned cache root); a rerun whose budget is already covered is served

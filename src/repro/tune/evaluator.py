@@ -14,10 +14,10 @@ Every candidate accepted by the tuner passes through three gates here:
   3. **latency** — the objective.  The primary metric is the scheduled
      design's per-sample latency (initiation interval x 10 ns for
      stage-pipelined designs, else makespan x 10 ns — the paper's interval
-     counts).  In ``measure`` mode the emitted SIMD design is additionally
-     wall-clocked; in ``--dry`` mode a roofline-style cost model
-     (``launch.roofline`` machine constants) estimates the CPU path
-     instead.
+     counts).  In ``measure`` mode the design's compiled rendering (the
+     DFG tier of ``emit_pallas``) is additionally wall-clocked; in
+     ``--dry`` mode a roofline-style cost model (``launch.roofline``
+     machine constants) estimates the CPU path instead.
 """
 
 from __future__ import annotations
@@ -207,9 +207,9 @@ class Evaluator:
         return err
 
     def _measure_cpu_us(self, design: CompiledDesign) -> float:
-        """Wall-clock the emitted SIMD design (us per sample).
+        """Wall-clock the compiled rendering's DFG tier (us per sample).
 
-        Memoised on the pass key — the emitted function depends only on the
+        Memoised on the pass key — the lowered function depends only on the
         optimised graph, never on the schedule knobs.
         """
         key = design.config.pass_key()
@@ -217,7 +217,7 @@ class Evaluator:
         if cached is not None:
             return cached
         import jax
-        fn = jax.jit(design.jax_fn())
+        fn = design.jax_fn()                         # jitted already
         batch = len(next(iter(self.feeds.values())))
         jax.block_until_ready(fn(self.feeds))        # compile + warm up
         t0 = time.perf_counter()

@@ -1,9 +1,10 @@
-"""The Pallas emission backend vs the functional simulator.
+"""The compiled rendering (``emit_pallas``) vs the functional simulator.
 
-Backend equivalence (``backend='pallas'`` vs ``emit.evaluate``, fp32 and
-quantised) on a conv2d design and on bridged BraggNN(s=1); the per-group
-tensor fallback path; the kernel registry's pattern table; and the
-``serve``/``to_jax_fn`` backend-validation contract.
+Equivalence (``to_pallas_fn`` vs ``emit.evaluate``, fp32 and quantised) on
+a conv2d design and on bridged BraggNN(s=1); the per-group tensor fallback
+path; the kernel registry's pattern table; the one serving path that
+``serve``, ``engine`` and ``trigger`` take by default; and the refusal of
+any other ``backend``.
 """
 
 import numpy as np
@@ -59,7 +60,7 @@ def bragg_feeds(bragg_design):
 def test_conv_dfg_matches_evaluate_fp32(conv_design, conv_feeds):
     g = conv_design.graph_opt
     ref = emit.evaluate(g, conv_feeds)
-    fn = emit.to_jax_fn(g, backend="pallas")
+    fn = to_pallas_fn(g)
     out = fn(conv_feeds)
     assert fn.plan.mode == "dfg"
     assert fn.plan.n_segments >= 1
@@ -74,7 +75,7 @@ def test_conv_dfg_matches_evaluate_quantised(conv_design, conv_feeds):
     functional model, matching ``emit.evaluate`` tightly."""
     g = conv_design.graph_opt
     ref = emit.evaluate(g, conv_feeds, fmt=FORMATS["5_4"])
-    fn = emit.to_jax_fn(g, backend="pallas", fmt="5_4")
+    fn = to_pallas_fn(g, fmt="5_4")
     out = fn(conv_feeds)
     for k in ref:
         np.testing.assert_allclose(np.asarray(out[k]), ref[k], atol=1e-5)
@@ -85,7 +86,7 @@ def test_conv_dfg_real_pallas_call_interpret(conv_design, conv_feeds):
     CPU) — the CI pallas-smoke path."""
     g = conv_design.graph_opt
     ref = emit.evaluate(g, conv_feeds)
-    fn = emit.to_jax_fn(g, backend="pallas", use_pallas=True)
+    fn = to_pallas_fn(g, use_pallas=True)
     assert fn.plan.use_pallas and fn.plan.interpret
     assert "interpret=True" in fn.plan.summary()
     out = fn(conv_feeds)
@@ -101,7 +102,7 @@ def test_conv_dfg_per_group_fallback(conv_design, conv_feeds):
     table = {k: v for k, v in registry.OPCODE_KERNELS.items()
              if k != "fmac"}
     ref = emit.evaluate(g, conv_feeds)
-    fn = emit.to_jax_fn(g, backend="pallas", opcode_table=table)
+    fn = to_pallas_fn(g, opcode_table=table)
     out = fn(conv_feeds)
     assert fn.plan.fallbacks, "dropping fmac must force fallbacks"
     assert all("fmac" in f for f in fn.plan.fallbacks)
@@ -114,7 +115,7 @@ def test_dfg_unbatched_feeds_broadcast(conv_design):
     feeds = verify.random_feeds(conv_design.graph_raw, batch=1, seed=3)
     unbatched = {k: np.asarray(v)[0] for k, v in feeds.items()}
     ref = emit.evaluate(conv_design.graph_opt, unbatched)
-    out = emit.to_jax_fn(conv_design.graph_opt, backend="pallas")(unbatched)
+    out = to_pallas_fn(conv_design.graph_opt)(unbatched)
     for k in ref:
         np.testing.assert_allclose(np.asarray(out[k]), ref[k],
                                    rtol=1e-5, atol=1e-4)
@@ -128,7 +129,7 @@ def test_dfg_unbatched_feeds_broadcast(conv_design):
 def test_braggnn_nest_tier_matches_evaluate(bragg_design, bragg_feeds):
     g = bragg_design.graph_opt
     ref = emit.evaluate(g, bragg_feeds)
-    fn = bragg_design.jax_fn(backend="pallas")
+    fn = bragg_design.jax_fn()
     assert fn.plan.mode == "nests"
     assert fn.plan.kernels, "registry kernels must serve the bridged nests"
     assert any(k.startswith("conv2d_vmem") for k in fn.plan.kernels)
@@ -146,7 +147,7 @@ def test_braggnn_nest_tier_pallas_interpret_matches_evaluate(bragg_design,
     fused_softmax) through ``pl.pallas_call``, here in the interpreter."""
     g = bragg_design.graph_opt
     ref = emit.evaluate(g, bragg_feeds)
-    fn = bragg_design.jax_fn(backend="pallas", use_pallas=True)
+    fn = bragg_design.jax_fn(use_pallas=True)
     assert fn.plan.use_pallas and fn.plan.interpret
     assert not fn.plan.fallbacks
     assert "Pallas interpreter" in fn.plan.summary()
@@ -211,7 +212,7 @@ def test_dfg_tier_on_tpu_runs_xla_bodies(conv_design, conv_feeds,
     from repro.core import emit_pallas
     monkeypatch.setattr(emit_pallas, "_on_tpu", lambda: True)
     g = conv_design.graph_opt
-    fn = emit.to_jax_fn(g, backend="pallas")
+    fn = to_pallas_fn(g)
     assert not fn.plan.use_pallas and not fn.plan.interpret
     assert "XLA segment bodies" in fn.plan.summary()
     ref = emit.evaluate(g, conv_feeds)
@@ -220,14 +221,14 @@ def test_dfg_tier_on_tpu_runs_xla_bodies(conv_design, conv_feeds,
         np.testing.assert_allclose(np.asarray(out[k]), ref[k],
                                    rtol=1e-5, atol=1e-4)
     with pytest.raises(NotImplementedError, match="Mosaic.*gather"):
-        emit.to_jax_fn(g, backend="pallas", use_pallas=True)
+        to_pallas_fn(g, use_pallas=True)
 
 
 def test_nest_tier_on_tpu_plans_mosaic_kernels(bragg_design, monkeypatch):
     from repro.core import emit_pallas
     monkeypatch.setattr(emit_pallas, "_on_tpu", lambda: True)
     for fmt in (None, "5_4"):
-        plan = bragg_design.jax_fn(backend="pallas", fmt=fmt).plan
+        plan = bragg_design.jax_fn(fmt=fmt).plan
         assert plan.use_pallas and not plan.interpret
         assert not plan.fallbacks
         assert "use_pallas=True interpret=False" in plan.summary()
@@ -238,7 +239,7 @@ def test_braggnn_dfg_tier_quantised_matches_evaluate(bragg_design,
                                                      bragg_feeds):
     g = bragg_design.graph_opt
     ref = emit.evaluate(g, bragg_feeds, fmt=FORMATS["5_4"])
-    fn = bragg_design.jax_fn(backend="pallas", mode="dfg", fmt="5_4")
+    fn = bragg_design.jax_fn(mode="dfg", fmt="5_4")
     assert fn.plan.mode == "dfg"
     out = fn(bragg_feeds)
     for k in ref:
@@ -246,14 +247,14 @@ def test_braggnn_dfg_tier_quantised_matches_evaluate(bragg_design,
 
 
 def test_braggnn_scatter_gather_fusion_happens(bragg_design):
-    fn = bragg_design.jax_fn(backend="pallas", mode="dfg")
+    fn = bragg_design.jax_fn(mode="dfg")
     assert fn.plan.fused_scatters > 0, \
         "aligned scatter->gather pairs must be forwarded in-register"
 
 
 def test_nest_tier_rejects_per_sample_weights(bragg_design):
     feeds = verify.random_feeds(bragg_design.graph_raw, batch=2, seed=1)
-    fn = bragg_design.jax_fn(backend="pallas")
+    fn = bragg_design.jax_fn()
     with pytest.raises(ValueError, match="varies across the batch"):
         fn(feeds)
 
@@ -263,7 +264,7 @@ def test_nest_tier_flash_attention_mode(bragg_design, bragg_feeds):
     approximation of the Taylor functional model — recorded as a note."""
     g = bragg_design.graph_opt
     ref = emit.evaluate(g, bragg_feeds)
-    fn = bragg_design.jax_fn(backend="pallas", nlb_flash=True)
+    fn = bragg_design.jax_fn(nlb_flash=True)
     assert "flash_attention" in fn.plan.kernels
     assert any("flash" in n for n in fn.plan.notes)
     out = fn(bragg_feeds)
@@ -279,7 +280,6 @@ def test_nest_tier_flash_attention_mode(bragg_design, bragg_feeds):
 def test_serve_pallas_backend_and_report(conv_design, conv_feeds):
     rep = conv_design.serve([conv_feeds, conv_feeds], backend="pallas",
                             collect=True)
-    assert rep.backend == "pallas"
     assert rep.served and rep.served.startswith("pallas[dfg]")
     assert rep.batches == 2 and rep.samples == 6
     ref = emit.evaluate(conv_design.graph_opt, conv_feeds)
@@ -288,18 +288,52 @@ def test_serve_pallas_backend_and_report(conv_design, conv_feeds):
                                    rtol=1e-5, atol=1e-4)
 
 
+@pytest.mark.parametrize("tier", ["nests", "dfg"])
+def test_default_serving_path_is_compiled(tier, request, conv_feeds):
+    """With no ``backend``, a bridged design serves, batches and triggers
+    through the nest tier; a loop nest serves through the DFG tier (its
+    weights are graph inputs, so only ``serve`` takes them)."""
+    if tier == "dfg":
+        design = request.getfixturevalue("conv_design")
+        rep = design.serve([conv_feeds], collect=True)
+        assert rep.served.startswith("pallas[dfg]")
+        ref = emit.evaluate(design.graph_opt, conv_feeds)
+        for k in ref:
+            np.testing.assert_allclose(np.asarray(rep.outputs[0][k]), ref[k],
+                                       rtol=1e-5, atol=1e-4)
+        return
+    from repro import trigger
+    design = request.getfixturevalue("bragg_design")
+    x = np.zeros((2, 1, 1, 9, 9), np.float32)
+    assert design.serve([x]).served.startswith("pallas[nests]")
+    eng = design.engine(buckets=(2,))
+    assert eng.report().served.startswith("pallas[nests]")
+    loop = design.trigger(window=2, threshold=0.0)
+    assert loop.served.startswith("pallas[nests]")
+    rep = loop.run(trigger.DetectorFeed(img=9, seed=0), 2)
+    assert rep.served == loop.served and rep.processed == 2
+
+
 def test_serve_rejects_unknown_backend(conv_design, conv_feeds):
-    with pytest.raises(ValueError, match="'tensor', 'simd' or 'pallas'"):
-        conv_design.serve([conv_feeds], backend="veryl")
+    """``pallas`` is the only serving path: every other name is refused
+    by ``serve``, ``engine`` and ``trigger`` before anything is lowered."""
+    for backend in ("tensor", "simd", "veryl"):
+        with pytest.raises(ValueError, match="'pallas' is the only"):
+            conv_design.serve([conv_feeds], backend=backend)
+        with pytest.raises(ValueError, match="'pallas' is the only"):
+            conv_design.engine(backend=backend)
+        with pytest.raises(ValueError, match="'pallas' is the only"):
+            conv_design.trigger(backend=backend)
 
 
 def test_to_jax_fn_rejects_unknown_backend(conv_design):
-    with pytest.raises(ValueError, match="simd, pallas"):
-        emit.to_jax_fn(conv_design.graph_opt, backend="veryl")
-    with pytest.raises(ValueError, match="simd, pallas"):
-        conv_design.jax_fn(backend="veryl")
-    with pytest.raises(TypeError, match="simd"):
-        emit.to_jax_fn(conv_design.graph_opt, fmt="5_4")
+    """``jax_fn`` takes lowering keywords only; the SIMD emitter is gone."""
+    assert not hasattr(emit, "to_jax_fn")
+    for backend in ("simd", "pallas"):
+        with pytest.raises(TypeError, match="backend"):
+            conv_design.jax_fn(backend=backend)
+        with pytest.raises(TypeError, match="backend"):
+            conv_design.compiled.jax_fn(backend=backend)
 
 
 def test_to_pallas_fn_rejects_unknown_mode(conv_design):

@@ -1,18 +1,15 @@
-"""The public API surface: ``repro.hls`` contract + deprecation shims.
+"""The public API surface: the ``repro.hls`` contract.
 
-Covers the api_redesign acceptance criteria: the documented ``__all__``
-surface, warn-once deprecation shims that forward to ``repro.hls``, and
-bit-identity (CompiledDesign hash) of ``hls.compile`` with the direct
+Covers the documented ``__all__`` surface and bit-identity
+(CompiledDesign hash) of ``hls.compile`` with the direct
 ``CompilerDriver`` path.
 """
-
-import warnings
 
 import numpy as np
 import pytest
 
 import repro.hls as hls
-from repro.core import frontend, pipeline, verify
+from repro.core import frontend, verify
 from repro.core.pipeline import CompilerConfig, CompilerDriver
 
 #: The documented surface (README "Public API" section).  Additions are
@@ -120,12 +117,14 @@ def test_session_cache_hit():
 
 
 def test_serve_simd_backend(design):
+    """A loop nest serves through the compiled rendering's DFG tier."""
     x = np.random.default_rng(0).normal(
         0, 0.5, (4, 3, 8, 8)).astype(np.float32)
     weights = verify.random_feeds(design.graph_raw, batch=1, seed=1)
     feeds = {k: v[0] for k, v in weights.items() if k != "input"}
     feeds["input"] = x[:, None]
-    rep = design.serve([feeds, feeds], backend="simd", collect=True)
+    rep = design.serve([feeds, feeds], collect=True)
+    assert rep.served.startswith("pallas[dfg]")
     assert rep.batches == 2 and rep.samples == 8
     assert rep.us_per_sample > 0
     assert len(rep.outputs) == 2
@@ -178,25 +177,3 @@ def test_tune_persists_and_apply_tuned_loads(design, tmp_path, caplog):
                          db=empty)
     assert d4.tuned_candidate is None
     assert str(empty.path) in caplog.text
-
-
-# ---------------------------------------------------------------------------
-# Deprecation shims
-# ---------------------------------------------------------------------------
-
-
-def test_shims_warn_exactly_once():
-    pipeline._DEPRECATION_WARNED.clear()
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        a = pipeline.compile(conv_build, name="shim")
-        b = pipeline.compile(conv_build, name="shim")
-        drv = pipeline.default_driver()
-        pipeline.default_driver()
-    dep = [w for w in rec if issubclass(w.category, DeprecationWarning)]
-    assert len(dep) == 2, [str(w.message) for w in dep]
-    assert any("repro.hls.compile" in str(w.message) for w in dep)
-    # the shims forward to the hls layer: same artifact type, same session
-    assert isinstance(a, hls.CompiledDesign)
-    assert a is b                                   # served from the cache
-    assert drv is hls._default_session().driver

@@ -65,7 +65,7 @@ def _assert_matches(out, ref):
 
 @pytest.mark.parametrize("fmt", [None, "5_4"], ids=["fp32", "5_4"])
 def test_resident_path_bit_identical_to_fed_path(design, x, fmt):
-    fn = design.jax_fn(backend="pallas", fmt=fmt)
+    fn = design.jax_fn(fmt=fmt)
     assert _count("nest.weight_uploads") == 1
     resident = fn({"input": x})
     fed = fn(design.feeds(x))
@@ -79,7 +79,7 @@ def test_resident_path_bit_identical_to_fed_path(design, x, fmt):
 
 def test_serve_uploads_once_and_every_call_is_resident(design, x):
     n = 5
-    rep = design.serve([x] * n, backend="pallas", collect=True)
+    rep = design.serve([x] * n, collect=True)
     assert rep.batches == n
     # the warm-up call, then every batch
     assert _count("nest.weight_uploads") == 1
@@ -90,7 +90,7 @@ def test_serve_uploads_once_and_every_call_is_resident(design, x):
 
 
 def test_trigger_uploads_once_and_every_window_is_resident(design):
-    loop = design.trigger(backend="pallas", window=4, threshold=0.0)
+    loop = design.trigger(window=4, threshold=0.0)
     assert _count("nest.weight_uploads") == 1
     assert _count("nest.calls_resident") == 1            # warm-up
     rep = loop.run(trigger.DetectorFeed(img=IMG, seed=3), 10)
@@ -101,7 +101,7 @@ def test_trigger_uploads_once_and_every_window_is_resident(design):
 
 
 def test_engine_warms_every_bucket_on_the_resident_path(design, x):
-    eng = design.engine(backend="pallas", max_batch=4)
+    eng = design.engine(max_batch=4)
     buckets = len(eng.buckets)
     assert _count("nest.weight_uploads") == 1
     assert _count("nest.calls_resident") == buckets
@@ -119,7 +119,7 @@ def test_engine_warms_every_bucket_on_the_resident_path(design, x):
 def test_fed_weights_of_another_param_set_win(design, module, x):
     other = module.weight_feeds(module.init_params(jax.random.PRNGKey(1)))
     feeds = {"input": x, **other}
-    fn = design.jax_fn(backend="pallas")
+    fn = design.jax_fn()
     out = fn(feeds)
     assert _count("nest.calls_fed") == 1
     assert _count("nest.calls_resident") == 0
@@ -132,7 +132,7 @@ def test_fed_weights_of_another_param_set_win(design, module, x):
 def test_one_fed_weight_joins_the_resident_rest(design, module, x):
     other = module.weight_feeds(module.init_params(jax.random.PRNGKey(1)))
     name = sorted(other)[0]
-    fn = design.jax_fn(backend="pallas")
+    fn = design.jax_fn()
     out = fn({"input": x, name: other[name]})
     assert _count("nest.calls_fed") == 1
     merged = dict(design.feeds(x))
@@ -143,14 +143,14 @@ def test_one_fed_weight_joins_the_resident_rest(design, module, x):
 def test_per_sample_weights_still_rejected(design, module, x):
     name, w = sorted(module.weight_feeds().items())[0]
     varied = np.stack([w + i for i in range(len(x))]).astype(np.float32)
-    fn = design.jax_fn(backend="pallas")
+    fn = design.jax_fn()
     with pytest.raises(ValueError, match="varies across the batch"):
         fn({"input": x, name: varied})
 
 
 def test_unbound_module_still_needs_weight_feeds(x):
     design = hls.compile(braggnn.build(1, img=IMG))
-    fn = design.jax_fn(backend="pallas")
+    fn = design.jax_fn()
     assert _count("nest.weight_uploads") == 0
     with pytest.raises(KeyError, match="missing weight feeds"):
         fn({"input": x})
@@ -164,9 +164,9 @@ def test_annotated_calls_keep_one_feed_dict(design, x):
     devtrace.annotate_calls(design)
     try:
         ref = emit.evaluate(design.graph_opt, design.feeds(x))
-        rep = design.serve([x, x], backend="pallas", collect=True)
+        rep = design.serve([x, x], collect=True)
         _assert_matches(rep.outputs[-1], ref)
-        eng = design.engine(backend="pallas", max_batch=4)
+        eng = design.engine(max_batch=4)
         reqs = [eng.submit(s) for s in x]
         eng.run_until_drained()
         for i, r in enumerate(reqs):

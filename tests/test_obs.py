@@ -262,7 +262,7 @@ def test_engine_request_spans_and_queue_histogram():
     model = braggnn.build(1, 9)
     params = model.init_params(jax.random.key(0))
     design = hls.Session().compile(model.bind(params), name="obs_engine")
-    eng = design.engine(backend="tensor", max_batch=4)
+    eng = design.engine(max_batch=4)
     rng = np.random.default_rng(0)
     xs = [rng.normal(0, 0.25, (1, 1, 9, 9)).astype(np.float32)
           for _ in range(6)]
@@ -289,7 +289,7 @@ def test_concurrent_engine_submissions_keep_spans_consistent():
     model = braggnn.build(1, 9)
     params = model.init_params(jax.random.key(0))
     design = hls.Session().compile(model.bind(params), name="obs_threads")
-    eng = design.engine(backend="tensor", max_batch=4, max_delay_ms=1.0)
+    eng = design.engine(max_batch=4, max_delay_ms=1.0)
     rng = np.random.default_rng(0)
     xs = [rng.normal(0, 0.25, (1, 1, 9, 9)).astype(np.float32)
           for _ in range(12)]

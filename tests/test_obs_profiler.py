@@ -79,7 +79,7 @@ def _inside(inner, outer):
 
 
 def test_nest_spans_land_in_the_profile(design, tmp_path):
-    run_one, _, _ = design._runner("pallas", None, None)
+    run_one, _, _ = design._runner(None, None)
     x = np.random.default_rng(0).normal(
         0, 0.5, (2, 1, IMG, IMG)).astype(np.float32)
     fed = design.feeds(x)                       # the weights with the call
@@ -112,7 +112,7 @@ def test_nest_spans_land_in_the_profile(design, tmp_path):
 def test_enabled_spans_reach_both_sinks_and_a_retrace_shows(design,
                                                             tmp_path):
     obs.enable()
-    run_one, _, _ = design._runner("pallas", None, None)
+    run_one, _, _ = design._runner(None, None)
     x = np.zeros((3, 1, IMG, IMG), np.float32)  # a new batch: traced anew
     with jax.profiler.trace(str(tmp_path), profiler_options=_options()):
         jax.block_until_ready(run_one(x))
@@ -173,7 +173,7 @@ def test_obs_imports_without_jax():
 
 def test_realtime_trigger_records_one_wait_per_window(design):
     obs.enable()
-    loop = design.trigger(backend="pallas", window=4)
+    loop = design.trigger(window=4)
     rep = loop.run(trigger.DetectorFeed(img=IMG, frame_rate_hz=400, seed=2),
                    30, realtime=True)
     spans = obs.tracer.spans()

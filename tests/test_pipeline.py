@@ -137,7 +137,7 @@ def test_cache_hit_on_identical_content_miss_on_config_change(tmp_path):
     assert (driver2.cache.hits, driver2.cache.misses) == (1, 0)
     assert d4.design_hash == d1.design_hash
     assert d4.makespan == d1.makespan
-    # the jax fn was dropped at pickle time and re-emits on demand
+    # nothing compiled is pickled: the loaded design lowers on demand
     feeds = verify.random_feeds(d4.graph_raw, batch=2, seed=3)
     np.testing.assert_allclose(
         np.asarray(d4.jax_fn()(feeds)["out"]),
@@ -187,7 +187,7 @@ def test_compile_equals_hand_stitched_flow_on_braggnn():
     assert design.schedule.start == sched.start
     assert design.schedule.resource_units == sched.resource_units
 
-    # identical numerics: functional sim and emitted SIMD design
+    # identical numerics: functional sim through both flows
     feeds = verify.random_feeds(g_raw, batch=4, seed=0, scale=0.4)
     out_hand = emit.evaluate(g_opt, feeds)
     out_drv = design.evaluate(feeds)

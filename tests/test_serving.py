@@ -108,13 +108,10 @@ def _drain(engine, xs):
 
 
 def _assert_same(a, b):
-    """Bit-identity across array outputs (tensor) or memref dicts (simd)."""
-    if isinstance(a, dict):
-        assert a.keys() == b.keys()
-        for k in a:
-            np.testing.assert_array_equal(np.asarray(a[k]), np.asarray(b[k]))
-    else:
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    """Bit-identity across output memref dicts."""
+    assert a.keys() == b.keys()
+    for k in a:
+        np.testing.assert_array_equal(np.asarray(a[k]), np.asarray(b[k]))
 
 
 def test_default_buckets():
@@ -126,11 +123,11 @@ def test_default_buckets():
 
 
 def test_engine_sync_mode_serves_all_requests(bound_design, samples):
-    eng = bound_design.engine(backend="tensor", max_batch=4)
+    eng = bound_design.engine(max_batch=4)
     outs = _drain(eng, samples)
     rep = eng.report()
     assert rep.completed == len(samples) and rep.dropped == 0
-    assert all(np.asarray(o).shape == (2,) for o in outs)
+    assert all(np.asarray(o["dense_3_out"]).shape == (1, 2) for o in outs)
     # head-of-queue grouping: 9 requests, max_batch 4 -> 4+4+1
     assert sorted(rep.batch_hist.items()) == [(1, 1), (4, 2)]
     assert rep.p95_ms >= rep.p50_ms >= 0.0
@@ -142,17 +139,17 @@ def test_engine_matches_design_serve(bound_design, samples):
     Same bucket shape as the serve batch — bit-identity is a per-compiled-
     program property, so the comparison pins one (9,) dispatch.
     """
-    eng = bound_design.engine(backend="tensor", buckets=(len(samples),))
+    eng = bound_design.engine(buckets=(len(samples),))
     outs = _drain(eng, samples)
     batch = np.concatenate(samples)          # (9, 1, IMG, IMG)
-    report = bound_design.serve([batch], backend="tensor", collect=True)
-    ref = np.asarray(report.outputs[0])
+    report = bound_design.serve([batch], collect=True)
+    ref = report.outputs[0]
     for i, o in enumerate(outs):
-        np.testing.assert_array_equal(o, ref[i])
+        _assert_same(o, {k: np.asarray(v)[i] for k, v in ref.items()})
 
 
 def test_engine_padding_counts_bucket_fill(bound_design, samples):
-    eng = bound_design.engine(backend="tensor", buckets=(4,))
+    eng = bound_design.engine(buckets=(4,))
     _drain(eng, samples[:3])
     rep = eng.report()
     assert rep.batch_hist == {4: 1}
@@ -160,21 +157,21 @@ def test_engine_padding_counts_bucket_fill(bound_design, samples):
 
 
 def test_engine_threaded_mode_drains_on_stop(bound_design, samples):
-    eng = bound_design.engine(backend="simd", max_batch=4, max_delay_ms=1.0)
+    eng = bound_design.engine(max_batch=4, max_delay_ms=1.0)
     with eng:
         reqs = [eng.submit(x) for x in samples]
         outs = [r.wait(timeout=30) for r in reqs]
     rep = eng.report()
     assert rep.completed == len(samples) and rep.dropped == 0
     assert rep.qps > 0
-    # the SIMD design returns its output memrefs as a dict, sliced per sample
+    # the design returns its output memrefs as a dict, sliced per sample
     assert all(np.asarray(o["dense_3_out"]).shape == (1, 2) for o in outs)
     with pytest.raises(RuntimeError, match="stopped"):
         eng.submit(samples[0])
 
 
 def test_engine_rejects_bad_sample_shape(bound_design):
-    eng = bound_design.engine(backend="tensor", max_batch=2)
+    eng = bound_design.engine(max_batch=2)
     with pytest.raises(ValueError, match="does not match input memref"):
         eng.submit(np.zeros((3, 3), np.float32))
 
@@ -184,18 +181,18 @@ def test_engine_rejects_bad_sample_shape(bound_design):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("backend", ["tensor", "simd"])
+@pytest.mark.parametrize("fmt", [None, "5_4"])
 def test_save_load_round_trip_bit_identical(bound_design, samples,
-                                            tmp_path, backend):
+                                            tmp_path, fmt):
     path = tmp_path / "bragg.design"
-    bound_design.save(path, backend=backend)
-    ref = _drain(bound_design.engine(backend=backend, max_batch=4), samples)
+    bound_design.save(path, fmt=fmt)
+    ref = _drain(bound_design.engine(fmt=fmt, max_batch=4), samples)
 
     loaded = hls.load(path)
-    assert loaded.manifest["backend"] == backend
+    assert loaded.manifest["fmt"] == fmt
     assert loaded.manifest["path"] == str(path)
-    eng = loaded.engine(max_batch=4)         # backend from the manifest
-    assert eng.backend == backend
+    eng = loaded.engine(max_batch=4)         # fmt from the manifest
+    assert eng.fmt == fmt
     outs = _drain(eng, samples)
     for a, b in zip(ref, outs):
         _assert_same(a, b)
@@ -219,15 +216,15 @@ def test_load_rejects_non_artifact(tmp_path):
 def test_fault_injection_restarts_from_artifact_no_request_lost(
         bound_design, samples, tmp_path):
     path = tmp_path / "bragg.design"
-    bound_design.save(path, backend="tensor")
+    bound_design.save(path)
 
     # uninterrupted reference run
-    ref = _drain(bound_design.engine(backend="tensor", max_batch=4,
+    ref = _drain(bound_design.engine(max_batch=4,
                                      artifact_path=path), samples)
 
     # poison dispatch 1: the second batch fails mid-stream
     inj = FailureInjector(fail_at=(1,))
-    eng = bound_design.engine(backend="tensor", max_batch=4,
+    eng = bound_design.engine(max_batch=4,
                               artifact_path=path, injector=inj)
     outs = _drain(eng, samples)
     rep = eng.report()
@@ -244,7 +241,7 @@ def test_fault_injection_restarts_from_artifact_no_request_lost(
 def test_fault_exhausted_retries_fail_requests_not_hang(bound_design,
                                                         samples):
     inj = FailureInjector(fail_at=(0, 1, 2))
-    eng = bound_design.engine(backend="tensor", max_batch=4, max_retries=2,
+    eng = bound_design.engine(max_batch=4, max_retries=2,
                               injector=inj)
     reqs = [eng.submit(x) for x in samples[:4]]
     eng.run_until_drained()
@@ -263,7 +260,7 @@ def test_fault_exhausted_retries_fail_requests_not_hang(bound_design,
 
 def test_serve_report_has_percentiles(bound_design, samples):
     batch = np.concatenate(samples)
-    report = bound_design.serve([batch] * 5, backend="tensor")
+    report = bound_design.serve([batch] * 5)
     assert report.p99_ms >= report.p95_ms >= report.p50_ms > 0.0
     assert "p50" in report.summary()
 
@@ -302,7 +299,7 @@ def test_queue_depth_counts_idle_and_ramp_periods(bound_design, samples):
     """A burst of 8 queued requests must report a max depth of 8 and a
     time-weighted mean/p95 near the top, even though dispatch-time
     sampling alone would see the queue only as it drains (mean ~4)."""
-    eng = bound_design.engine(backend="tensor", buckets=(1,))
+    eng = bound_design.engine(buckets=(1,))
     for x in samples[:8]:
         eng.submit(x)
     time.sleep(0.25)          # the queue sits at depth 8 the whole time

@@ -158,7 +158,7 @@ def _lower_braggnn(s, fmt):
                          ids=["fp32", "5_4", "s4-fp32", "s4-5_4"])
 def test_braggnn_nest_tier_compiles(one_chip, s, fmt):
     """The whole jitted nest-tier BraggNN(s, img=11) program, as
-    ``Design.jax_fn(backend='pallas')`` runs it on the chip."""
+    ``Design.jax_fn()`` runs it on the chip."""
     core, module, plan, weights = _lower_braggnn(s, fmt)
     for kname in ("conv2d_vmem", "smallfloat_matmul", "fused_softmax"):
         assert any(k.startswith(kname) for k in plan.kernels), plan.kernels

@@ -105,7 +105,7 @@ def test_pallas_nest_tier_taylor_and_flash(design, params):
     feeds = {**model.weight_feeds(params), "input": x}
     want = np.asarray(transformer.forward(params, x, n_heads=H))
 
-    fn = design.jax_fn(backend="pallas")
+    fn = design.jax_fn()
     assert fn.plan.mode == "nests"
     assert fn.plan.kernels.get("smallfloat_matmul", 0) >= 2
     assert fn.plan.kernels.get("fused_softmax", 0) == 1
@@ -113,7 +113,7 @@ def test_pallas_nest_tier_taylor_and_flash(design, params):
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
     # flash throughput mode: true-exp softmax, so only approximately equal
-    fnf = design.jax_fn(backend="pallas", nlb_flash=True)
+    fnf = design.jax_fn(nlb_flash=True)
     assert fnf.plan.kernels.get("flash_attention", 0) == 1
     gotf = np.asarray(fnf(feeds)["ln_post_out"])
     np.testing.assert_allclose(gotf, want, rtol=5e-2, atol=5e-3)

@@ -173,7 +173,7 @@ def test_feed_deterministic_and_pileup_bursts():
 
 def test_loop_decisions_bit_identical_across_runs(design):
     def once():
-        loop = design.trigger(backend="tensor", window=4)
+        loop = design.trigger(window=4)
         loop.calibrate(trigger.DetectorFeed(img=IMG, seed=9), 32)
         rep = loop.run(trigger.DetectorFeed(img=IMG, seed=9), 50)
         return loop.threshold, rep
@@ -191,7 +191,7 @@ def test_loop_decisions_bit_identical_across_runs(design):
 
 
 def test_loop_partial_window_padding(design):
-    loop = design.trigger(backend="tensor", window=8, threshold=0.0)
+    loop = design.trigger(window=8, threshold=0.0)
     rep = loop.run(trigger.DetectorFeed(img=IMG, seed=1), 10)
     assert rep.processed == 10                     # 8 + padded 2
     assert rep.windows == 2
@@ -201,7 +201,7 @@ def test_loop_partial_window_padding(design):
 def test_loop_deadline_accounting(design):
     # an impossible deadline: every decision late, slack negative
     tight = trigger.TriggerBudget(max_latency_us=1e-3)
-    rep = design.trigger(backend="tensor", window=4, budget=tight).run(
+    rep = design.trigger(window=4, budget=tight).run(
         trigger.DetectorFeed(img=IMG, seed=2), 12)
     assert rep.deadline_misses == rep.processed == 12
     assert rep.miss_pct == 100.0
@@ -210,7 +210,7 @@ def test_loop_deadline_accounting(design):
 
     # a generous one: all met, slack positive
     loose = trigger.TriggerBudget(max_latency_us=60e6)
-    rep2 = design.trigger(backend="tensor", window=4, budget=loose).run(
+    rep2 = design.trigger(window=4, budget=loose).run(
         trigger.DetectorFeed(img=IMG, seed=2), 12)
     assert rep2.deadline_misses == 0
     assert all(d.deadline_met and d.slack_us > 0 for d in rep2.decisions)
@@ -225,7 +225,7 @@ def test_loop_realtime_overrun_drops_oldest(design):
         time.sleep(0.02)
         return slow(out)
 
-    loop = design.trigger(backend="tensor", window=2, capacity=4,
+    loop = design.trigger(window=2, capacity=4,
                           predicate=slow_predicate)
     rep = loop.run(trigger.DetectorFeed(img=IMG, frame_rate_hz=2000,
                                         seed=3), 60, realtime=True)
@@ -240,7 +240,7 @@ def test_loop_realtime_overrun_drops_oldest(design):
 
 def test_loop_realtime_sustains_modest_rate(design):
     budget = trigger.TriggerBudget(max_latency_us=2e6)
-    loop = design.trigger(backend="tensor", window=4, budget=budget)
+    loop = design.trigger(window=4, budget=budget)
     rep = loop.run(trigger.DetectorFeed(img=IMG, frame_rate_hz=200,
                                         seed=4), 60, realtime=True)
     assert rep.dropped == 0
@@ -252,7 +252,7 @@ def test_loop_realtime_sustains_modest_rate(design):
 
 def test_loop_window_spans_and_counters(design):
     obs.enable()
-    loop = design.trigger(backend="tensor", window=4,
+    loop = design.trigger(window=4,
                           budget=trigger.TriggerBudget(max_latency_us=1e-3))
     rep = loop.run(trigger.DetectorFeed(img=IMG, seed=6), 16)
     spans = [s for s in obs.tracer.spans() if s.name == "trigger.window"]
@@ -271,7 +271,6 @@ def test_loop_rejects_bad_window(design):
 
 
 def test_calibrate_refuses_custom_predicate(design):
-    loop = design.trigger(backend="tensor",
-                          predicate=trigger.threshold_predicate(0.1))
+    loop = design.trigger(predicate=trigger.threshold_predicate(0.1))
     with pytest.raises(ValueError, match="custom predicate"):
         loop.calibrate(trigger.DetectorFeed(img=IMG), 8)
