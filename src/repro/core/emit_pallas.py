@@ -554,7 +554,15 @@ def _einsum(spec, a, b):
 def _nlb_step(node, conv_e, sm_e, fa_e, q, fmt_tuple, kw, nlb_flash: bool,
               plan: PallasPlan):
     """The NonLocalBlock composite: three 1x1 convs -> attention ->
-    out-projection -> residual, every stage through a registry kernel."""
+    out-projection -> residual, every stage through a registry kernel.
+
+    The block works channel-major: x goes to ``(C1, H·W·B)`` once on
+    entry, the 1x1 convs take and give that layout (``conv2d_vmem``'s
+    pointwise form, pixels in the lanes), the attention reads the ``(C2,
+    H·W, B)`` views, and the result goes back to ``(B, C1, H, W)`` once, in
+    the residual's add.  The pixels run sample-minor, so the entry and the
+    exit are plain 2-D transposes of the ``(B, C1·H·W)`` activation that
+    the unrolled convs on either side give and take."""
     import jax.numpy as jnp
 
     pre = node.prefix
@@ -569,35 +577,34 @@ def _nlb_step(node, conv_e, sm_e, fa_e, q, fmt_tuple, kw, nlb_flash: bool,
         plan.record_kernel(sm_e.name)
 
     def step(x, w):
-        b, c1, h, _ = x.shape
-        n = h * h
-        theta = q(conv_e.fn(x, w[f"{pre}.theta.weight"], None,
-                            fmt=fmt_tuple, **kw))
-        phi = q(conv_e.fn(x, w[f"{pre}.phi.weight"], None,
-                          fmt=fmt_tuple, **kw))
-        g = q(conv_e.fn(x, w[f"{pre}.g.weight"], None,
-                        fmt=fmt_tuple, **kw))
-        c2 = theta.shape[1]
-        tf = theta.reshape(b, c2, n)
-        pf = phi.reshape(b, c2, n)
-        gf = g.reshape(b, c2, n)
+        def conv(xc, sub):                  # (Cin, n·B) -> (Cout, n·B)
+            return q(conv_e.fn(xc, w[f"{pre}.{sub}.weight"], None,
+                               fmt=fmt_tuple, **kw))
+
+        b, c1, h, wd = x.shape
+        n = h * wd
+        x2 = x.reshape(b, c1 * n)
+        xc = x2.T.reshape(c1, n * b)
+        theta, phi, g = (conv(xc, sub) for sub in ("theta", "phi", "g"))
+        c2 = theta.shape[0]
+        tf = theta.reshape(c2, n, b)
+        pf = phi.reshape(c2, n, b)
+        gf = g.reshape(c2, n, b)
         if use_flash:
             # A = softmax(theta^T phi) — flash divides logits by sqrt(D),
             # so pre-scale q to keep the DFG's unscaled scores
-            qv = (tf * jnp.sqrt(jnp.float32(c2))).transpose(0, 2, 1)
-            kv = pf.transpose(0, 2, 1)
-            vv = gf.transpose(0, 2, 1)
+            qv = (tf * jnp.sqrt(jnp.float32(c2))).transpose(2, 1, 0)
+            kv = pf.transpose(2, 1, 0)
+            vv = gf.transpose(2, 1, 0)
             y = fa_e.fn(qv[:, :, None, :], kv[:, :, None, :],
                         vv[:, :, None, :], causal=False, **kw)
-            yc = q(y[:, :, 0, :].transpose(0, 2, 1))         # (B, c2, n)
+            yc = q(y[:, :, 0, :].transpose(2, 1, 0))         # (c2, n, B)
         else:
-            scores = q(_einsum("bci,bcj->bij", tf, pf))
+            scores = q(_einsum("cib,cjb->bij", tf, pf))
             attn = sm_e.fn(scores, taylor_order=node.taylor_order, **kw)
-            yc = q(_einsum("bij,bcj->bci", attn, gf))
-        y4 = yc.reshape(b, c2, h, h)
-        z = q(conv_e.fn(y4, w[f"{pre}.out_cnn.weight"], None,
-                        fmt=fmt_tuple, **kw))
-        return q(x + z)
+            yc = q(_einsum("bij,cjb->cib", attn, gf))
+        z = conv(yc.reshape(c2, n * b), "out_cnn")
+        return q(x2 + z.reshape(c1 * n, b).T).reshape(b, c1, h, wd)
 
     return step
 

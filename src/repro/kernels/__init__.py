@@ -9,6 +9,7 @@ Each kernel directory holds:
 
 smallfloat_matmul — reduced-precision MAC array (paper §4.2)
 conv2d_vmem       — weights-resident BraggNN conv (paper's no-BRAM result):
+                    1×1 convs channel-major with the pixels in the lanes,
                     small 3×3 convs unrolled into one lane-dense
                     contraction, the rest as im2col
 flash_attention   — blockwise attention (32k prefill path)

@@ -7,8 +7,15 @@ analogue: the conv's weights live in VMEM for the kernel's lifetime while
 the batch streams through in row blocks.  Valid padding, stride 1, NCHW —
 matching the loop-nest semantics of ``repro.core.frontend.conv2d``.
 
-The wrapper lowers the conv to one 2-D contraction in one of two forms,
+The wrapper lowers the conv to one 2-D contraction in one of three forms,
 chosen by :func:`conv_form` from the conv's shape alone:
+
+* ``"pointwise"`` — a 1×1 conv, laid out channel-major: the weights ``(Cout,
+  Cin)`` times the ``(Cin, B·H·W)`` view of the input, whose pixels fill the
+  lanes, giving the ``(Cout, B·H·W)`` output.  A caller that keeps its
+  activations channel-major (the non-local block) passes that 2-D view
+  itself and gets it back, with no transpose on either side; an NCHW input
+  is transposed to it and back inside the call.
 
 * ``"unrolled"`` — the loop nest unrolled onto the MXU, as OpenHLS unrolls
   it into the FPGA fabric: the input flattened per sample, ``(B,
@@ -18,13 +25,14 @@ chosen by :func:`conv_form` from the conv's shape alone:
   themselves, flattened, and the output is lane-dense: no patch matrix and
   no transpose in HBM.  The matrix grows as (H·W)², so this form serves a
   conv with kh·kw > 1 whose matrix fits :data:`UNROLL_BYTES`.
-* ``"im2col"`` — every other conv (1×1, or an image too large to unroll):
+* ``"im2col"`` — a conv with kh·kw > 1 and an image too large to unroll:
   the ``(B·Ho·Wo, Cin·kh·kw)`` patch matrix times the ``(Cin·kh·kw, Cout)``
   weight matrix.
 
-Either way the kernel is ``relu?(quantise?(lhs) @ rhs + bias)`` per block,
-fp32 accumulation at ``HIGHEST``; the (wE,wF) quantiser runs on the
-streamed operand in VMEM (the FloPoCo discipline), and the weights are
+Every form's kernel is ``relu?(lhs @ rhs + bias)`` per block, fp32
+accumulation at ``HIGHEST``; the (wE,wF) quantiser runs on the streamed
+operand in VMEM (the FloPoCo discipline: the rows of the lhs, or the
+pointwise form's pixel columns of the rhs), and the weights are
 quantised once per call before they are laid out (``quantize`` is
 elementwise and maps ±0 to ±0, so the unrolled matrix's zeros stay zero).
 A per-tap contraction over NCHW blocks would need in-kernel reshapes across
@@ -33,6 +41,8 @@ the lane axis (e.g. 81 lanes at img=11), which the TPU compiler refuses.
 Grid: (rows / bm, cols / bn), columns innermost, so a row block is fetched
 once and stays in VMEM while the weight blocks pass; where the whole
 weight matrix is one column block it stays resident for the whole call.
+The pointwise form's grid is (cols / bn,): its whole weight matrix stays
+resident and the pixel columns stream.
 """
 
 from __future__ import annotations
@@ -61,10 +71,13 @@ VMEM_BLOCK_BYTES = 12 << 20
 
 
 def conv_form(cin: int, h: int, w: int, cout: int, kh: int, kw: int) -> str:
-    """``"unrolled"`` or ``"im2col"``: how ``conv2d_vmem`` lowers a valid,
-    stride-1 conv of this shape (a pure function of the shape)."""
+    """``"pointwise"``, ``"unrolled"`` or ``"im2col"``: how ``conv2d_vmem``
+    lowers a valid, stride-1 conv of this shape (a pure function of the
+    shape)."""
+    if kh * kw == 1:
+        return "pointwise"
     ho, wo = h - kh + 1, w - kw + 1
-    if kh * kw > 1 and 4 * cin * h * w * cout * ho * wo <= UNROLL_BYTES:
+    if 4 * cin * h * w * cout * ho * wo <= UNROLL_BYTES:
         return "unrolled"
     return "im2col"
 
@@ -121,6 +134,10 @@ def _lanes(n: int) -> int:
     return -(-n // 128) * 128
 
 
+def _sublanes(n: int) -> int:
+    return -(-n // 8) * 8
+
+
 def blocks(rows: int, k: int, n: int, *, bm: int,
            quantised: bool) -> Blocks:
     """The blocks a ``(rows, k) @ (k, n)`` contraction takes: the whole
@@ -140,11 +157,42 @@ def blocks(rows: int, k: int, n: int, *, bm: int,
     return Blocks(bm, bn, -(-n // bn) * bn)
 
 
-def _kernel(p_ref, w_ref, b_ref, o_ref, *, fmt, fuse_relu):
-    p = p_ref[...].astype(jnp.float32)            # (bm, K)
+def pointwise_blocks(cin: int, cout: int, n: int, *, quantised: bool,
+                     cap: Optional[int] = None) -> Blocks:
+    """The blocks of the pointwise form's ``(Cout, Cin) @ (Cin, n)``: the
+    ``Cout`` rows whole (``bm``) and ``bn`` pixel columns per grid step.
+
+    ``bn`` is the whole ``n`` where it fits :data:`VMEM_BLOCK_BYTES` (and
+    ``cap`` columns, if given); else a multiple of 128 within both, the
+    largest that divides ``n`` if one lies in the upper half of that room,
+    so the columns need no padding (the offline batch's 4096·81), and the
+    room itself, with ``n`` padded to whole blocks, where none does."""
+    def step_bytes(bn_):
+        x = _sublanes(cin) * bn_
+        out = _sublanes(cout) * bn_
+        tmp = out + (3 * x if quantised else 0)
+        fixed = _sublanes(cout) * (_lanes(cin) + 128)
+        return 4 * (2 * (x + out + fixed) + tmp)
+
+    per_col = step_bytes(1) - step_bytes(0)
+    room = (VMEM_BLOCK_BYTES - step_bytes(0)) // per_col // 128 * 128
+    if cap is not None:
+        room = min(room, cap // 128 * 128)
+    room = max(room, 128)
+    if n <= room:
+        return Blocks(cout, n, n)
+    bn = next((c for c in range(room, room // 2, -128) if n % c == 0), room)
+    return Blocks(cout, bn, -(-n // bn) * bn)
+
+
+def _kernel(lhs_ref, rhs_ref, b_ref, o_ref, *, fmt, fuse_relu, streamed=0):
+    """``relu?(lhs @ rhs + b)`` on one block.  The quantiser rounds the
+    streamed operand (``streamed``: 0 the lhs rows, 1 the rhs pixel
+    columns); the weights, the other, come pre-quantised."""
+    ops = [lhs_ref[...].astype(jnp.float32), rhs_ref[...].astype(jnp.float32)]
     if fmt is not None:
-        p = _quantize_block(p, *fmt)
-    acc = _dot(p, w_ref[...])                     # (K, bn), pre-quantised
+        ops[streamed] = _quantize_block(ops[streamed], *fmt)
+    acc = _dot(*ops)
     if b_ref is not None:
         acc = acc + b_ref[...]
     if fuse_relu:
@@ -152,34 +200,49 @@ def _kernel(p_ref, w_ref, b_ref, o_ref, *, fmt, fuse_relu):
     o_ref[...] = acc
 
 
-def _kernel_nobias(p_ref, w_ref, o_ref, **kw):
-    _kernel(p_ref, w_ref, None, o_ref, **kw)
+def _kernel_nobias(lhs_ref, rhs_ref, o_ref, **kw):
+    _kernel(lhs_ref, rhs_ref, None, o_ref, **kw)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "fmt", "fuse_relu", "bm", "interpret"))
 def conv2d_vmem(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
                 *, fmt: Optional[tuple[int, int]] = None,
-                fuse_relu: bool = False, bm: int = 512,
+                fuse_relu: bool = False, bm: Optional[int] = None,
                 interpret: bool = False) -> jax.Array:
-    """x: (B, Cin, H, W), w: (Cout, Cin, kh, kw), b: (Cout,) -> fp32.
+    """x: (B, Cin, H, W), w: (Cout, Cin, kh, kw), b: (Cout,) -> fp32
+    (B, Cout, Ho, Wo).  For a 1×1 conv, x may instead be the channel-major
+    ``(Cin, N)`` view of N pixels, and the result is then ``(Cout, N)``.
 
     ``bm`` caps the rows per grid step (samples in the unrolled form,
-    patch rows in im2col); rows are zero-padded up to a whole number of
-    blocks when there is more than one block.
+    patch rows in im2col, 512 by default) or the pixel columns (the
+    pointwise form, as many as fit VMEM by default); rows or columns are
+    zero-padded up to a whole number of blocks when there is more than one
+    block.
     """
-    bsz, cin, h, wdim = x.shape
-    cout, cin2, kh, kw = w.shape
-    if cin != cin2:
+    cout, cin, kh, kw = w.shape
+    if (x.shape[0] if x.ndim == 2 else x.shape[1]) != cin:
         raise ValueError(f"input channels differ: x {x.shape}, w {w.shape}")
-    ho, wo = h - kh + 1, wdim - kw + 1
     w = w.astype(jnp.float32)
     if fmt is not None:
         w = quantize(w, FloatFormat(*fmt))
     if b is not None:
         b = b.astype(jnp.float32)
-    unrolled = conv_form(cin, h, wdim, cout, kh, kw) == "unrolled"
-    if unrolled:
+    if x.ndim == 2:
+        if kh * kw != 1:
+            raise ValueError(f"a channel-major (Cin, N) input needs a 1×1 "
+                             f"conv, got w {w.shape}")
+        return _pointwise(x, w.reshape(cout, cin), b, fmt=fmt,
+                          fuse_relu=fuse_relu, cap=bm, interpret=interpret)
+    bsz, _, h, wdim = x.shape
+    ho, wo = h - kh + 1, wdim - kw + 1
+    form = conv_form(cin, h, wdim, cout, kh, kw)
+    if form == "pointwise":
+        xc = x.transpose(1, 0, 2, 3).reshape(cin, bsz * h * wdim)
+        out = _pointwise(xc, w.reshape(cout, cin), b, fmt=fmt,
+                         fuse_relu=fuse_relu, cap=bm, interpret=interpret)
+        return out.reshape(cout, bsz, h, wdim).transpose(1, 0, 2, 3)
+    if form == "unrolled":
         lhs = x.reshape(bsz, cin * h * wdim)
         rhs = unroll_weights(w, h, wdim)
         if b is not None:
@@ -189,7 +252,8 @@ def conv2d_vmem(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
         rhs = w.reshape(cout, cin * kh * kw).T
     rows, kdim = lhs.shape
     n = rhs.shape[1]
-    blk = blocks(rows, kdim, n, bm=bm, quantised=fmt is not None)
+    blk = blocks(rows, kdim, n, bm=512 if bm is None else bm,
+                 quantised=fmt is not None)
     rows_p = -(-rows // blk.bm) * blk.bm
     if rows_p != rows:
         lhs = jnp.pad(lhs, ((0, rows_p - rows), (0, 0)))
@@ -218,6 +282,36 @@ def conv2d_vmem(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None,
     )(*args)
     if (rows_p, blk.np_) != (rows, n):
         out = out[:rows, :n]
-    if unrolled:
+    if form == "unrolled":
         return out.reshape(bsz, cout, ho, wo)
     return out.reshape(bsz, ho, wo, cout).transpose(0, 3, 1, 2)
+
+
+def _pointwise(x, w, b, *, fmt, fuse_relu, cap, interpret):
+    """The pointwise form: ``(Cout, Cin) @ (Cin, N) -> (Cout, N)`` with the
+    weights (quantised already) resident and the pixel columns streamed."""
+    cin, n = x.shape
+    cout = w.shape[0]
+    blk = pointwise_blocks(cin, cout, n, quantised=fmt is not None, cap=cap)
+    if blk.np_ != n:
+        x = jnp.pad(x, ((0, 0), (0, blk.np_ - n)))
+    in_specs = [
+        pl.BlockSpec((cout, cin), lambda j: (0, 0)),
+        pl.BlockSpec((cin, blk.bn), lambda j: (0, j)),
+    ]
+    args = [w, x]
+    kernel = _kernel_nobias
+    if b is not None:
+        # bias as a column: it broadcasts along the pixel lanes
+        in_specs.append(pl.BlockSpec((cout, 1), lambda j: (0, 0)))
+        args.append(b.reshape(cout, 1))
+        kernel = _kernel
+    out = pl.pallas_call(
+        functools.partial(kernel, fmt=fmt, fuse_relu=fuse_relu, streamed=1),
+        grid=(blk.np_ // blk.bn,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((cout, blk.bn), lambda j: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((cout, blk.np_), jnp.float32),
+        interpret=interpret,
+    )(*args)
+    return out[:, :n] if blk.np_ != n else out

@@ -1,4 +1,5 @@
-"""Pure-jnp oracle for conv2d_vmem (valid padding, stride 1, NCHW)."""
+"""Pure-jnp oracle for conv2d_vmem (valid padding, stride 1, NCHW, or the
+channel-major ``(Cin, N)`` view of N pixels for a 1×1 conv)."""
 
 from __future__ import annotations
 
@@ -13,6 +14,9 @@ from repro.core.precision import FloatFormat, quantize
 def conv2d_ref(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None, *,
                fmt: Optional[tuple[int, int]] = None,
                fuse_relu: bool = False) -> jax.Array:
+    if x.ndim == 2:   # channel-major (Cin, N): one 1×N image
+        return conv2d_ref(x[None, :, None, :], w, b, fmt=fmt,
+                          fuse_relu=fuse_relu)[0, :, 0, :]
     x = x.astype(jnp.float32)
     w = w.astype(jnp.float32)
     if fmt is not None:

@@ -92,7 +92,8 @@ def _register_exemplars() -> None:
         oracle=_conv_ref.conv2d_ref,
         accelerates=("Conv2d", "nlb.conv1x1"),
         description="weights-resident valid conv as one contraction "
-                    "(small 3x3 convs unrolled, the rest im2col), optional "
+                    "(1x1 convs channel-major, small 3x3 convs unrolled, "
+                    "the rest im2col), optional "
                     "fused ReLU + (wE,wF) operand quantisation"))
     register(KernelEntry(
         name="smallfloat_matmul",

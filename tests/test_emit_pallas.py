@@ -186,7 +186,8 @@ def test_nest_tier_records_matmul_blocking(s, blocks):
 @pytest.mark.parametrize("s", [1, 4])
 def test_nest_tier_records_conv_forms(s):
     """BraggNN's three 3×3 convs take the unrolled form and the non-local
-    block's four 1×1 convs im2col, at s=1 and at s=4."""
+    block's four 1×1 convs the pointwise form, at s=1 and at s=4; none
+    takes im2col."""
     from repro import obs
     obs.enable()
     obs.reset()
@@ -197,11 +198,12 @@ def test_nest_tier_records_conv_forms(s):
         obs.disable()
         obs.reset()
     assert fn.plan.convs == {
-        "conv1": "unrolled", "nlb.theta": "im2col", "nlb.phi": "im2col",
-        "nlb.g": "im2col", "nlb.out_cnn": "im2col", "conv2a": "unrolled",
+        "conv1": "unrolled", "nlb.theta": "pointwise", "nlb.phi": "pointwise",
+        "nlb.g": "pointwise", "nlb.out_cnn": "pointwise", "conv2a": "unrolled",
         "conv2b": "unrolled"}
     assert counters["pallas.kernel.conv2d_vmem:unrolled"] == 3
-    assert counters["pallas.kernel.conv2d_vmem:im2col"] == 4
+    assert counters["pallas.kernel.conv2d_vmem:pointwise"] == 4
+    assert "pallas.kernel.conv2d_vmem:im2col" not in counters
 
 
 def test_dfg_tier_on_tpu_runs_xla_bodies(conv_design, conv_feeds,
@@ -270,6 +272,49 @@ def test_nest_tier_flash_attention_mode(bragg_design, bragg_feeds):
     out = fn(bragg_feeds)
     for k in ref:
         np.testing.assert_allclose(np.asarray(out[k]), ref[k], atol=5e-2)
+
+
+def _nlb_true_softmax(p, x):
+    """The non-local block in float32 at HIGHEST with a true-exp softmax,
+    NCHW throughout: x + out(softmax(θᵀφ) g)."""
+    import jax.numpy as jnp
+    hi = jax.lax.Precision.HIGHEST
+    b, c1, h, w = x.shape
+
+    def conv(wt, a):
+        return jnp.einsum("oc,bcp->bop", wt[:, :, 0, 0], a, precision=hi)
+
+    xf = x.reshape(b, c1, h * w)
+    t, ph, g = (conv(p[k]["w"], xf) for k in ("theta", "phi", "g"))
+    a = jax.nn.softmax(jnp.einsum("bci,bcj->bij", t, ph, precision=hi), -1)
+    y = jnp.einsum("bij,bcj->bci", a, g, precision=hi)
+    return (xf + conv(p["out"]["w"], y)).reshape(x.shape)
+
+
+@pytest.mark.parametrize("use_pallas", [None, True],
+                         ids=["oracle", "interpret"])
+def test_nest_tier_flash_nlb_matches_true_softmax(use_pallas):
+    """The non-local block alone with ``nlb_flash=True``: the channel-major
+    layout through flash attention equals a true-exp softmax block, at a
+    batch whose B·81 pixel columns fill no whole block.  θ and φ are
+    scaled so the scores spread over several units and the softmax is far
+    from uniform."""
+    from repro.nn import graph as nng
+    nlb = nng.NonLocalBlock("nlb", channels=16, mid_channels=8)
+    module = nng.ModuleGraph("nlb_only", (1, 16, 9, 9), [nlb])
+    params = module.init_params(jax.random.PRNGKey(3))
+    for k in ("theta", "phi"):
+        params["nlb"][k]["w"] = params["nlb"][k]["w"] * 3.0
+    fn = to_pallas_fn(None, module=module.bind(params), mode="nests",
+                      nlb_flash=True, use_pallas=use_pallas)
+    assert "flash_attention" in fn.plan.kernels
+    x = np.random.default_rng(1).standard_normal(
+        (5, 1, 16, 9, 9)).astype(np.float32)
+    (got,) = fn({module.input_name: x}).values()
+    want = np.asarray(_nlb_true_softmax(params["nlb"], x[:, 0]))
+    got = np.asarray(got).reshape(want.shape)
+    assert np.abs(want - x[:, 0]).max() > 0.1     # the block does work
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -9,7 +9,8 @@ import pytest
 from repro.core.precision import FloatFormat, quantize
 from repro.kernels.conv2d_vmem import conv2d_vmem as conv_mod
 from repro.kernels.conv2d_vmem.conv2d_vmem import (
-    conv2d_vmem, conv_form, unroll_weights)
+    VMEM_BLOCK_BYTES, conv2d_vmem, conv_form, pointwise_blocks,
+    unroll_weights)
 from repro.kernels.conv2d_vmem.ref import conv2d_ref
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.fused_softmax.fused_softmax import fused_softmax
@@ -134,8 +135,8 @@ BRAGG_CONVS = [((5, 1, 11, 11), (16, 1, 3, 3), True),
                               "conv2b"])
 def test_conv2d_vmem_braggnn_shapes(xs, ws, bias, fmt):
     """BraggNN's convs in the form ``conv_form`` gives them (the 3×3 ones
-    unrolled, the 1×1 ones as im2col, whose patch rows B·Ho·Wo then span
-    several padded row blocks)."""
+    unrolled, the 1×1 ones pointwise, whose B·H·W pixel columns then fill
+    no whole block of 128 and are padded)."""
     key = jax.random.key(xs[1] * 100 + ws[0])
     x = _r(jax.random.fold_in(key, 0), xs, jnp.float32)
     w = _r(jax.random.fold_in(key, 1), ws, jnp.float32) * 0.3
@@ -318,10 +319,82 @@ def test_quantize_keeps_signed_zeros():
 
 @pytest.mark.parametrize("cin,h,cout,k,form", [
     *[(c, h, o, 3, "unrolled") for c, h, o in BRAGG_3X3],
-    (16, 9, 8, 1, "im2col"), (8, 9, 16, 1, "im2col"),
-    (64, 9, 32, 1, "im2col"), (32, 9, 64, 1, "im2col"),
+    (16, 9, 8, 1, "pointwise"), (8, 9, 16, 1, "pointwise"),
+    (64, 9, 32, 1, "pointwise"), (32, 9, 64, 1, "pointwise"),
     (1, 64, 16, 3, "im2col"),
 ], ids=[*BRAGG_3X3_IDS, "nlb_in", "nlb_out", "s4.nlb_in", "s4.nlb_out",
         "img64"])
 def test_conv_form(cin, h, cout, k, form):
     assert conv_form(cin, h, h, cout, k, k) == form
+
+
+#: the non-local block's 1×1 convs, s=1 then s=4: (Cin, Cout)
+BRAGG_1X1 = [(16, 8), (8, 16), (64, 32), (32, 64)]
+BRAGG_1X1_IDS = ["nlb_in", "nlb_out", "s4.nlb_in", "s4.nlb_out"]
+
+
+@pytest.mark.parametrize("batch", [1, 3, 4, 5])
+@pytest.mark.parametrize("bias", [False, True], ids=["plain", "bias_relu"])
+@pytest.mark.parametrize("fmt", [None, (5, 4)], ids=["fp32", "5_4"])
+@pytest.mark.parametrize("cin,cout", BRAGG_1X1, ids=BRAGG_1X1_IDS)
+def test_conv2d_vmem_pointwise(cin, cout, fmt, bias, batch):
+    """The pointwise form at the non-local block's shapes against the
+    oracle, on the channel-major ``(Cin, B·81)`` view the block passes.
+    Blocks of 128 columns: B·81 fills no whole block at these batches, so
+    the last block is padded (one whole block at batch 1)."""
+    assert conv_form(cin, 9, 9, cout, 1, 1) == "pointwise"
+    key = jax.random.key(cin * 1000 + cout * 10 + batch)
+    x = _r(jax.random.fold_in(key, 0), (cin, batch * 81), jnp.float32)
+    w = _r(jax.random.fold_in(key, 1), (cout, cin, 1, 1), jnp.float32) \
+        / np.sqrt(cin)
+    b = _r(jax.random.fold_in(key, 2), (cout,), jnp.float32) \
+        if bias else None
+    got = conv2d_vmem(x, w, b, fmt=fmt, fuse_relu=bias, bm=128,
+                      interpret=True)
+    want = conv2d_ref(x, w, b, fmt=fmt, fuse_relu=bias)
+    assert got.shape == want.shape == (cout, batch * 81)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("fmt", [None, (5, 4)], ids=["fp32", "5_4"])
+def test_conv2d_vmem_pointwise_nchw(fmt):
+    """An NCHW input to a 1×1 conv takes the pointwise form too, through
+    the channel-major view and back: it equals the oracle on NCHW, and
+    the channel-major call on the same pixels."""
+    key = jax.random.key(7)
+    x = _r(jax.random.fold_in(key, 0), (3, 16, 9, 9), jnp.float32)
+    w = _r(jax.random.fold_in(key, 1), (8, 16, 1, 1), jnp.float32) / 4.0
+    b = _r(jax.random.fold_in(key, 2), (8,), jnp.float32)
+    got = conv2d_vmem(x, w, b, fmt=fmt, fuse_relu=True, interpret=True)
+    want = conv2d_ref(x, w, b, fmt=fmt, fuse_relu=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    xc = x.transpose(1, 0, 2, 3).reshape(16, -1)
+    cm = conv2d_vmem(xc, w, b, fmt=fmt, fuse_relu=True, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(cm), np.asarray(got).transpose(1, 0, 2, 3).reshape(8, -1))
+
+
+def test_conv2d_vmem_pointwise_refuses_a_3x3_channel_major_input():
+    with pytest.raises(ValueError, match="1×1"):
+        conv2d_vmem(jnp.zeros((16, 81)), jnp.zeros((8, 16, 3, 3)),
+                    interpret=True)
+
+
+@pytest.mark.parametrize("quantised", [False, True], ids=["fp32", "5_4"])
+@pytest.mark.parametrize("cin,cout", BRAGG_1X1, ids=BRAGG_1X1_IDS)
+def test_pointwise_blocks(cin, cout, quantised):
+    """At the offline batch (4096·81 columns) the column block divides the
+    columns, so nothing is padded, and a step fits VMEM; at the trigger's
+    batch (4·81) the columns are one whole block."""
+    n = 4096 * 81
+    blk = pointwise_blocks(cin, cout, n, quantised=quantised)
+    assert blk.bm == cout and blk.np_ == n and n % blk.bn == 0
+    assert blk.bn % 128 == 0 and blk.bn >= 4096
+    step = 4 * (2 * (cin + cout) + cout + (3 * cin if quantised else 0))
+    assert step * blk.bn <= VMEM_BLOCK_BYTES
+    assert pointwise_blocks(cin, cout, 324, quantised=quantised) == \
+        (cout, 324, 324)
+    padded = pointwise_blocks(cin, cout, 405, quantised=quantised, cap=128)
+    assert padded == (cout, 128, 512)

@@ -178,17 +178,33 @@ def test_conv2d_vmem_unrolled_compiles_at_offline_batch(one_chip, fmt):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("fmt", FMTS, ids=["fp32", "5_4"])
+def test_conv2d_vmem_pointwise_compiles_at_offline_batch(one_chip, fmt):
+    """s=4's 64 → 32 1×1 conv in the pointwise form at the offline cells'
+    batch, on the channel-major ``(64, 4096·81)`` view: the 331,776 pixel
+    columns stream in blocks that divide them."""
+    def fn(x, w):
+        return conv2d_vmem(x, w, None, fmt=fmt)
+    text = _compile(fn, one_chip, (64, OFFLINE_BATCH * 81), (32, 64, 1, 1))
+    assert "tpu_custom_call" in text
+    assert f"f32[32,{OFFLINE_BATCH * 81}]" in text
+
+
 @pytest.mark.parametrize("s,fmt", [(1, None), (1, "5_4"), (4, None)],
                          ids=["fp32", "5_4", "s4-fp32"])
 def test_braggnn_nest_tier_compiles_at_offline_batch(one_chip, s, fmt):
     """The whole program at the offline cells' batch: one ``conv2d_vmem``
-    call per conv layer (7), and no im2col patch matrix for the 3×3
-    convs, which take the unrolled form."""
+    call per conv layer (7); no im2col patch matrix for the 3×3 convs,
+    which take the unrolled form, nor for the non-local block's 1×1 convs,
+    which take the pointwise form on its channel-major activations (no
+    ``(B·81, C)`` patches, no NHWC copy of the block's input)."""
     core, module, plan, weights = _lower_braggnn(s, fmt)
     b, c1, c2 = OFFLINE_BATCH, 16 * s, 8 * s
     text = _compile(core, one_chip, (b,) + module.input_shape[1:], weights)
     assert len(re.findall(r"%conv2d_vmem(?:\.\d+)? = \S+ custom-call\(",
                           text)) == 7
     for patches in (f"f32[{b * 81},9]", f"f32[{b * 49},{c1 * 9}]",
-                    f"f32[{b},7,7,{c1},9]", f"f32[{b * 25},{c2 * 9}]"):
+                    f"f32[{b},7,7,{c1},9]", f"f32[{b * 25},{c2 * 9}]",
+                    f"f32[{b * 81},{c1}]", f"f32[{b * 81},{c2}]",
+                    f"f32[{b},9,9,{c1}]"):
         assert patches not in text
